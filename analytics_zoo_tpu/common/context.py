@@ -62,6 +62,26 @@ class NNContext:
 
 _CONTEXT: Optional[NNContext] = None
 
+#: the checkout (or install) root: the directory that holds the package
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory in use.  Placed from OUTSIDE when it can be: with
+    ``JAX_COMPILATION_CACHE_DIR`` set jax reads it itself and nothing
+    is set in code; otherwise the fixed ``<checkout>/.jax_cache`` — a
+    directory that moves never hits, so never a temporary, pid- or
+    time-named one.  Failure to enable it raises: a cold compile
+    mistaken for a warm one is a wrong measurement, not a degraded
+    one."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
 
 def init_nncontext(conf: Optional[ZooTpuConfig] = None,
                    app_name: Optional[str] = None) -> NNContext:
@@ -83,6 +103,7 @@ def init_nncontext(conf: Optional[ZooTpuConfig] = None,
     logging.basicConfig(level=getattr(logging, conf.log_level, logging.INFO))
     if conf.version_check:
         check_version()
+    enable_compile_cache()
     # join the pod-wide cluster BEFORE the first backend-initializing jax
     # call, when launcher/cloud env vars are present (the reference's
     # Engine.init-before-use ordering, NNContext.scala:132-146) — after

@@ -3,14 +3,15 @@
 The reference delegated image decode to OpenCV through JNI
 (feature/image/OpenCVMethod.scala); here the equivalent C++ library is
 built on demand with the system toolchain and bound via ctypes (pybind11
-is not available in this environment).  Everything degrades gracefully:
-``available()`` is False when the toolchain or libjpeg/libpng are missing
-and callers fall back to PIL.
+is not available in this environment).  ``available()`` is False when
+the toolchain or libjpeg/libpng are missing; callers then fall back to
+PIL, and the reason is logged once (``build_error()`` keeps it).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,19 +21,27 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "zoo_native.cc")
-_LIB_PATH = os.path.join(_DIR, "libzoo_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _build() -> None:
+def _lib_path() -> str:
+    """The binary is named by its source's digest, so it is only ever
+    reused for the exact source it was built from.  (An mtime
+    comparison means nothing after the tree has been copied, and the
+    binary is never committed: first use in a fresh checkout builds.)"""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libzoo_native.{digest}.so")
+
+
+def _build(lib_path: str) -> None:
     # build to a per-process temp path and rename atomically: concurrent
     # first-use builds from several worker processes must never leave a
-    # torn .so at the final path (its fresh mtime would defeat the
-    # staleness check forever)
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    # torn .so at the final path
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", _SRC,
            "-o", tmp, "-ljpeg", "-lpng", "-lpthread"]
     proc = subprocess.run(cmd, capture_output=True, text=True)
@@ -42,7 +51,7 @@ def _build() -> None:
         except OSError:
             pass
         raise RuntimeError(f"native build failed: {proc.stderr[-2000:]}")
-    os.replace(tmp, _LIB_PATH)
+    os.replace(tmp, lib_path)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -53,11 +62,10 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            stale = (not os.path.exists(_LIB_PATH) or
-                     os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC))
-            if stale:
-                _build()
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            lib = ctypes.CDLL(lib_path)
             lib.zoo_decode_rgb.restype = ctypes.c_int
             lib.zoo_decode_rgb.argtypes = [
                 ctypes.c_char_p, ctypes.c_size_t,
@@ -82,6 +90,10 @@ def _load() -> Optional[ctypes.CDLL]:
             _lib = lib
         except Exception as e:  # toolchain/libs absent: PIL fallback
             _build_error = str(e)
+            from ..observability.log import get_logger
+            get_logger("zoo.native").warning(
+                "native_unavailable_pil_fallback",
+                error=f"{type(e).__name__}: {e}")
     return _lib
 
 

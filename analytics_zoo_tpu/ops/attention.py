@@ -28,8 +28,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ..observability.log import get_logger as _get_logger
+from ..parallel import mesh as _mesh_lib
 
 NEG_INF = -1e30
+
+_slog = _get_logger("zoo.ops.attention")
 
 
 def _clamp_lengths(kv_lengths, sk):
@@ -328,12 +335,8 @@ def _mega(interpret: bool) -> dict:
     """Megacore grid partitioning hints (harmless on one core)."""
     if interpret:
         return {}
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))}
-    except (ImportError, AttributeError):
-        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))}
 
 
 def _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
@@ -410,13 +413,10 @@ def _flash_core_bwd(sq, sk, causal, masked, block_q, block_k, scale,
                     axis=-1)[:, None, :]  # (bh, 1, sq), like lse
     # backward blocks: q-chunk stays at the forward's (which divides sq
     # by construction); key-chunk halves when possible — the dkv cell's
-    # (block_q × block_k) f32 p/dp/ds live simultaneously.  A prime-ish
-    # sk whose only small divisors are tiny keeps the forward's block
-    # rather than degenerating to a per-element grid.
+    # (block_q × block_k) f32 p/dp/ds live simultaneously — under the
+    # same tiling rule as the forward's, whose block it keeps otherwise.
     bwd_bq = block_q
-    bwd_bk = _largest_divisor(sk, min(block_k, 512))
-    if bwd_bk < 8:
-        bwd_bk = block_k
+    bwd_bk = _tiled_block(sk, min(block_k, 512)) or block_k
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_k=bwd_bk, sk=sk, causal=causal, sq=sq,
@@ -485,11 +485,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
                     kv_lengths=None):
     """Pallas TPU flash attention.
 
-    Default blocks (q 256 × k 1024) are tuned on a v5e: measured (scan-
-    loop methodology, r3) 14.2 vs 12.3 TFLOP/s for the XLA blockwise
-    formulation at [4, 2048, 8, 128] and 42.9 vs 28.5 at [1, 8192, 8,
-    128] — 1.50× at long sequence, and 1.64× over
-    jax.experimental.pallas.ops.tpu.flash_attention at the 2048 shape.
+    Default block caps (q 256 × k 1024) date from an earlier round's
+    v5e tuning; they have not been re-measured on today's code.
 
     ``layout`` (VERDICT r3 #8 — the transpose tax):
 
@@ -518,13 +515,15 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
     and both backward kernels), and whole key blocks beyond the length
     are skipped.  See ``naive_attention`` for the padded-query caveat.
 
-    Awkward (prime-ish) lengths with no block divisor >= 8 are handled
-    by padding q/k/v up to a 128-multiple: padded keys ride the same
-    kv_lengths masking, padded query rows are sliced off (their dout is
-    zero through the slice's VJP, so real dk/dv are exact).  The one
-    shape that still raises is causal attention at CROSS lengths
-    (sq != sk) with no usable divisor — equal padding would break the
-    q_pos = i + sk - sq alignment there.
+    Lengths with no block that meets the TPU tiling rule (see
+    ``_tiled_block``) are handled by padding q/k/v up to a
+    128-multiple: padded keys ride the same kv_lengths masking, padded
+    query rows are sliced off (their dout is zero through the slice's
+    VJP, so real dk/dv are exact).  Shapes that still raise
+    (``_flash_plan`` names the reason): causal attention at CROSS
+    lengths (sq != sk) that would need padding — equal padding would
+    break the q_pos = i + sk - sq alignment there; causal sq > sk; and
+    sequences past the VMEM bound of the whole-K/V blocking.
     """
     if layout == "bshd":
         b, sq, h, d = q.shape
@@ -535,55 +534,10 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
     else:
         raise ValueError(f"layout must be 'bshd' or 'bhsd', got {layout!r}")
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    # clamp to the sequence, then fall back to the largest divisor so any
-    # seq length that has a usable block works with the tuned defaults
-    # (e.g. 384 % 256 != 0 → block_q 128)
-    cap_q, cap_k = block_q, block_k
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q:
-        block_q = _largest_divisor(sq, block_q)
-    if sk % block_k:
-        block_k = _largest_divisor(sk, block_k)
-    pad_q = pad_k = 0
-    if min(block_q, block_k) < 8:
-        # awkward (prime-ish) lengths: PAD up to a 128-multiple and
-        # mask.  Padded keys ride the kv_lengths kernel masking (scores
-        # masked, whole padded blocks skipped); padded query rows are
-        # sliced off the output, and the slice's VJP zero-fills their
-        # dout, so they contribute nothing to dk/dv of real keys.
-        # Causal alignment (q_pos = i + sk − sq) survives because both
-        # sides pad by the SAME amount — which requires sq == sk; the
-        # causal cross-length case keeps the loud error.
-        if causal and sq != sk:
-            raise ValueError(
-                f"causal flash attention at cross lengths (sq={sq}, "
-                f"sk={sk}) needs a block divisor >= 8 on both — use "
-                "blockwise/naive attention")
-        if block_q < 8 or (causal and block_k < 8):
-            pad_q = -sq % 128
-        if block_k < 8 or (causal and block_q < 8):
-            pad_k = -sk % 128
-        block_q = _largest_divisor(sq + pad_q, min(cap_q, sq + pad_q))
-        block_k = _largest_divisor(sk + pad_k, min(cap_k, sk + pad_k))
-    if min(block_q, block_k) < 8:
-        # only reachable via caller-supplied tiny block caps (padding
-        # guarantees a >= 128 divisor otherwise) — keep the loud error
-        # instead of handing the pallas kernel a sub-sublane tile
-        raise ValueError(
-            f"flash attention blocks (block_q={block_q}, "
-            f"block_k={block_k}) must be >= 8 (TPU sublane tiling)")
-    if causal and sq > sk:
-        # rows aligned before the first key are FULLY masked; their
-        # backward replay (p = exp(s − lse)) would cancel the finite
-        # NEG_INF sentinel into phantom 1/n probabilities and corrupt
-        # dk/dv of valid rows — and the forward's "output" for such rows
-        # is meaningless anyway.  blockwise/naive keep the where-based
-        # autodiff semantics for this degenerate shape.
-        raise ValueError(
-            f"causal flash attention needs sq <= sk (got sq={sq}, "
-            f"sk={sk}): rows before the first key are fully masked — "
-            "use blockwise/naive attention")
+    plan, why = _flash_plan(causal, sq, sk, d, q.dtype, block_q, block_k)
+    if plan is None:
+        raise ValueError(why)
+    block_q, block_k, pad_q, pad_k = plan
     if layout == "bshd":
         # fold batch and heads into the grid's first axis — a materialized
         # transpose (see docstring; pass layout="bhsd" to avoid it)
@@ -625,19 +579,173 @@ def _largest_divisor(n: int, cap: int) -> int:
     return 1
 
 
-def _flash_supports(causal: bool, sq: int, sk: int) -> bool:
-    """Can ``flash_attention`` (at its default block caps) run this
-    shape?  Pad-and-mask covers every length except the causal CROSS
-    shapes: sq > sk has fully-masked rows, and sq != sk with no block
-    divisor >= 8 cannot pad both sides equally (the q_pos alignment).
-    The single eligibility predicate for both dispatchers — keep in
-    sync with flash_attention's internal raise."""
+def _tiled_block(n: int, cap: int) -> int:
+    """Largest block <= ``cap`` that divides ``n`` AND meets the TPU
+    tiling rule of every spec the kernels feed it to; 0 when none does.
+
+    A q block is the sublane dim of the ``(block, d)`` q/o/do tiles
+    (multiple of 8) and the LANE dim of the ``(1, block)`` lse/delta
+    statistics and of the dkv kernel's dynamic slices into them
+    (multiple of 128 — "or the full dim" satisfies the BlockSpec but
+    not the slice: Mosaic must prove the lane offset a multiple of
+    128); a k block is the sublane dim of the backward's ``(block, d)``
+    k/v tiles and of the in-kernel K/V slices, and the lane dim of the
+    ``(block_q, block_k)`` score tile.  One rule covers them all: a
+    multiple of 128.  The interpreter has no tiles and would accept any
+    divisor — the rule is the chip compiler's, applied everywhere so
+    CPU tests exercise the blocks the chip gets."""
+    for blk in range(cap - cap % 128, 0, -128):
+        if n % blk == 0:
+            return blk
+    return 0
+
+
+# Scoped VMEM the TPU compiler grants one kernel (v5e, libtpu 0.0.34:
+# "limit 16.00M").  Every kernel takes a whole sequence as ONE block —
+# K and V (sk, d) in fwd/dq, Q and dO (sq, d) in dkv — and the pipeline
+# double-buffers each, with d padded to the 128-lane tile:
+#   resident = 2 arrays x 2 buffers x max(sq, sk) x roundup(d, 128)
+#              x itemsize
+# The boundary is the compiler's, asked at 12 heads, causal, masked and
+# unmasked, d 32..256, bf16 and f32: every probe with resident <= 12 MiB
+# compiled (fwd, dq and dkv); the first refusals are at 14 MiB (d 128)
+# and 15 MiB (d 64), and between 13 and 16 MiB the outcome is irregular
+# (what else the kernel keeps varies) — hence a 4 MiB reserve, not a
+# derived figure.  So bf16 runs to sk 12288 and f32 to 6144 (d <= 128).
+# Streaming K/V would lift the bound (ROADMAP S7).
+_VMEM_LIMIT = 16 * 2 ** 20
+_VMEM_RESERVE = 4 * 2 ** 20
+_VMEM_BUDGET = _VMEM_LIMIT - _VMEM_RESERVE
+
+
+def _flash_resident_bytes(sq: int, sk: int, d: int, dtype) -> int:
+    """VMEM the whole-sequence blocks pin (see ``_VMEM_LIMIT``)."""
+    lanes = -(-d // 128) * 128
+    return 4 * max(sq, sk) * lanes * jnp.dtype(dtype).itemsize
+
+
+def _flash_plan(causal: bool, sq: int, sk: int, d: int, dtype,
+                cap_q: int = 256, cap_k: int = 1024):
+    """The static block/pad decision for one shape: returns
+    ``((block_q, block_k, pad_q, pad_k), None)``, or ``(None, reason)``
+    when the kernels cannot run it.  The single source of eligibility:
+    ``flash_attention`` raises ``reason``, ``_flash_supports`` (the
+    dispatchers' predicate) is ``plan is not None``."""
     if causal and sq > sk:
-        return False
-    if causal and sq != sk and min(_largest_divisor(sq, 256),
-                                   _largest_divisor(sk, 1024)) < 8:
-        return False
-    return True
+        # rows aligned before the first key are FULLY masked; their
+        # backward replay (p = exp(s − lse)) would cancel the finite
+        # NEG_INF sentinel into phantom 1/n probabilities and corrupt
+        # dk/dv of valid rows — and the forward's "output" for such rows
+        # is meaningless anyway.  blockwise/naive keep the where-based
+        # autodiff semantics for this degenerate shape.
+        return None, (
+            f"causal flash attention needs sq <= sk (got sq={sq}, "
+            f"sk={sk}): rows before the first key are fully masked — "
+            "use blockwise/naive attention")
+    block_q, block_k = _tiled_block(sq, cap_q), _tiled_block(sk, cap_k)
+    pad_q = pad_k = 0
+    if not (block_q and block_k):
+        # no tileable divisor: PAD up to a 128-multiple and mask.
+        # Padded keys ride the kv_lengths kernel masking (scores
+        # masked, whole padded blocks skipped); padded query rows are
+        # sliced off the output, and the slice's VJP zero-fills their
+        # dout, so they contribute nothing to dk/dv of real keys.
+        # Causal alignment (q_pos = i + sk − sq) survives because both
+        # sides pad by the SAME amount — which requires sq == sk.
+        if causal and sq != sk:
+            return None, (
+                f"causal flash attention at cross lengths (sq={sq}, "
+                f"sk={sk}) needs a tileable block divisor on both — "
+                "use blockwise/naive attention")
+        if not block_q or (causal and not block_k):
+            pad_q = -sq % 128
+        if not block_k or (causal and not block_q):
+            pad_k = -sk % 128
+        block_q = _tiled_block(sq + pad_q, cap_q)
+        block_k = _tiled_block(sk + pad_k, cap_k)
+    if not (block_q and block_k):
+        # only reachable via caller-supplied block caps below 128
+        return None, (
+            f"flash attention block caps (block_q={cap_q}, "
+            f"block_k={cap_k}) admit no block that meets the TPU "
+            f"tiling rule for sq={sq}, sk={sk} (a multiple of 128)")
+    resident = _flash_resident_bytes(sq + pad_q, sk + pad_k, d, dtype)
+    if resident > _VMEM_BUDGET:
+        return None, (
+            f"flash attention keeps whole (seq, d) K/V and Q/dO blocks "
+            f"in VMEM: sq={sq}, sk={sk}, d={d}, "
+            f"{jnp.dtype(dtype).name} pins {resident} bytes, past the "
+            f"{_VMEM_BUDGET} the kernels may — use "
+            "blockwise attention")
+    return (block_q, block_k, pad_q, pad_k), None
+
+
+def _flash_supports(causal: bool, sq: int, sk: int, d: int,
+                    dtype) -> bool:
+    """Can ``flash_attention`` (at its default block caps) run this
+    shape on the chip?  The single eligibility predicate for both
+    dispatchers — ``_flash_plan`` is what ``flash_attention`` itself
+    raises from, so the two cannot drift."""
+    return _flash_plan(causal, sq, sk, d, dtype)[0] is not None
+
+
+@functools.lru_cache(maxsize=None)
+def _log_auto_fallback(causal, sq, sk, d, dtype_name, why):
+    """``auto`` routing an ineligible shape off the kernel ON THE CHIP
+    is a static decision on shapes — said once per shape (the cache is
+    the once), never discovered by catching the compiler."""
+    _slog.info("flash_ineligible_auto_blockwise", causal=causal, sq=sq,
+               sk=sk, d=d, dtype=dtype_name, reason=why)
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def _auto_implementation(causal: bool, sq: int, sk: int, d: int,
+                         dtype) -> str:
+    """``auto``'s choice, from the platform and the shapes alone: the
+    pallas kernel on TPU for every shape ``_flash_plan`` admits,
+    blockwise otherwise, naive for lengths whose blocks degenerate."""
+    if _on_tpu():
+        plan, why = _flash_plan(causal, sq, sk, d, dtype)
+        if plan is not None:
+            return "flash"
+        _log_auto_fallback(causal, sq, sk, d, jnp.dtype(dtype).name, why)
+    if min(_largest_divisor(sq, 256), _largest_divisor(sk, 1024)) < 8:
+        # prime-ish lengths: blocked XLA scans degenerate, use naive
+        return "naive"
+    return "blockwise"
+
+
+def _flash_over_mesh(mesh, q, k, v, causal: bool, kv_lengths,
+                     interpret: bool):
+    """The kernel under a multi-device Trainer mesh.  GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so each
+    device runs the UNCHANGED kernel on its slice: batch over the data
+    axes, heads over ``tensor`` — attention has no term across either.
+    A dim its axes do not divide stays whole (every device computes
+    it), which is also what the Trainer's replicated-batch fallback
+    needs."""
+    def dividing(n, names):
+        use = tuple(a for a in names
+                    if a in mesh.axis_names and mesh.shape[a] > 1)
+        size = math.prod(mesh.shape[a] for a in use)
+        return use if use and n % size == 0 else None
+
+    b_ax = dividing(q.shape[0], ("data", "fsdp"))
+    spec = P(b_ax, dividing(q.shape[1], ("tensor",)), None, None)
+    args, specs = [q, k, v], [spec, spec, spec]
+    if kv_lengths is not None:
+        args.append(jnp.asarray(kv_lengths))
+        specs.append(P(b_ax))
+
+    def local(q, k, v, lens=None):
+        return flash_attention(q, k, v, causal=causal, layout="bhsd",
+                               interpret=interpret, kv_lengths=lens)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=spec, check_vma=False)(*args)
 
 
 def attention_bhsd(q, k, v, causal: bool = False,
@@ -648,29 +756,29 @@ def attention_bhsd(q, k, v, causal: bool = False,
     note).  On TPU the pallas kernel consumes the layout directly; on
     other backends the arrays are transposed to the (b, s, h, d)
     contract around blockwise/naive (cheap on CPU, where this path is
-    only a test oracle).
+    only a test oracle).  Explicit ``"flash"`` on a shape the kernels
+    cannot run RAISES (never a silent fallback); ``"auto"`` decides
+    statically from the shapes (``_auto_implementation``).
 
     ``kv_lengths``: optional (batch,) valid key counts — right-padded
     batches mask keys past their length in every implementation."""
-    sq, sk = q.shape[2], k.shape[2]
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if implementation == "flash" or (
-            implementation == "auto" and on_tpu
-            and _flash_supports(causal, sq, sk)):
-        # awkward lengths pad-and-mask inside flash_attention; the one
-        # unsupported shape (causal cross-length with no divisor)
-        # RAISES there on explicit "flash" (never a silent O(S²)
-        # naive fallback) and falls through to blockwise/naive on auto
+    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+    if implementation == "auto":
+        implementation = _auto_implementation(causal, sq, sk, d, q.dtype)
+    if implementation == "flash":
+        mesh = _mesh_lib.get_step_mesh()
+        if mesh is not None and mesh.size > 1:
+            return _flash_over_mesh(mesh, q, k, v, causal, kv_lengths,
+                                    interpret=not _on_tpu())
         return flash_attention(q, k, v, causal=causal, layout="bhsd",
-                               interpret=not on_tpu,
+                               interpret=not _on_tpu(),
                                kv_lengths=kv_lengths)
-    bq, bk = _largest_divisor(sq, 256), _largest_divisor(sk, 1024)
     qs, ks, vs = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-    if implementation == "blockwise" or (
-            implementation == "auto" and min(bq, bk) >= 8):
-        out = blockwise_attention(qs, ks, vs, causal=causal, block_k=bk,
+    if implementation == "blockwise":
+        out = blockwise_attention(qs, ks, vs, causal=causal,
+                                  block_k=_largest_divisor(sk, 1024),
                                   kv_lengths=kv_lengths)
-    elif implementation in ("auto", "naive"):
+    elif implementation == "naive":
         out = naive_attention(qs, ks, vs, causal=causal,
                               kv_lengths=kv_lengths)
     else:
@@ -680,23 +788,16 @@ def attention_bhsd(q, k, v, causal: bool = False,
 
 def attention(q, k, v, causal: bool = False, implementation: str = "auto",
               kv_lengths=None):
-    """Dispatch: pallas on TPU (awkward lengths pad-and-mask inside
-    flash_attention), blockwise elsewhere; lengths with no usable block
-    divisor fall back to naive off-TPU (and for the causal cross-length
-    shape flash cannot pad)."""
-    sq, sk = q.shape[1], k.shape[1]
+    """Dispatch on the (b, s, h, d) contract: ``"auto"`` is the pallas
+    kernel on TPU for every shape it can run and blockwise/naive
+    otherwise (``_auto_implementation``)."""
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[3]
     if implementation == "auto":
-        if (jax.devices()[0].platform == "tpu"
-                and _flash_supports(causal, sq, sk)):
-            return flash_attention(q, k, v, causal=causal,
-                                   kv_lengths=kv_lengths)
-        bq, bk = _largest_divisor(sq, 256), _largest_divisor(sk, 1024)
-        if min(bq, bk) < 8:
-            # prime-ish lengths: blocked kernels degenerate, use naive
-            return naive_attention(q, k, v, causal=causal,
-                                   kv_lengths=kv_lengths)
-        return blockwise_attention(q, k, v, causal=causal, block_k=bk,
-                                   kv_lengths=kv_lengths)
+        implementation = _auto_implementation(causal, sq, sk, d, q.dtype)
+        if implementation == "blockwise":
+            return blockwise_attention(
+                q, k, v, causal=causal,
+                block_k=_largest_divisor(sk, 1024), kv_lengths=kv_lengths)
     if implementation == "flash":
         return flash_attention(q, k, v, causal=causal,
                                kv_lengths=kv_lengths)

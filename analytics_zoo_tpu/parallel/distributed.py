@@ -71,26 +71,11 @@ def maybe_initialize_distributed() -> bool:
              or os.environ.get("JAX_NUM_PROCESSES"))
     pid = (envcontract.env_str(ENV_PID)
            or os.environ.get("JAX_PROCESS_ID"))
-    requested = os.environ.get("JAX_PLATFORMS", "").strip()
-    if requested:
-        # honor the launcher's platform choice explicitly — an installed
-        # accelerator plugin can otherwise pre-empt the env var and pull
-        # a simulated pod onto the real device
-        try:
-            jax.config.update("jax_platforms", requested)
-        except Exception as e:
-            log.warning(
-                "could not force jax platform %r (%s) — if the backend "
-                "was already initialized on an accelerator plugin, this "
-                "pod process may run on the wrong platform", requested, e)
-    if requested == "cpu":
+    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
         # multi-process CPU (the test/dryrun substrate — SURVEY §4's
         # "local device = cluster" trick at process granularity) needs the
         # gloo collectives implementation
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older/newer jaxlib without the option
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if coord:
         kwargs["coordinator_address"] = coord

@@ -31,7 +31,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import axis_size, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -128,7 +128,7 @@ def _moe_local(x, params: MoEParams, n_experts: int, capacity: int,
                axis_name: str):
     """Per-device body under shard_map: x is this device's token shard,
     expert weights are this device's expert shard."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     e_local = n_experts // n
     # routing needs ALL experts' gate columns — gate is replicated
     dispatch, combine, (f, p) = _route(x, params.gate, n_experts,

@@ -110,6 +110,15 @@ def get_active_mesh() -> Optional[Mesh]:
     return _ACTIVE_MESH if _ACTIVE_MESH is not None else _DEFAULT_MESH
 
 
+def get_step_mesh() -> Optional[Mesh]:
+    """The mesh of the currently-executing Trainer step, or None outside
+    one — NO fallback to the process default: code that lays work out
+    over the mesh (the flash kernel's shard_map) must only do so where
+    the arrays really are laid out on it, and a serving trace on its own
+    device is not."""
+    return _ACTIVE_MESH
+
+
 def get_default_mesh() -> Mesh:
     global _DEFAULT_MESH
     if _DEFAULT_MESH is None:

@@ -24,7 +24,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import axis_size, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -33,7 +33,7 @@ def _pipeline_local(x, params, stage_fn: Callable, n_micro: int,
     """Per-device body under shard_map.  ``x`` is the full input
     (replicated); ``params`` is this stage's slice (leading axis
     squeezed by the P(axis_name) spec to size 1 -> index [0])."""
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     local_params = jax.tree_util.tree_map(lambda p: p[0], params)
 

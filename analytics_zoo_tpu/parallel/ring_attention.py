@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import axis_size, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -71,7 +71,7 @@ def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = False,
     sequences grow at fixed ring size (measured: ring_report r5)."""
     b, sq, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     q_offset = my_idx * sq
     shard = k.shape[1]

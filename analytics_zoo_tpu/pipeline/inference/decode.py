@@ -437,6 +437,13 @@ class DecodeEngine:
                                                 self._rep)
         else:
             self._draft_params = None
+        # every plan takes the weights as its LAST runtime argument
+        # (bound by ``_plan``), never as closed-over constants: a
+        # closure bakes a private copy of the weights into each
+        # executable — at GPT-2-small widths 1.4 GB and ~55 s of
+        # compile per plan on a v5e, seven copies in HBM, and nothing
+        # the compilation cache could hold (chip_smoke.py, PR 21)
+        self._weights = (self._params, self._draft_params)
 
         # ---- device state: the persistent slot array.  jnp.zeros
         # builds ON the device (a fill, not a transfer); tok/pos for
@@ -507,13 +514,12 @@ class DecodeEngine:
         self._prefix_pool = (_PrefixPool(prefix_pool) if prefix_pool
                              else None)
         # persistent executable store: resolved once; None keeps every
-        # store branch inert.  The plans close over the params, so the
-        # weights digest rides every plan fingerprint — two engines
-        # with different weights can never share a store entry.  The
-        # draft digest and the sampling-static config ride alongside
-        # (large closed-over constants can elide from the HLO text, and
-        # two spec engines differing only in draft weights must never
-        # share a verify executable).
+        # store branch inert.  The plans are weight-agnostic (the
+        # weights are a runtime argument), but the weights digest rides
+        # every plan fingerprint anyway — a redeploy with new weights
+        # must never be answered by an entry recorded against old ones
+        # — and the draft digest and the sampling-static config ride
+        # alongside.
         self._store = _execstore().current()
         self._wdigest = (_execstore().params_digest(self._params)
                          if self._store is not None else None)
@@ -623,7 +629,7 @@ class DecodeEngine:
         return jax.vmap(pick)(logits, seed, stepc, temp, topk,
                               topp).astype(jnp.int32)
 
-    def _step_core(self, caches, tok, pos, samp):
+    def _step_core(self, caches, tok, pos, samp, weights):
         """ONE slot-array decode step over ALL ``capacity`` slots —
         the body the step, fused, and speculative plans all trace, so
         every plan's per-token numerics are identical by construction.
@@ -632,7 +638,7 @@ class DecodeEngine:
         it is attended, and admission overwrites ``[0, bucket)``
         wholesale.  Shapes depend on (capacity, max_len) only — never
         occupancy."""
-        params, hyper, max_len = self._params, self._hyper, self.max_len
+        params, hyper, max_len = weights[0], self._hyper, self.max_len
         posc = jnp.minimum(pos, max_len - 1)
         emb = _embed_token(params, tok, posc)
         logits, caches = _decode_step(params, hyper, caches, emb, posc)
@@ -641,8 +647,8 @@ class DecodeEngine:
         return (caches, nxt, jnp.minimum(pos + 1, max_len),
                 (seed, stepc + 1, temp, topk, topp))
 
-    def _step_body(self, caches, tok, pos, samp):
-        return self._step_core(caches, tok, pos, samp)
+    def _step_body(self, caches, tok, pos, samp, weights):
+        return self._step_core(caches, tok, pos, samp, weights)
 
     def _samp_specs(self):
         s0 = self._slot_sharding(1)
@@ -691,15 +697,26 @@ class DecodeEngine:
     def _plan(self, name: str, jitted, arg_specs):
         """AOT-build one decode plan: lower, consult the persistent
         executable store (read-through), compile + persist on a miss
-        (write-behind).  Returns a callable jax-level ``Compiled`` —
-        plan calls in the decode loop execute a fixed binary, never
-        trace.  The fingerprint covers the lowered HLO text (graph +
-        every shape; large closed-over constants may be elided from
-        it, which is exactly why the weights digest rides alongside),
+        (write-behind).  ``jitted`` takes the plan's state arguments
+        then the weights bundle; the returned callable is the
+        jax-level ``Compiled`` with the engine's weights bound as that
+        last argument — plan calls in the decode loop execute a fixed
+        binary, never trace.  The fingerprint covers the lowered HLO
+        text (graph + every shape), the weights digest (the compiled
+        code is weight-agnostic, but a redeploy with new weights must
+        never be answered by an entry recorded against old ones),
         the (capacity, max_len) tuple, and the runtime environment; a
         corrupt or unloadable entry counts ``invalid`` and falls back
         to the compile — never to a wrong executable."""
-        lowered = jitted.lower(*arg_specs)
+        weights = self._weights
+        lowered = jitted.lower(*arg_specs, jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            weights))
+
+        def bound(compiled):
+            return lambda *args: compiled(*args, weights)
+
         store = self._store
         fp = None
         if store is not None:
@@ -713,7 +730,10 @@ class DecodeEngine:
             ent = store.lookup(fp)
             if ent is not None:
                 try:
-                    return es.rehydrate(ent.payload)
+                    return bound(es.rehydrate(
+                        ent.payload,
+                        (self._device,) if self._mesh is None
+                        else self._mesh.devices.flat))
                 except Exception as e:  # noqa: BLE001 — fall back to
                     # the compile below on any rehydration failure
                     store.note_invalid(fp, e)
@@ -735,7 +755,7 @@ class DecodeEngine:
                 # best-effort: serving proceeds on the fresh compile
                 _slog.error("decode_plan_store_failed", plan=name,
                             error=f"{type(e).__name__}: {e}")
-        return compiled
+        return bound(compiled)
 
     def _build_step_plan(self):
         """The persistent single-step plan: (caches, tok, pos, samp)
@@ -767,10 +787,10 @@ class DecodeEngine:
         ``_choose_fuse``), so batching stays iteration-level exactly
         when iteration-level matters."""
 
-        def stepk(caches, tok, pos, samp):
+        def stepk(caches, tok, pos, samp, weights):
             def body(carry, _):
                 c, t, p, sm = carry
-                c, t, p, sm = self._step_body(c, t, p, sm)
+                c, t, p, sm = self._step_body(c, t, p, sm, weights)
                 return (c, t, p, sm), t
 
             (caches, tok, pos, samp), toks = lax.scan(
@@ -805,10 +825,12 @@ class DecodeEngine:
         cache lines a later step overwrites before attending — the
         same write-then-attend invariant free slots rely on."""
         k = self.spec_tokens
-        params, hyper, max_len = self._params, self._hyper, self.max_len
-        dparams, dhyper = self._draft_params, self._draft_hyper
+        hyper, max_len = self._hyper, self.max_len
+        dhyper = self._draft_hyper
 
-        def spec(caches, dcaches, tok, pos, samp):
+        def spec(caches, dcaches, tok, pos, samp, weights):
+            params, dparams = weights
+
             def dbody(carry, _):
                 dc, t, p = carry
                 posc = jnp.minimum(p, max_len - 1)
@@ -823,7 +845,8 @@ class DecodeEngine:
             dprops = dprops[:k - 1]  # (k-1, capacity)
             # the exact fallback token — bit-identical to the
             # non-speculative step plan by shared trace
-            caches, t0, _, _ = self._step_core(caches, tok, pos, samp)
+            caches, t0, _, _ = self._step_core(caches, tok, pos, samp,
+                                               weights)
             # windowed verify of the proposals at pos+1 .. pos+k-1
             embs = [_embed_token(params, dprops[j],
                                  jnp.minimum(pos + 1 + j, max_len - 1))
@@ -899,11 +922,11 @@ class DecodeEngine:
         all one executable, so admitting is a single dispatch.  A
         drafted engine's plan also prefills the DRAFT's caches for the
         prompt (the draft must enter the window in lockstep)."""
-        params, hyper = self._params, self._hyper
-        dparams, dhyper = self._draft_params, self._draft_hyper
+        hyper, dhyper = self._hyper, self._draft_hyper
 
         def admit(caches, dcaches, tok, pos, samp, prompt, length,
-                  slot, seed0, temp0, topk0, topp0):
+                  slot, seed0, temp0, topk0, topp0, weights):
+            params, dparams = weights
             x, pc = _prefill(params, hyper, prompt, s_b)
             last = lax.dynamic_index_in_dim(x[0], length - 1,
                                             keepdims=False)
@@ -969,10 +992,10 @@ class DecodeEngine:
         Runs ONCE per distinct prefix content (the pool miss); its
         outputs are exactly what a pool hit memcpys, which is why hit
         and miss admissions are bit-identical."""
-        params, hyper = self._params, self._hyper
+        hyper = self._hyper
 
-        def fill(prefix):
-            x, pc = _prefill(params, hyper, prefix, p_b)
+        def fill(prefix, weights):
+            x, pc = _prefill(weights[0], hyper, prefix, p_b)
             return pc, x[0, p_b - 1]
 
         return jax.jit(fill)
@@ -1009,11 +1032,12 @@ class DecodeEngine:
         admissions reuse the pooled last-hidden for the first token's
         logits; the p_b == s_b variant compiles without any tail
         compute at all."""
-        params, hyper = self._params, self._hyper
+        hyper = self._hyper
         tail_pad = s_b - p_b
 
         def padmit(caches, tok, pos, samp, pkv, h_pfx, tail, length,
-                   slot, seed0, temp0, topk0, topp0):
+                   slot, seed0, temp0, topk0, topp0, weights):
+            params = weights[0]
             if tail_pad:
                 xt, tc = _prefill_ext(params, hyper, tail, pkv, p_b)
             new_caches = []

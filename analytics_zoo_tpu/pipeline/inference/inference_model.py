@@ -93,7 +93,7 @@ class InferenceModel:
           batch-global, so padding would perturb real rows).
         * ``coalescing`` — concurrent ``predict()`` callers are packed
           by a dispatcher thread into ONE padded device batch per
-          dispatch (amortizing the ~4-8 ms dispatch floor), waiting at
+          dispatch (amortizing the per-dispatch floor), waiting at
           most ``max_wait_ms`` to fill ``max_batch_size`` rows; results
           fan back out bit-identical to solo runs.
         * ``replicas`` — ``"all"`` or an int N: place each bucket
@@ -313,18 +313,11 @@ class InferenceModel:
         # a raw jax fn is not a quantized registry handle — a stale flag
         # from a previous quantized load must not disable the fast path
         self._quantize_flag = False
-        # close over the placed params instead of passing the tree per
-        # call: weights are fixed for the lifetime of a load (reload
-        # re-installs), and flattening a many-leaf tree on every call is
-        # measurable against the per-dispatch floor
-        params_dev = self._params
-        predict_fn = jax.jit(lambda x: fn(params_dev, x))
-        # hand the PLACED tree to the replica path: device_put of an
-        # array already committed to the target device is a no-op, so
-        # replica 0 shares the closure's buffers instead of pinning a
-        # second copy of the weights in device-0 memory
-        self._install(predict_fn, replica_fn=fn,
-                      replica_params=self._params)
+        # hand the PLACED tree on: device_put of an array already
+        # committed to the target device is a no-op, so replica 0 shares
+        # these buffers instead of pinning a second copy of the weights
+        # in device-0 memory
+        self._install(fn, self._params)
         return self
 
     def _attach(self, graph, params, state):
@@ -332,23 +325,12 @@ class InferenceModel:
         self._params = params
         self._state = state
 
-        # params/state are captured as jit closure constants — per-call
-        # python arg processing shrinks to the batch alone (weights are
-        # fixed until the next load, which re-installs)
-        @jax.jit
-        def predict_fn(x):
-            out, _ = graph.apply(params, state, x, training=False)
-            return out
-
-        def replica_fn(bundle, x):
-            # the replica path needs the weights as an ARGUMENT (placed
-            # per device by the ReplicaSet), not a closure constant
+        def forward(bundle, x):
             out, _ = graph.apply(bundle["params"], bundle["state"], x,
                                  training=False)
             return out
 
-        self._install(predict_fn, replica_fn=replica_fn,
-                      replica_params={"params": params, "state": state})
+        self._install(forward, {"params": params, "state": state})
 
     def _resolve_replicas(self) -> int:
         """The effective replica count: the request ("all" or an int),
@@ -365,10 +347,19 @@ class InferenceModel:
             raise ValueError(f"replicas must be >= 1, got {n}")
         return min(n, avail)
 
-    def _install(self, predict_fn, replica_fn=None, replica_params=None):
-        """Install the forward and (re)build the serving fast path for
-        it: bucketed executable cache (optionally replicated across
-        local devices) + optional coalescer.  Quantized handles stay on
+    def _install(self, fn, params):
+        """Install the forward ``fn(params, x)`` and (re)build the
+        serving fast path for it: bucketed executable cache (optionally
+        replicated across local devices) + optional coalescer.
+
+        The weights are a runtime ARGUMENT of the one jitted forward —
+        bound here for the single-device path, placed per device by the
+        ReplicaSet — never closed-over constants: a closure bakes a
+        private copy of the weights into every bucket's executable (N
+        buckets = N copies in device memory, compiles that grow with
+        the model, entries no compilation cache can hold).
+
+        Quantized handles stay on
         the exact-shape path — their dynamic activation scales are
         batch-global, so padded filler rows would change real-row
         outputs.
@@ -378,6 +369,11 @@ class InferenceModel:
         closed — its already-queued requests drain through the OLD
         executables while new traffic flows to the new ones.  No request
         is ever abandoned or served by a half-swapped path."""
+        forward = jax.jit(fn)
+
+        def predict_fn(x):
+            return forward(params, x)
+
         old_coalescer = self._coalescer
         cache = None
         coalescer = None
@@ -390,20 +386,20 @@ class InferenceModel:
             # raw executables, and only the replica path dispatches
             # them — this is what makes a warm-store deploy()
             # zero-compile even on one device.  Store off, one device:
-            # the closure-jit path of PR 1, bit-for-bit unchanged.
+            # the plain jitted forward (``predict_fn``).
             store_on = _execstore().current() is not None
-            if self._mesh is not None and replica_fn is not None:
+            if self._mesh is not None:
                 # sharded serving: the mesh spec (not ``replicas``)
                 # decides how many groups the local device set carves
                 # into; one sharded compile, every further group is a
                 # device-assignment rewrite
                 from ...serving.shardgroup import ShardGroupSet
                 replica_set = ShardGroupSet(
-                    replica_fn, replica_params, self._mesh,
+                    fn, params, self._mesh,
                     devices=jax.local_devices(), tag=self.store_tag)
-            elif (n_rep > 1 or store_on) and replica_fn is not None:
+            elif n_rep > 1 or store_on:
                 replica_set = ReplicaSet(
-                    replica_fn, replica_params,
+                    fn, params,
                     devices=jax.local_devices()[:n_rep],
                     tag=self.store_tag)
             cache = BucketedExecutableCache(

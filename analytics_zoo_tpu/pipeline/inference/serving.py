@@ -1,6 +1,6 @@
 """Serving fast path: shape-bucketed executables + request coalescing.
 
-Two measured walls motivate this module (PERF_NOTES):
+Two walls motivate this module:
 
 * **Compile-per-shape.** A jitted forward re-traces for every distinct
   batch size, so a live request stream with ragged batch sizes compiles
@@ -9,12 +9,12 @@ Two measured walls motivate this module (PERF_NOTES):
   default) so the whole stream is served by a handful of pre-compilable
   executables, with per-bucket hit/miss/compile-time counters and an
   AOT ``warmup``.
-* **Per-dispatch floor.** A dispatched computation has a ~4-8 ms floor
-  (PERF_NOTES §"Per-dispatch floor"), so one device call per request
-  caps throughput regardless of model size.  ``RequestCoalescer`` packs
-  concurrent ``predict()`` callers into ONE padded device batch per
-  dispatch and fans the rows back out — amortizing the floor across
-  every rider.
+* **Per-dispatch floor.** A dispatched computation pays a fixed host
+  cost whatever its size (not measured on today's chip), so one device
+  call per request caps throughput for small models.
+  ``RequestCoalescer`` packs concurrent ``predict()`` callers into ONE
+  padded device batch per dispatch and fans the rows back out —
+  amortizing the floor across every rider.
 
 Padding safety: rows are independent under inference-mode forward
 passes (BatchNorm uses running stats, softmax is row-wise), so padded
@@ -48,7 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
-from jax.lib import xla_client as _xla_client
+from jaxlib import xla_client as _xla_client
 
 from ...common.utils import pad_leading as _pad_rows
 from ...observability import profile as _profile
@@ -330,7 +330,7 @@ class ReplicaSet:
         """Rehydrate serialized-executable bytes onto one replica's
         unit.  The base maps a replica to its single device; the
         sharded set rewrites the assignment to span the whole group."""
-        return self._load_serialized(ser, replica.device)
+        return self._load_serialized(ser, (replica.device,))
 
     @property
     def n(self) -> int:
@@ -373,16 +373,31 @@ class ReplicaSet:
             return all(len(self._exes[k]) == len(self.replicas)
                        for k in keys if k in self._exes)
 
-    def _load_serialized(self, ser: bytes, device):
-        """Load serialized-executable bytes onto ``device``: fresh
-        single-device CompileOptions with only the device assignment
-        set — the PR 5 round trip, now also how a store entry
-        rehydrates (it works with no original executable in hand).  A
-        load, not a compile: no ``backend_compile`` event fires."""
+    @staticmethod
+    def _unwrap(compiled) -> Tuple[Any, Optional[Sequence[int]]]:
+        """(PJRT executable, kept-input indices) of a jax ``Compiled``
+        for the raw dispatch path.  ``runtime_executable()`` is public;
+        the set of inputs XLA did not prune (``_kept_var_idx``) has no
+        public accessor — with ``_load_serialized`` below, the only
+        reach under jax's public surface, kept side by side."""
+        return (compiled.runtime_executable(),
+                getattr(compiled._executable, "_kept_var_idx", None))
+
+    def _load_serialized(self, ser: bytes, devices: Sequence):
+        """Load serialized-executable bytes onto ``devices`` (one
+        replica, ``len(devices)`` partitions) with only the device
+        assignment rewritten — also how a store entry rehydrates (it
+        works with no original executable in hand).  A load, not a
+        compile: no ``backend_compile`` event fires.
+
+        ``jax.experimental.serialize_executable`` pickles device ids
+        with the executable and cannot relocate it, so the PJRT client
+        is called directly."""
         opts = _xla_client.CompileOptions()
         opts.device_assignment = _xla_client.DeviceAssignment.create(
-            np.array([[device.id]], dtype=np.int32))
-        return self._backend.deserialize_executable(ser, opts)
+            np.array([[d.id for d in devices]], dtype=np.int32))
+        return self._backend.deserialize_executable(
+            ser, _xla_client.DeviceList(tuple(devices)), opts)
 
     def ensure_compiled(self, batched, key: Optional[Tuple] = None
                         ) -> float:
@@ -470,10 +485,7 @@ class ReplicaSet:
                 # the ONE traced lowering + XLA compile for this
                 # signature (this is the call the backend_compile
                 # counter sees)
-                compiled = lowered.compile()
-                mexe = compiled._executable
-                exe0 = mexe.xla_executable
-                kept = getattr(mexe, "_kept_var_idx", None)
+                exe0, kept = self._unwrap(lowered.compile())
                 kept_t = (None if kept is None or len(kept) == n_in
                           else tuple(sorted(kept)))
                 if len(self.replicas) > 1:

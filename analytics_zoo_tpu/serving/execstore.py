@@ -120,10 +120,7 @@ def hlo_digest(lowered) -> str:
     Large constants may be elided from the text, which is why every
     caller ALSO folds a :func:`params_digest` of the weights into its
     fingerprint."""
-    try:
-        text = lowered.compiler_ir(dialect="hlo").as_hlo_text()
-    except Exception:  # older/newer IR surface: StableHLO text
-        text = lowered.as_text()
+    text = lowered.compiler_ir(dialect="hlo").as_hlo_text()
     return hashlib.sha256(text.encode()).hexdigest()
 
 
@@ -154,15 +151,19 @@ def serialize_compiled(compiled) -> bytes:
     return pickle.dumps((payload, in_tree, out_tree), protocol=4)
 
 
-def rehydrate(payload: bytes):
+def rehydrate(payload: bytes, devices):
     """Store payload bytes back into a callable jax-level ``Compiled``
-    — a LOAD, not a compile: no ``backend_compile`` event fires, and
-    calling the result is bit-identical to calling the freshly
-    compiled original (same binary).  Raises on any malformed payload
-    (callers fall back to compiling)."""
+    on ``devices`` (the devices it was compiled for, in assignment
+    order) — a LOAD, not a compile: no ``backend_compile`` event
+    fires, and calling the result is bit-identical to calling the
+    freshly compiled original (same binary).  Raises on any malformed
+    payload (callers fall back to compiling)."""
     from jax.experimental import serialize_executable as _se
     ser, in_tree, out_tree = pickle.loads(payload)
-    return _se.deserialize_and_load(ser, in_tree, out_tree)
+    devices = list(devices)
+    return _se.deserialize_and_load(ser, in_tree, out_tree,
+                                    backend=devices[0].client,
+                                    execution_devices=devices)
 
 
 class StoreEntry:

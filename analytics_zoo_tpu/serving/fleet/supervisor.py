@@ -31,6 +31,13 @@ new worker warms from the shared execstore via the same
 ``retire`` is the deliberate scale-down terminal — marked BEFORE the
 terminate so the monitor never mistakes a drained worker's exit for
 a crash.  The router owns the drain discipline around these.
+
+Devices: workers inherit the supervisor's environment and nothing here
+assigns a device to a child.  A chip belongs to one process at a time,
+so a LOCAL fleet is CPU worker processes (or at most one worker per
+chip-holding machine); on a pod, run one supervised worker per chip.
+The supervisor/router parent itself never initialises a jax backend —
+it only moves bytes — so it can front a worker that holds the chip.
 """
 
 from __future__ import annotations

@@ -54,7 +54,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.lib import xla_client as _xla_client
 
 from ..observability import profile as _profile
 from ..observability.log import get_logger as _get_logger
@@ -299,10 +298,7 @@ class ShardGroupSet(ReplicaSet):
         the group's devices — instead of the base class's ``(1, 1)``.
         Still a load, never a compile: zero ``backend_compile`` events
         (the bench's ``SHARDED_ZERO_COMPILE`` gate counts)."""
-        opts = _xla_client.CompileOptions()
-        opts.device_assignment = _xla_client.DeviceAssignment.create(
-            np.array([[d.id for d in group.devices]], dtype=np.int32))
-        return self._backend.deserialize_executable(ser, opts)
+        return self._load_serialized(ser, group.devices)
 
     # ---- identity / introspection ----
     @property

@@ -341,9 +341,21 @@ class Trainer:
         params = jax.tree_util.tree_map(
             lambda p, s: jax.device_put(p, s), params, self._param_shardings)
         model_state = jax.device_put(model_state, self._repl_sharding)
-        opt_state = self.optimizer.init(params)
-        self.state = TrainState(params, model_state, opt_state,
+        self.state = TrainState(params, model_state,
+                                self._init_opt_state(params),
                                 rng=loop_rng)
+
+    def _init_opt_state(self, params):
+        """Optimizer state from the PLACED params (moments inherit their
+        shardings), then committed whole to the layout the compiled step
+        declares.  optax's housekeeping scalars (Adam's ``count``) are
+        born uncommitted on the default device, and an uncommitted
+        first-step argument makes the step compile a SECOND time when
+        the committed output comes back in as step two's input."""
+        opt_state = self.optimizer.init(params)
+        return jax.device_put(
+            opt_state, sharding_lib.opt_state_sharding_tree(
+                opt_state, params, self._param_shardings, self.mesh))
 
     def adopt_weights(self, params, model_state=None):
         """Replace weights with an externally provided pytree, re-placed
@@ -386,12 +398,12 @@ class Trainer:
         model_state = jax.device_put(model_state, self._repl_sharding)
         if self.state is None:
             self.state = TrainState(placed, model_state,
-                                    self.optimizer.init(placed),
+                                    self._init_opt_state(placed),
                                     rng=loop_rng)
         else:
             self.state.params = placed
             self.state.model_state = model_state
-            self.state.opt_state = self.optimizer.init(placed)
+            self.state.opt_state = self._init_opt_state(placed)
 
     # ------------------------------------------------------------------
     def _mesh_scoped(self, fn):
@@ -433,6 +445,19 @@ class Trainer:
                                 compute_dtype=self.compute_dtype,
                                 accum_steps=self.accum_steps,
                                 in_shardings=in_sh, out_shardings=out_sh)
+
+    def lower_train_step(self, x, y):
+        """AOT-lower THE step ``fit`` runs, for one host batch of the
+        global batch size: ``.compile()`` the result to read
+        ``as_text()`` / ``memory_analysis()`` of exactly that program.
+        Inspection only — lowering needs shapes, so no state is donated
+        or advanced, and ``fit`` keeps its own compiled step."""
+        self.ensure_initialized()
+        st = self.state
+        bx, by = self._stage_batch(x, y)
+        with mesh_lib.active_mesh(self.mesh):
+            return self._build_train_step().lower(
+                st.params, st.model_state, st.opt_state, st.rng, bx, by)
 
     def _build_eval_step(self, metrics: Optional[Sequence] = None):
         model = self.model
@@ -505,6 +530,13 @@ class Trainer:
         sy = (tuple(split(a) for a in y) if isinstance(y, (tuple, list))
               else split(y))
         return sx, sy
+
+    def _stage_batch(self, x, y):
+        """Host batch -> placed step input: the microbatch split (under
+        gradient accumulation) then the per-shard upload."""
+        if self.accum_steps > 1:
+            x, y = self._split_microbatches(x, y)
+        return self._put_batch(x, y, microbatched=self.accum_steps > 1)
 
     def _put_batch(self, x, y, microbatched: bool = False):
         """Place a host-local batch onto the mesh, per-shard: the
@@ -695,23 +727,17 @@ class Trainer:
         profiling = False
         profile_end_step = None
         if self._profile_dir is not None:
-            try:
-                jax.profiler.start_trace(self._profile_dir)
-                profiling = True
-                profile_end_step = st.step + self._profile_steps
-            except Exception as e:  # tracing is best-effort telemetry
-                import logging
-                logging.getLogger("analytics_zoo_tpu").warning(
-                    "could not start jax.profiler trace: %s", e)
+            # a trace that was asked for and cannot start is an error,
+            # not a quieter run
+            jax.profiler.start_trace(self._profile_dir)
+            profiling = True
+            profile_end_step = st.step + self._profile_steps
 
         def _stop_profile():
             nonlocal profiling
             if profiling:
                 profiling = False
-                try:
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass
+                jax.profiler.stop_trace()
 
         try:
             while True:
@@ -738,12 +764,7 @@ class Trainer:
                     resume_skip = 0
                 accum = self.accum_steps
                 if prof is None:
-                    if accum == 1:
-                        put_fn = lambda b: self._put_batch(*b)
-                    else:
-                        put_fn = lambda b: self._put_batch(
-                            *self._split_microbatches(*b),
-                            microbatched=True)
+                    put_fn = lambda b: self._stage_batch(*b)
                 else:
                     def put_fn(b):
                         # grad_accum (host microbatch split) and h2d

@@ -68,11 +68,13 @@ def test_blockwise_kv_lengths_matches_naive(causal):
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_kv_lengths_matches_naive(causal):
     """Kernel-level masking: lengths that straddle key-block boundaries
-    (block_k=16; 37 = 2 blocks + 5, 5 = partial first block)."""
-    q, k, v = qkv()
-    ref = naive_attention(q, k, v, causal=causal, kv_lengths=LENS)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
-                          interpret=True, kv_lengths=LENS)
+    (block_k=128 — the smallest block the TPU tiling rule admits;
+    293 = 2 blocks + 37, 5 = partial first block)."""
+    q, k, v = qkv(s=512)
+    lens = np.array([512, 293, 5])
+    ref = naive_attention(q, k, v, causal=causal, kv_lengths=lens)
+    out = flash_attention(q, k, v, causal=causal, block_q=128,
+                          block_k=128, interpret=True, kv_lengths=lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
 
@@ -82,21 +84,22 @@ def test_flash_backward_kv_lengths_matches_naive(causal):
     """The custom-VJP backward kernels replay the mask: dq/dk/dv must
     match autodiff through the masked naive oracle, and grads of padded
     keys/values must be exactly zero."""
-    q, k, v = qkv(b=2, s=32, h=2, d=8, seed=1)
-    lens = np.array([32, 11])
+    s = 256
+    q, k, v = qkv(b=2, s=s, h=2, d=8, seed=1)
+    lens = np.array([s, 139])  # full; one block + 11
 
     def loss_naive(q, k, v):
         # padded-query rows are garbage by contract: weight them zero,
         # as a sequence loss would
         o = naive_attention(q, k, v, causal=causal, kv_lengths=lens)
-        w = (np.arange(32)[None, :, None, None]
+        w = (np.arange(s)[None, :, None, None]
              < lens[:, None, None, None])
         return jnp.sum(jnp.where(w, o, 0.0) ** 2)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, block_q=8, block_k=8,
-                            interpret=True, kv_lengths=lens)
-        w = (np.arange(32)[None, :, None, None]
+        o = flash_attention(q, k, v, causal=causal, block_q=128,
+                            block_k=128, interpret=True, kv_lengths=lens)
+        w = (np.arange(s)[None, :, None, None]
              < lens[:, None, None, None])
         return jnp.sum(jnp.where(w, o, 0.0) ** 2)
 
@@ -106,8 +109,8 @@ def test_flash_backward_kv_lengths_matches_naive(causal):
         np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                    rtol=5e-4, atol=5e-5)
     # dk/dv of padded keys: exactly zero
-    np.testing.assert_array_equal(np.asarray(g_out[1])[1, 11:], 0.0)
-    np.testing.assert_array_equal(np.asarray(g_out[2])[1, 11:], 0.0)
+    np.testing.assert_array_equal(np.asarray(g_out[1])[1, 139:], 0.0)
+    np.testing.assert_array_equal(np.asarray(g_out[2])[1, 139:], 0.0)
 
 
 def test_attention_dispatch_passes_lengths():

@@ -34,10 +34,12 @@ def test_blockwise_matches_naive(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_kernel_matches_naive(causal):
-    q, k, v = qkv(b=1, s=128, h=2, d=32)
+    # 128 is the smallest block the TPU tiling rule admits (the rule
+    # holds in interpret mode too): 2 x 2 blocks at s=256
+    q, k, v = qkv(b=1, s=256, h=2, d=32)
     ref = naive_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
-                          interpret=True)
+    out = flash_attention(q, k, v, causal=causal, block_q=128,
+                          block_k=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
 
@@ -46,12 +48,12 @@ def test_flash_kernel_matches_naive(causal):
 def test_flash_bhsd_layout_matches_bshd(causal):
     """VERDICT r3 #8: layout='bhsd' skips the materialized transposes;
     results must be identical to the default layout."""
-    q, k, v = qkv(b=2, s=128, h=2, d=32)
-    ref = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
-                          interpret=True)
+    q, k, v = qkv(b=2, s=256, h=2, d=32)
+    ref = flash_attention(q, k, v, causal=causal, block_q=128,
+                          block_k=128, interpret=True)
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-    out = flash_attention(qt, kt, vt, causal=causal, block_q=32,
-                          block_k=32, interpret=True, layout="bhsd")
+    out = flash_attention(qt, kt, vt, causal=causal, block_q=128,
+                          block_k=128, interpret=True, layout="bhsd")
     np.testing.assert_allclose(np.asarray(out.transpose(0, 2, 1, 3)),
                                np.asarray(ref), rtol=1e-6, atol=1e-6)
     with pytest.raises(ValueError, match="layout"):
@@ -59,10 +61,10 @@ def test_flash_bhsd_layout_matches_bshd(causal):
 
 
 @pytest.mark.parametrize("causal,sq,sk,bq,bk", [
-    (True, 128, 128, 32, 32),
-    (False, 128, 128, 32, 32),
-    (True, 64, 128, 32, 32),    # rectangular: cached-kv decode shape
-    (True, 128, 128, 64, 32),   # uneven fwd blocks exercise bwd clamps
+    (True, 256, 256, 128, 128),
+    (False, 256, 256, 128, 128),
+    (True, 128, 256, 128, 128),  # rectangular: cached-kv decode shape
+    (True, 512, 512, 256, 128),  # uneven fwd blocks exercise bwd clamps
 ])
 def test_flash_backward_matches_naive(causal, sq, sk, bq, bk):
     """The custom-VJP backward (pallas dq + dk/dv kernels) must match the
@@ -93,8 +95,7 @@ def test_flash_causal_rejects_fully_masked_rows():
     q = qkv(b=1, s=96, h=2, d=32, seed=5)[0]
     _, k, v = qkv(b=1, s=48, h=2, d=32, seed=6)
     with pytest.raises(ValueError, match="sq <= sk"):
-        flash_attention(q, k, v, causal=True, block_q=32, block_k=16,
-                        interpret=True)
+        flash_attention(q, k, v, causal=True, interpret=True)
     out = attention(q, k, v, causal=True)  # auto: blockwise fallback
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(naive_attention(q, k, v, causal=True)),
@@ -107,7 +108,7 @@ def test_flash_causal_rejects_fully_masked_rows():
     (False, 131, 64),    # awkward q only
 ])
 def test_flash_pads_awkward_lengths_matches_naive(causal, sq, sk):
-    """Lengths with no block divisor >= 8 pad-and-mask inside
+    """Lengths with no tileable block divisor pad-and-mask inside
     flash_attention (r5; formerly a ValueError) — forward AND backward
     must match the naive oracle exactly, including with a kv_lengths
     ragged batch on top."""
@@ -149,10 +150,10 @@ def test_flash_backward_prime_key_length_keeps_fwd_block():
 
 def test_flash_backward_bhsd_layout():
     """Gradients flow through the transpose-free layout fold too."""
-    q, k, v = qkv(b=1, s=64, h=2, d=32, seed=4)
+    q, k, v = qkv(b=1, s=256, h=2, d=32, seed=4)
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     loss_bhsd = lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, block_q=32, block_k=32, interpret=True,
+        q, k, v, causal=True, block_q=128, block_k=128, interpret=True,
         layout="bhsd") ** 2)
     loss_naive = lambda q, k, v: jnp.sum(
         naive_attention(q, k, v, causal=True) ** 2)
@@ -300,3 +301,37 @@ def test_attention_auto_odd_lengths():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(naive_attention(q7, k7, v7)),
         rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("batch,lens", [
+    (4, None),          # batch splits over data x fsdp
+    (4, [128, 70, 5, 33]),
+    (3, None),          # 4 does not divide 3: every device runs it whole
+])
+def test_flash_under_trainer_mesh_matches_unsharded(batch, lens):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    Trainer mesh the kernel runs inside shard_map on each device's
+    slice of batch/heads — forward and backward must equal the same
+    kernel run whole (no term crosses batch or heads)."""
+    from analytics_zoo_tpu.ops.attention import attention_bhsd
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+    mesh = create_mesh({"data": 2, "fsdp": 2}, devices=jax.devices()[:4])
+    q, k, v = (a.transpose(0, 2, 1, 3)
+               for a in qkv(b=batch, s=128, h=2, d=16, seed=21))
+    lens = None if lens is None else jnp.asarray(lens, jnp.int32)
+
+    def loss(q, k, v):
+        return jnp.sum(attention_bhsd(q, k, v, causal=True,
+                                      implementation="flash",
+                                      kv_lengths=lens) ** 2)
+
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert mesh_lib.get_step_mesh() is None
+    with mesh_lib.active_mesh(mesh):
+        got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert mesh_lib.get_step_mesh() is None
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        # two separately compiled programs: float tolerance, not bits
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)

@@ -837,3 +837,27 @@ def test_cross_process_generate_determinism(tmp_path):
             assert [np.asarray(t).tolist() for t in out2] == got
     finally:
         r.close()
+
+
+def test_fleet_parent_imports_initialise_no_backend():
+    """A chip belongs to one process: the router/supervisor parent (and
+    the launcher) only move bytes and must never touch a jax backend,
+    or they would hold the chip their one worker needs.  Importing the
+    package — serving plane, fleet and launcher included — must leave
+    jax's backend table empty."""
+    import subprocess
+    import sys
+    code = (
+        "import analytics_zoo_tpu, analytics_zoo_tpu.serving, "
+        "analytics_zoo_tpu.launcher\n"
+        "from analytics_zoo_tpu.serving.fleet import router, supervisor\n"
+        "from analytics_zoo_tpu.pipeline.inference import "
+        "InferenceModel, DecodeEngine\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, xla_bridge._backends\n"
+        "print('NO_BACKEND')\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          env={**os.environ, "PYTHONPATH": REPO},
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0 and "NO_BACKEND" in proc.stdout, \
+        proc.stderr[-2000:]

