@@ -1,0 +1,11 @@
+"""Device: 1 - (union of the intervals in which an operation ran on the
+device) / (traced window), from the profiler's trace, averaged over the
+chips."""
+
+LAYER, UNIT, SOURCE, MOVES = ("Device", "%", "device_trace",
+                              "train_samples_s")
+
+
+def read(ctx):
+    from benchmark import xplane
+    return xplane.idle_share(ctx["trace"])
