@@ -25,6 +25,8 @@ import queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, Optional
 
+from ..observability.profile import annotate
+
 _END = object()
 _ERR = object()
 
@@ -42,12 +44,21 @@ def _put(q: "queue.Queue", stop: threading.Event, item) -> bool:
 
 
 def _worker(source, transform, q, stop):
+    # two host spans a batch on the profiler's clock (inert without a
+    # profiler session): the source's next(), and the transform (the
+    # trainer's microbatch split + upload)
     try:
-        for item in source:
+        source = iter(source)
+        while True:
+            with annotate("input/produce"):
+                item = next(source, _END)
+            if item is _END:
+                break
             if stop.is_set():
                 return
             if transform is not None:
-                item = transform(item)
+                with annotate("input/h2d"):
+                    item = transform(item)
             if not _put(q, stop, (None, item)):
                 return
         _put(q, stop, (_END, None))

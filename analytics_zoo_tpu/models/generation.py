@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import profile as _profile
 from ..ops.attention import attention_bhsd
 from ..parallel.expert import MoEParams, expert_capacity, switch_moe
 from ..pipeline.api.keras.activations import get as get_activation
@@ -88,6 +89,7 @@ def _embed_token(params, tok, pos):
     return emb + p.astype(emb.dtype)
 
 
+@jax.named_scope(_profile.SCOPE_PREFILL)
 def _prefill(params, hyper, prompt, cache_len):
     """Batched prompt pass: causal attention over the whole prompt in one
     forward (the training-shaped compute), writing each layer's K/V into
@@ -141,23 +143,25 @@ def _decode_step(params, hyper, caches, x_tok, pos):
         moe = bool(moe_every) and (i + 1) % moe_every == 0
         bp = _block_params(params, i, moe)
         ck, cv = caches[i]
-        a = _layer_norm(bp["ln_a"], x)
-        q = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wq"])
-        k = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wk"])
-        v = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wv"])
-        ck = _cache_write(ck, k, pos)
-        cv = _cache_write(cv, v, pos)
-        d = q.shape[-1]
-        scores = jnp.einsum("bhd,bhtd->bht", q, ck) / math.sqrt(d)
-        t = ck.shape[2]
-        posv = jnp.broadcast_to(pos, (ck.shape[0],))
-        valid = jnp.arange(t)[None, None, :] <= posv[:, None, None]
-        scores = jnp.where(valid, scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        o = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
-        x = x + jnp.einsum("bhd,hde->be", o, bp["attn"]["Wo"])
-        f = _layer_norm(bp["ln_m"], x)
-        x = x + _mlp(bp, f)
+        with jax.named_scope(_profile.SCOPE_DECODE_ATTENTION):
+            a = _layer_norm(bp["ln_a"], x)
+            q = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wq"])
+            k = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wk"])
+            v = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wv"])
+            ck = _cache_write(ck, k, pos)
+            cv = _cache_write(cv, v, pos)
+            d = q.shape[-1]
+            scores = jnp.einsum("bhd,bhtd->bht", q, ck) / math.sqrt(d)
+            t = ck.shape[2]
+            posv = jnp.broadcast_to(pos, (ck.shape[0],))
+            valid = jnp.arange(t)[None, None, :] <= posv[:, None, None]
+            scores = jnp.where(valid, scores, -1e30)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            o = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
+            x = x + jnp.einsum("bhd,hde->be", o, bp["attn"]["Wo"])
+        with jax.named_scope(_profile.SCOPE_DECODE_MLP):
+            f = _layer_norm(bp["ln_m"], x)
+            x = x + _mlp(bp, f)
         new_caches.append((ck, cv))
     return _head_logits(params, x), new_caches
 
@@ -189,29 +193,32 @@ def _decode_window(params, hyper, caches, x_toks, pos):
         moe = bool(moe_every) and (i + 1) % moe_every == 0
         bp = _block_params(params, i, moe)
         ck, cv = caches[i]
-        a = _layer_norm(bp["ln_a"], x)
-        q = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wq"])
-        kk = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wk"])
-        vv = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wv"])
-        for j in range(k):
-            ck = _cache_write(ck, kk[:, :, j], qpos[:, j])
-            cv = _cache_write(cv, vv[:, :, j], qpos[:, j])
-        d = q.shape[-1]
-        scores = jnp.einsum("bhkd,bhtd->bhkt", q, ck) / math.sqrt(d)
-        valid = (jnp.arange(t)[None, None, None, :]
-                 <= qpos[:, None, :, None])
-        scores = jnp.where(valid, scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        o = jnp.einsum("bhkt,bhtd->bhkd", probs.astype(cv.dtype), cv)
-        x = x + jnp.einsum("bhkd,hde->bke", o, bp["attn"]["Wo"])
-        f = _layer_norm(bp["ln_m"], x)
-        x = x + _mlp(bp, f)
+        with jax.named_scope(_profile.SCOPE_DECODE_ATTENTION):
+            a = _layer_norm(bp["ln_a"], x)
+            q = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wq"])
+            kk = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wk"])
+            vv = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wv"])
+            for j in range(k):
+                ck = _cache_write(ck, kk[:, :, j], qpos[:, j])
+                cv = _cache_write(cv, vv[:, :, j], qpos[:, j])
+            d = q.shape[-1]
+            scores = jnp.einsum("bhkd,bhtd->bhkt", q, ck) / math.sqrt(d)
+            valid = (jnp.arange(t)[None, None, None, :]
+                     <= qpos[:, None, :, None])
+            scores = jnp.where(valid, scores, -1e30)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            o = jnp.einsum("bhkt,bhtd->bhkd", probs.astype(cv.dtype), cv)
+            x = x + jnp.einsum("bhkd,hde->bke", o, bp["attn"]["Wo"])
+        with jax.named_scope(_profile.SCOPE_DECODE_MLP):
+            f = _layer_norm(bp["ln_m"], x)
+            x = x + _mlp(bp, f)
         new_caches.append((ck, cv))
     b = x.shape[0]
     logits = _head_logits(params, x.reshape(b * k, -1))
     return logits.reshape(b, k, -1), new_caches
 
 
+@jax.named_scope(_profile.SCOPE_PREFILL)
 def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
     """Prefix-conditioned tail prefill — the prefix-KV-pool admit
     compute.  ``tail`` is (1, s_t) token ids occupying positions

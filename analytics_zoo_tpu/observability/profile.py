@@ -29,6 +29,13 @@ Install once per process (the web service does), plug
     registry.register_collector(handle.families)
     ...
     handle.close()
+
+The same module owns the names a DEVICE profile goes by: :func:`annotate`
+writes the program's host spans (``SPANS``) into the profiler's own
+trace, on the device's clock; ``KERNELS``, ``SCOPES`` and ``PROGRAMS``
+are the names the flash kernels, the ``jax.named_scope`` regions and the
+jitted programs carry there.  Tests and the benchmark's readers import
+them from here.
 """
 
 from __future__ import annotations
@@ -40,6 +47,79 @@ from . import trace
 from .metrics import Family
 
 _COMPILE_EVENT_SUBSTR = "backend_compile"
+
+# ---- names in a device profile ------------------------------------------
+#: every host span of the program starts with this
+SPAN_PREFIX = "zoo/"
+#: the host spans, each on the thread that does the work: the fit loop
+#: (``train/``), the prefetch worker (``input/``), the decode dispatcher
+#: (``decode/``)
+SPANS = tuple(SPAN_PREFIX + n for n in (
+    "train/step", "train/data_wait", "train/step_dispatch",
+    "train/ckpt_save", "train/loss_fetch",
+    "input/produce", "input/h2d",
+    "decode/admit", "decode/admit_fetch", "decode/dispatch",
+    "decode/fetch", "decode/fanout", "decode/idle"))
+#: ``name=`` of the flash attention ``pallas_call``s (ops/attention.py)
+KERNEL_FLASH_FWD = "zoo_flash_fwd"
+KERNEL_FLASH_BWD_DQ = "zoo_flash_bwd_dq"
+KERNEL_FLASH_BWD_DKV = "zoo_flash_bwd_dkv"
+KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV)
+#: regions inside the jitted programs: ``jax.named_scope``s, which are
+#: HLO metadata (an executable answered from the persistent compilation
+#: cache keeps the metadata of whoever compiled it first), and
+#: ``zoo_sample``, a jit of its own inside the decode plans, whose name
+#: is part of the program
+SCOPE_LOSS = "zoo_loss"
+SCOPE_OPTIMIZER_UPDATE = "zoo_optimizer_update"
+SCOPE_GRAD_ACCUM = "zoo_grad_accum"
+SCOPE_DECODE_ATTENTION = "zoo_decode_attention"
+SCOPE_DECODE_MLP = "zoo_decode_mlp"
+SCOPE_PREFILL = "zoo_prefill"
+SCOPE_SAMPLE = "zoo_sample"
+SCOPES = (SCOPE_LOSS, SCOPE_OPTIMIZER_UPDATE, SCOPE_GRAD_ACCUM,
+          SCOPE_DECODE_ATTENTION, SCOPE_DECODE_MLP, SCOPE_PREFILL,
+          SCOPE_SAMPLE)
+#: XLA module names of the jitted programs: the trainer's step, the
+#: decode engine's admit / prefix-admit / single step / fused window /
+#: speculative window / prefix-fill plans
+PROGRAM_TRAIN_STEP = "jit_train_step"
+PROGRAM_ADMIT = "jit_admit"
+PROGRAM_PADMIT = "jit_padmit"
+PROGRAM_STEP = "jit_step"
+PROGRAM_STEPK = "jit_stepk"
+PROGRAM_SPEC = "jit_spec"
+PROGRAM_FILL = "jit_fill"
+PROGRAMS = (PROGRAM_TRAIN_STEP, PROGRAM_ADMIT, PROGRAM_PADMIT,
+            PROGRAM_STEP, PROGRAM_STEPK, PROGRAM_SPEC, PROGRAM_FILL)
+
+_annotations = None     # (TraceAnnotation, StepTraceAnnotation), lazily
+
+
+def annotate(name: str, **stats):
+    """A host span ``zoo/<name>`` on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` where a
+    ``step_num`` is given) to enter around the work, on the thread that
+    does it.  ``stats`` are the counts taken at that boundary; they come
+    back as the event's stats, and ``set_metadata(**more)`` on the
+    returned object adds those known only at the end.  Inert unless a
+    profiler session is running (well under a microsecond), so there is
+    no switch: whoever captures a profile gets the program's spans."""
+    global _annotations
+    if _annotations is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        _annotations = (TraceAnnotation, StepTraceAnnotation)
+    return _annotations["step_num" in stats](SPAN_PREFIX + name, **stats)
+
+
+def named(name: str, fn):
+    """``fn`` renamed so that ``jax.jit(fn)`` is called ``name`` in a
+    device profile: one of ``PROGRAMS`` (``jit_<fn>``, the XLA module of
+    an outermost jit) or, for a jit inside a program, the bare name its
+    operations then carry as ``jit(<name>)``.  Pinned here and not left
+    to whatever the function happens to be called."""
+    fn.__name__ = fn.__qualname__ = name.removeprefix("jit_")
+    return fn
 
 _lock = threading.Lock()
 _installed: "Optional[XlaProfile]" = None

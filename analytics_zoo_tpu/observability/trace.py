@@ -64,7 +64,7 @@ PHASES = ("admission_queue", "pager_wait", "weights_h2d",
 #: when gradient accumulation is on (also prefetch-thread-measured),
 #: the compiled step dispatch, and the checkpoint save when its
 #: trigger fires.
-TRAIN_PHASES = ("data_wait", "h2d", "grad_accum", "step_compute",
+TRAIN_PHASES = ("data_wait", "h2d", "grad_accum", "step_dispatch",
                 "ckpt_save")
 
 _SPAN_VAR: "contextvars.ContextVar[Optional[Span]]" = \

@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ..observability import profile as _profile
 from ..observability.log import get_logger as _get_logger
 from ..parallel import mesh as _mesh_lib
 
@@ -374,6 +375,7 @@ def _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=interpret,
+        name=_profile.KERNEL_FLASH_FWD,
         **_mega(interpret),
     )(*args)
 
@@ -440,6 +442,7 @@ def _flash_core_bwd(sq, sk, causal, masked, block_q, block_k, scale,
         out_specs=pl.BlockSpec((None, bwd_bq, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
         interpret=interpret,
+        name=_profile.KERNEL_FLASH_BWD_DQ,
         **_mega(interpret),
     )(*dq_args)
 
@@ -471,6 +474,7 @@ def _flash_core_bwd(sq, sk, causal, masked, block_q, block_k, scale,
             jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
         ],
         interpret=interpret,
+        name=_profile.KERNEL_FLASH_BWD_DKV,
         **_mega(interpret),
     )(*dkv_args)
     return dq, dk, dv, jnp.zeros_like(lens)

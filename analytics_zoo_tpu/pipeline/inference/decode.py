@@ -198,7 +198,7 @@ class _DecodeRequest:
     # from it, since ``produced`` lags by the in-flight dispatch.
     __slots__ = ("prompt", "length", "bucket", "max_new", "eos_id",
                  "stream", "span", "produced", "scheduled", "slot",
-                 "temperature", "top_k", "top_p", "seed")
+                 "temperature", "top_k", "top_p", "seed", "t_submit")
 
     def __init__(self, prompt: np.ndarray, length: int, bucket: int,
                  max_new: int, eos_id: Optional[int], stream: TokenStream,
@@ -219,6 +219,66 @@ class _DecodeRequest:
         self.top_k = top_k
         self.top_p = top_p
         self.seed = seed
+        # built in submit(): the queue wait counts from here
+        self.t_submit = time.perf_counter()
+
+
+def _pick(logits, seed, index, temperature, top_k, top_p):
+    """One row's token: :func:`_sample` with the key
+    ``fold_in(PRNGKey(seed), index)``, ``index`` the token's absolute
+    index in its stream."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), index)
+    return _sample(logits, key, temperature, top_k,
+                   top_p).astype(jnp.int32)
+
+
+# The pick of the next token is a jitted function of its own INSIDE the
+# plans, named ``zoo_sample``: a device profile then shows its
+# operations under ``jit(zoo_sample)``.  A ``jax.named_scope`` would not
+# do here: scopes are metadata, jax leaves metadata out of the
+# persistent compilation cache's key, and a plan whose instructions did
+# not change is answered from the cache with the metadata (or none) of
+# whoever compiled it first.  The function's symbol is part of the
+# program, so it cannot go stale; XLA inlines the call.
+_pick = jax.jit(_profile.named(_profile.SCOPE_SAMPLE, _pick))
+
+
+#: what the dispatcher thread can be doing: host work (``admit``,
+#: ``dispatch``, ``fanout``), waiting on the device (``admit_fetch``,
+#: ``fetch``) or waiting for work (``idle``)
+LOOP_PHASES = ("admit", "admit_fetch", "dispatch", "fetch", "fanout",
+               "idle")
+
+
+class _LoopPhase:
+    """One phase of the dispatcher's loop, as a context manager: the
+    host span ``zoo/decode/<phase>`` on the profiler's clock AND the
+    always-on counter ``loop_<phase>_s``, opened and closed together so
+    the two cannot drift apart.  The counter holds SELF time: what a
+    nested phase (``admit_fetch`` inside ``admit``) took is its own, so
+    the phases sum to the loop's wall.  Entering returns the annotation
+    (``set_metadata`` adds the counts known only at the end)."""
+
+    __slots__ = ("engine", "key", "ann", "t0", "outer")
+
+    def __init__(self, engine, phase, stats):
+        self.engine = engine
+        self.key = "loop_" + phase + "_s"
+        self.ann = _profile.annotate("decode/" + phase, **stats)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        eng = self.engine
+        self.outer, eng._nested_s = eng._nested_s, 0.0
+        self.ann.__enter__()
+        return self.ann
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        eng = self.engine
+        took = time.perf_counter() - self.t0
+        eng._counters[self.key] += took - eng._nested_s
+        eng._nested_s = self.outer + took
 
 
 class _PrefixEntry:
@@ -545,7 +605,13 @@ class DecodeEngine:
                           "fused_dispatches": 0, "sampled_tokens": 0,
                           "prefix_hits": 0, "prefix_misses": 0,
                           "prefix_evictions": 0, "spec_windows": 0,
-                          "spec_proposed": 0, "spec_accepted": 0}
+                          "spec_proposed": 0, "spec_accepted": 0,
+                          # submit -> admission, summed (beside
+                          # ``admitted``), and the dispatcher thread's
+                          # time by what it was doing (_LoopPhase)
+                          "queue_wait_s": 0.0,
+                          **{f"loop_{p}_s": 0.0 for p in LOOP_PHASES}}
+        self._nested_s = 0.0    # _LoopPhase: time of the phases inside
         self._bucket_stats: Dict[str, Dict[int, Any]] = {
             "hits": {}, "misses": {}, "compile_time_s": {}}
         self._occupancy = 0
@@ -621,13 +687,8 @@ class DecodeEngine:
         revisit if a production vocab makes the sort visible next to
         the transformer step."""
         seed, stepc, temp, topk, topp = samp
-
-        def pick(lg, s, i, t, k, p):
-            key = jax.random.fold_in(jax.random.PRNGKey(s), i + offset)
-            return _sample(lg, key, t, k, p)
-
-        return jax.vmap(pick)(logits, seed, stepc, temp, topk,
-                              topp).astype(jnp.int32)
+        return jax.vmap(_pick)(logits, seed, stepc + offset, temp, topk,
+                               topp)
 
     def _step_core(self, caches, tok, pos, samp, weights):
         """ONE slot-array decode step over ALL ``capacity`` slots —
@@ -771,8 +832,14 @@ class DecodeEngine:
         # deferred fetch, and donating would invalidate that buffer
         # mid-flight (they are (capacity,) scalars — the copy is
         # free).
+        # jitted under a name of its own: from the bound method the
+        # module would be ``jit__step_body``
+        def step(caches, tok, pos, samp, weights):
+            return self._step_body(caches, tok, pos, samp, weights)
+
         return self._plan(
-            "step1", jax.jit(self._step_body, donate_argnums=(0,)),
+            "step1", jax.jit(_profile.named(_profile.PROGRAM_STEP, step),
+                             donate_argnums=(0,)),
             self._state_specs())
 
     def _build_stepk_plan(self, k: int):
@@ -797,9 +864,11 @@ class DecodeEngine:
                 body, (caches, tok, pos, samp), None, length=k)
             return caches, tok, pos, samp, toks  # toks: (k, capacity)
 
-        return self._plan(f"step{k}",
-                          jax.jit(stepk, donate_argnums=(0,)),
-                          self._state_specs())
+        return self._plan(
+            f"step{k}", jax.jit(_profile.named(_profile.PROGRAM_STEPK,
+                                               stepk),
+                                donate_argnums=(0,)),
+            self._state_specs())
 
     def _build_spec_plan(self):
         """The speculative window plan — draft proposal scan, ONE
@@ -869,7 +938,9 @@ class DecodeEngine:
 
         caches, ispec, _, samp = self._state_specs()
         return self._plan(
-            f"spec{k}", jax.jit(spec, donate_argnums=(0, 1)),
+            f"spec{k}", jax.jit(_profile.named(_profile.PROGRAM_SPEC,
+                                               spec),
+                                donate_argnums=(0, 1)),
             (caches, self._draft_specs(), ispec, ispec, samp))
 
     def _ensure_step_plans(self):
@@ -911,9 +982,8 @@ class DecodeEngine:
         """First-token selection at absolute index 0 (the same
         :func:`_sample` + fold_in discipline every later index
         uses)."""
-        key0 = jax.random.fold_in(jax.random.PRNGKey(seed0), 0)
-        return _sample(logits0, key0, temp0, topk0,
-                       topp0).astype(jnp.int32)
+        return _pick(logits0, seed0, jnp.zeros((), jnp.int32), temp0,
+                     topk0, topp0)
 
     def _build_admit_fn(self, s_b: int):
         """One prompt bucket's monolithic admission plan: batched
@@ -960,7 +1030,8 @@ class DecodeEngine:
         # excluded for the same pipeline-aliasing reason (an admission
         # can run while the previous step's token vector still awaits
         # its deferred fetch)
-        return jax.jit(admit, donate_argnums=(0, 1))
+        return jax.jit(_profile.named(_profile.PROGRAM_ADMIT, admit),
+                       donate_argnums=(0, 1))
 
     def _admit_fn_for(self, s_b: int):
         fn = self._admit_fns.get(s_b)
@@ -998,7 +1069,7 @@ class DecodeEngine:
             x, pc = _prefill(weights[0], hyper, prefix, p_b)
             return pc, x[0, p_b - 1]
 
-        return jax.jit(fill)
+        return jax.jit(_profile.named(_profile.PROGRAM_FILL, fill))
 
     def _pfxfill_fn_for(self, p_b: int):
         fn = self._pfxfill_fns.get(p_b)
@@ -1069,7 +1140,8 @@ class DecodeEngine:
                 topk0, topp0)
             return new_caches, tok, pos, samp, tok0
 
-        return jax.jit(padmit, donate_argnums=(0,))
+        return jax.jit(_profile.named(_profile.PROGRAM_PADMIT, padmit),
+                       donate_argnums=(0,))
 
     def _pfxadmit_fn_for(self, p_b: int, s_b: int):
         fn = self._pfxadmit_fns.get((p_b, s_b))
@@ -1343,6 +1415,11 @@ class DecodeEngine:
         return [s.result(timeout=timeout) for s in streams]
 
     # ---- stats ----------------------------------------------------------
+    def _phase(self, phase: str, **stats) -> _LoopPhase:
+        """The dispatcher thread enters ``phase`` (one of
+        ``LOOP_PHASES``): span and counter together."""
+        return _LoopPhase(self, phase, stats)
+
     def stats(self) -> Dict[str, Any]:
         """Point-in-time decode counters (re-exported per model by
         ``InferenceModel.serving_stats`` and the Prometheus bridge)."""
@@ -1438,7 +1515,8 @@ class DecodeEngine:
          self._samp, tok0) = fn(
             self._caches, self._dcaches, self._tok, self._pos,
             self._samp, prompt_dev, length_dev, slot_dev, *scalars)
-        tok0 = int(jax.device_get(tok0))
+        with self._phase("admit_fetch"):
+            tok0 = int(jax.device_get(tok0))
         _profile.note_transfer("d2h")
         if fresh:
             self._bucket_stats["compile_time_s"][req.bucket] = \
@@ -1498,7 +1576,8 @@ class DecodeEngine:
         (self._caches, self._tok, self._pos, self._samp, tok0) = fn(
             self._caches, self._tok, self._pos, self._samp, ent.kv,
             ent.h_last, tail_dev, length_dev, slot_dev, *scalars)
-        tok0 = int(jax.device_get(tok0))
+        with self._phase("admit_fetch"):
+            tok0 = int(jax.device_get(tok0))
         _profile.note_transfer("d2h")
         if fresh:
             self._bucket_stats["compile_time_s"][s_b] = \
@@ -1512,6 +1591,16 @@ class DecodeEngine:
         first token, and activate the slot — or finish the request
         immediately when the first token already ends it (EOS /
         max_new == 1)."""
+        waited = time.perf_counter() - req.t_submit
+        self._counters["queue_wait_s"] += waited
+        # the phase is how long an admission holds the loop: the host
+        # preparation and the plan's dispatch, with the blocking fetch
+        # of the first token as its child (admit_fetch)
+        with self._phase("admit", bucket=req.bucket, length=req.length,
+                         slot=slot, queue_wait_us=int(waited * 1e6)):
+            self._admit(req, slot)
+
+    def _admit(self, req: _DecodeRequest, slot: int):
         span = req.span
         if span is not None:
             span.phase_start("prefill")
@@ -1604,21 +1693,22 @@ class DecodeEngine:
         if self._draft_hyper is not None:
             return self._dispatch_spec()
         k = self._choose_fuse()
-        if k > 1:
-            (self._caches, self._tok, self._pos, self._samp,
-             toks) = self._stepk_fns[k](self._caches, self._tok,
-                                        self._pos, self._samp)
-            self._counters["fused_dispatches"] += 1
-        else:
-            (self._caches, self._tok, self._pos,
-             self._samp) = self._step_fn(self._caches, self._tok,
-                                         self._pos, self._samp)
-            toks = self._tok
-        self._counters["steps"] += k
-        for req in self._slots:
-            if req is not None:
-                req.scheduled += k
-        return toks, None, list(self._slots), k
+        with self._phase("dispatch", k=k, live=self._occupancy):
+            if k > 1:
+                (self._caches, self._tok, self._pos, self._samp,
+                 toks) = self._stepk_fns[k](self._caches, self._tok,
+                                            self._pos, self._samp)
+                self._counters["fused_dispatches"] += 1
+            else:
+                (self._caches, self._tok, self._pos,
+                 self._samp) = self._step_fn(self._caches, self._tok,
+                                             self._pos, self._samp)
+                toks = self._tok
+            self._counters["steps"] += k
+            for req in self._slots:
+                if req is not None:
+                    req.scheduled += k
+            return toks, None, list(self._slots), k
 
     def _dispatch_spec(self):
         """Dispatch one speculative window (draft scan + exact step +
@@ -1627,16 +1717,17 @@ class DecodeEngine:
         pending tuple so the fetch side knows how many of each slot's
         ``spec_tokens`` candidates are valid."""
         k = self.spec_tokens
-        (self._caches, self._dcaches, self._tok, self._pos,
-         self._samp, toks, acc) = self._spec_fn(
-            self._caches, self._dcaches, self._tok, self._pos,
-            self._samp)
-        self._counters["steps"] += k
-        self._counters["spec_windows"] += 1
-        for req in self._slots:
-            if req is not None:
-                req.scheduled += k
-        return toks, acc, list(self._slots), k
+        with self._phase("dispatch", k=k, live=self._occupancy):
+            (self._caches, self._dcaches, self._tok, self._pos,
+             self._samp, toks, acc) = self._spec_fn(
+                self._caches, self._dcaches, self._tok, self._pos,
+                self._samp)
+            self._counters["steps"] += k
+            self._counters["spec_windows"] += 1
+            for req in self._slots:
+                if req is not None:
+                    req.scheduled += k
+            return toks, acc, list(self._slots), k
 
     def _push_window(self, snapshot, toks, counts):
         """Fan one fetched window out to the slots live at dispatch
@@ -1648,28 +1739,33 @@ class DecodeEngine:
         its stream is closed and the slot's extra computed tokens are
         garbage by construction, as are any tokens past a request's
         max_new/EOS inside a window."""
-        for slot, req in enumerate(snapshot):
-            if req is None or req.stream.done:
-                continue
-            sampled = req.temperature > 0.0
-            for j in range(counts[slot]):
-                tok = int(toks[j, slot])
-                req.produced += 1
-                self._counters["tokens"] += 1
-                if sampled:
-                    self._counters["sampled_tokens"] += 1
-                req.stream._push(tok)
-                if (req.produced >= req.max_new
-                        or (req.eos_id is not None
-                            and tok == req.eos_id)):
-                    if req.span is not None:
-                        req.span.phase_end()
-                    self._counters["evicted"] += 1
-                    self._occupancy -= 1
-                    req.stream._finish()
-                    self._slots[slot] = None
-                    self._free.append(slot)
-                    break
+        c = self._counters
+        tokens0, evicted0 = c["tokens"], c["evicted"]
+        with self._phase("fanout") as ann:
+            for slot, req in enumerate(snapshot):
+                if req is None or req.stream.done:
+                    continue
+                sampled = req.temperature > 0.0
+                for j in range(counts[slot]):
+                    tok = int(toks[j, slot])
+                    req.produced += 1
+                    c["tokens"] += 1
+                    if sampled:
+                        c["sampled_tokens"] += 1
+                    req.stream._push(tok)
+                    if (req.produced >= req.max_new
+                            or (req.eos_id is not None
+                                and tok == req.eos_id)):
+                        if req.span is not None:
+                            req.span.phase_end()
+                        c["evicted"] += 1
+                        self._occupancy -= 1
+                        req.stream._finish()
+                        self._slots[slot] = None
+                        self._free.append(slot)
+                        break
+            ann.set_metadata(tokens=c["tokens"] - tokens0,
+                             evicted=c["evicted"] - evicted0)
 
     def _process_step(self, pending):
         """Fetch a dispatched window ((capacity,) single step,
@@ -1677,7 +1773,8 @@ class DecodeEngine:
         tok_dev, acc_dev, snapshot, k = pending
         if acc_dev is not None:
             return self._process_spec(pending)
-        toks = jax.device_get(tok_dev)
+        with self._phase("fetch"):      # the host waits for the device
+            toks = jax.device_get(tok_dev)
         _profile.note_transfer("d2h")
         if k == 1:
             toks = toks.reshape(1, -1)
@@ -1690,8 +1787,9 @@ class DecodeEngine:
         the whole window) — the verify loop's host half, hot once per
         window."""
         tok_dev, acc_dev, snapshot, k = pending
-        toks = jax.device_get(tok_dev)
-        acc = jax.device_get(acc_dev)
+        with self._phase("fetch"):      # the host waits for the device
+            toks = jax.device_get(tok_dev)
+            acc = jax.device_get(acc_dev)
         _profile.note_transfer("d2h")
         counts = [0] * self.capacity
         for slot, req in enumerate(snapshot):
@@ -1765,7 +1863,8 @@ class DecodeEngine:
                 if shutdown:
                     return
                 try:
-                    nxt = self._q.get(timeout=0.05)
+                    with self._phase("idle"):
+                        nxt = self._q.get(timeout=0.05)
                 except queue.Empty:
                     continue
                 if nxt is _SHUTDOWN:

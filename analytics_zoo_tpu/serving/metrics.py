@@ -86,7 +86,10 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
     # proposed/accepted pair (their ratio is the acceptance rate the
     # spec bench gates on) — exported whenever a decode engine is
     # live, zeros until the feature serves traffic, so dashboards and
-    # alerts can pre-wire at deploy
+    # alerts can pre-wire at deploy.  The two seconds counters say
+    # whether the host or the chip is the limit: how long requests
+    # waited for a slot, and the dispatcher thread's time by phase
+    # (host work / waiting on the device / waiting for work)
     decode_counters: Dict[str, List] = {
         "zoo_decode_tokens_total": [],
         "zoo_decode_steps_total": [],
@@ -95,6 +98,8 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
         "zoo_decode_prefix_misses_total": [],
         "zoo_decode_spec_proposed_total": [],
         "zoo_decode_spec_accepted_total": [],
+        "zoo_decode_queue_wait_seconds_total": [],
+        "zoo_decode_loop_seconds_total": [],
     }
     decode_gauges: Dict[str, List] = {
         "zoo_decode_slot_occupancy": [],
@@ -209,9 +214,15 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
                     ("zoo_decode_spec_proposed_total",
                      "spec_proposed"),
                     ("zoo_decode_spec_accepted_total",
-                     "spec_accepted")):
+                     "spec_accepted"),
+                    ("zoo_decode_queue_wait_seconds_total",
+                     "queue_wait_s")):
                 decode_counters[prom_name].append(
                     (ml, dec.get(key, 0)))
+            decode_counters["zoo_decode_loop_seconds_total"].extend(
+                ({**ml, "phase": key[len("loop_"):-len("_s")]}, v)
+                for key, v in dec.items()
+                if key.startswith("loop_") and key.endswith("_s"))
             decode_gauges["zoo_decode_slot_occupancy"].append(
                 (ml, dec.get("slots_active", 0)))
             decode_gauges["zoo_decode_slot_capacity"].append(
@@ -321,6 +332,13 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
         "zoo_decode_spec_accepted_total":
             "draft proposals accepted by the target verify "
             "(accepted/proposed = acceptance rate)",
+        "zoo_decode_queue_wait_seconds_total":
+            "seconds admitted requests waited between submit and "
+            "their admission into a decode slot, summed",
+        "zoo_decode_loop_seconds_total":
+            "decode dispatcher thread seconds by phase: host work "
+            "(admit/dispatch/fanout), waiting on the device "
+            "(admit_fetch/fetch), waiting for work (idle)",
         "zoo_decode_slot_occupancy":
             "decode slots currently holding a live sequence",
         "zoo_decode_slot_capacity":

@@ -8,7 +8,7 @@ time would not say which.  When enabled, every step carries one
 :class:`~..observability.trace.Span` over the phase chain
 (``trace.TRAIN_PHASES``)::
 
-    data_wait -> h2d -> grad_accum -> step_compute -> ckpt_save
+    data_wait -> h2d -> grad_accum -> step_dispatch -> ckpt_save
 
 * ``data_wait`` — the loop thread blocked on the prefetch queue (input
   pipeline can't keep up when this dominates);
@@ -17,9 +17,9 @@ time would not say which.  When enabled, every step carries one
   step via :meth:`Span.phase_add`;
 * ``grad_accum`` — the host-side (accum, micro, ...) microbatch split
   when gradient accumulation is on (also prefetch-thread-measured; the
-  device-side scan itself is inside ``step_compute`` — it is ONE
+  device-side scan itself is inside ``step_dispatch`` — it is ONE
   compiled program);
-* ``step_compute`` — the compiled step dispatch; the span is ACTIVE
+* ``step_dispatch`` — the compiled step dispatch; the span is ACTIVE
   here, so XLA ``backend_compile`` events (profile.py hooks) attribute
   to the exact step that paid the compile;
 * ``ckpt_save`` — the checkpoint write when its trigger fires.

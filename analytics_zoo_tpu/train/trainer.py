@@ -35,6 +35,7 @@ from ..common.utils import pad_leading
 from ..data.dataset import (Dataset, check_batch_divisibility,
                             prefetch_iterator, shard_batch)
 from ..observability import flightrec
+from ..observability import profile as profile_lib
 from ..observability import trace as trace_lib
 from ..parallel import distributed as dist_lib
 from ..parallel import mesh as mesh_lib
@@ -140,9 +141,10 @@ def build_train_step(model, loss_fn, optimizer, compute_dtype=None,
             p_in = jax.tree_util.tree_map(castf, p_in)
         y_pred, new_state = model.apply(
             p_in, mstate, xin, training=True, rng=step_rng)
-        per_sample = loss_fn(y, y_pred.astype(jnp.float32)
-                             if cast is not None else y_pred)
-        loss = jnp.mean(per_sample) + collect_aux(new_state)
+        with jax.named_scope(profile_lib.SCOPE_LOSS):
+            per_sample = loss_fn(y, y_pred.astype(jnp.float32)
+                                 if cast is not None else y_pred)
+            loss = jnp.mean(per_sample) + collect_aux(new_state)
         return loss, new_state
 
     def train_step(params, model_state, opt_state, rng, x, y):
@@ -161,16 +163,19 @@ def build_train_step(model, loss_fn, optimizer, compute_dtype=None,
 
             # accumulate in the MASTER dtype (grads already left the
             # bf16 region through the cast's transpose)
-            zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-            (g_sum, loss_sum, new_state), _ = jax.lax.scan(
-                micro_step,
-                (zeros, jnp.zeros((), jnp.float32), model_state),
-                (jnp.arange(accum), x, y))
-            inv = 1.0 / accum
-            grads = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
-            loss = loss_sum * inv
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+            with jax.named_scope(profile_lib.SCOPE_GRAD_ACCUM):
+                zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
+                (g_sum, loss_sum, new_state), _ = jax.lax.scan(
+                    micro_step,
+                    (zeros, jnp.zeros((), jnp.float32), model_state),
+                    (jnp.arange(accum), x, y))
+                inv = 1.0 / accum
+                grads = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
+                loss = loss_sum * inv
+        with jax.named_scope(profile_lib.SCOPE_OPTIMIZER_UPDATE):
+            updates, new_opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, new_state, new_opt_state, loss
 
     if not jit:
@@ -180,8 +185,9 @@ def build_train_step(model, loss_fn, optimizer, compute_dtype=None,
         kwargs["in_shardings"] = in_shardings
     if out_shardings is not None:
         kwargs["out_shardings"] = out_shardings
-    return jax.jit(train_step, donate_argnums=(0, 1, 2) if donate else (),
-                   **kwargs)
+    return jax.jit(
+        profile_lib.named(profile_lib.PROGRAM_TRAIN_STEP, train_step),
+        donate_argnums=(0, 1, 2) if donate else (), **kwargs)
 
 
 #: env-contract knobs (declared in envcontract.VARS): deployment-wide
@@ -618,7 +624,7 @@ class Trainer:
     def enable_step_profiler(self, timeline_path: Optional[str] = None
                              ) -> "stepprof.StepProfiler":
         """Turn on the per-step phase profiler (data_wait -> h2d ->
-        step_compute -> ckpt_save; train/stepprof.py) for subsequent
+        step_dispatch -> ckpt_save; train/stepprof.py) for subsequent
         ``fit`` calls.  ``timeline_path`` additionally publishes the
         bounded per-step timeline as JSONL at fit end.  Also reachable
         without code changes via ``ZOO_STEP_PROFILE=1`` /
@@ -782,67 +788,88 @@ class Trainer:
                 dev_it = prefetch_iterator(batch_it, put_fn)
                 step_it = (dev_it if prof is None
                            else prof.timed_iter(dev_it))
-                for item in step_it:
-                    if prof is None:
-                        bx, by = item
-                        span = None
-                    else:
-                        (bx, by), h2d_s, accum_s = item
-                        span = prof.begin_step(st.step + 1, h2d_s,
-                                               accum_s=accum_s)
-                    step_rng = jax.random.fold_in(st.rng, st.step)
-                    if span is None:
-                        st.params, st.model_state, st.opt_state, loss = \
-                            self._train_step(st.params, st.model_state,
-                                             st.opt_state, step_rng,
-                                             bx, by)
-                    else:
-                        # the span is ACTIVE across the dispatch so
-                        # backend_compile events attribute to the exact
-                        # step that paid the compile
-                        span.phase_start("step_compute")
-                        with trace_lib.activate(span):
-                            st.params, st.model_state, st.opt_state, \
-                                loss = self._train_step(
-                                    st.params, st.model_state,
-                                    st.opt_state, step_rng, bx, by)
-                        span.phase_end()
-                    st.step += 1
-                    faults.heartbeat()
-                    train_metrics.record_step()
-                    if recorder is not None:
-                        # liveness marker BEFORE the fault hook: a
-                        # crash at step k must leave the step-k record
-                        # (the postmortem's "last completed step")
-                        recorder.record_step(st.step)
-                        if not st.step & 15:
-                            # throttle-CHECK every 16th step: the call
-                            # itself is measurable in a contended loop
-                            # and the snapshot cadence is seconds
-                            recorder.snapshot_metrics()
-                    # injected faults land BEFORE the checkpoint trigger:
-                    # a crash at step k must never leave a step-k tag
-                    faults.maybe_fault(st.step)
-                    epoch_samples += batch_size
-                    epoch_losses.append(loss)
-                    if profiling and st.step >= profile_end_step:
-                        jax.block_until_ready(loss)  # trace covers real work
-                        _stop_profile()
-                    it_record = {"epoch": st.epoch, "iteration": st.step,
-                                 "loss": loss}
-                    if self._ckpt_path and not isinstance(
-                            self._ckpt_trigger, trigger_lib.EveryEpoch) \
-                            and self._ckpt_trigger(it_record):
-                        if span is not None:
-                            span.phase_start("ckpt_save")
-                        save = (save_sharded if faults.sync_checkpoints()
-                                else async_save_sharded)
-                        save(self._ckpt_path, st.step, st.as_tree(),
-                             meta={"step": st.step, "epoch": st.epoch,
-                                   "epoch_step":
-                                       st.step - epoch_start_step})
-                        if span is not None:
-                            span.phase_end()
+                while True:
+                    # host spans on the profiler's clock (inert without
+                    # a profiler session): the step from asking for its
+                    # batch to its dispatch returning, and inside it the
+                    # wait, the enqueue and the checkpoint.  An epoch's
+                    # last span holds only the wait that found the
+                    # source exhausted.
+                    with profile_lib.annotate("train/step",
+                                              step_num=st.step + 1):
+                        with profile_lib.annotate("train/data_wait"):
+                            item = next(step_it, None)
+                        if item is None:
+                            break
+                        if prof is None:
+                            bx, by = item
+                            span = None
+                        else:
+                            (bx, by), h2d_s, accum_s = item
+                            span = prof.begin_step(st.step + 1, h2d_s,
+                                                   accum_s=accum_s)
+                        step_rng = jax.random.fold_in(st.rng, st.step)
+                        with profile_lib.annotate("train/step_dispatch"):
+                            if span is None:
+                                st.params, st.model_state, st.opt_state, \
+                                    loss = self._train_step(
+                                        st.params, st.model_state,
+                                        st.opt_state, step_rng, bx, by)
+                            else:
+                                # the span is ACTIVE across the dispatch
+                                # so backend_compile events attribute to
+                                # the exact step that paid the compile
+                                span.phase_start("step_dispatch")
+                                with trace_lib.activate(span):
+                                    st.params, st.model_state, \
+                                        st.opt_state, loss = \
+                                        self._train_step(
+                                            st.params, st.model_state,
+                                            st.opt_state, step_rng, bx, by)
+                                span.phase_end()
+                        st.step += 1
+                        faults.heartbeat()
+                        train_metrics.record_step()
+                        if recorder is not None:
+                            # liveness marker BEFORE the fault hook: a
+                            # crash at step k must leave the step-k record
+                            # (the postmortem's "last completed step")
+                            recorder.record_step(st.step)
+                            if not st.step & 15:
+                                # throttle-CHECK every 16th step: the call
+                                # itself is measurable in a contended loop
+                                # and the snapshot cadence is seconds
+                                recorder.snapshot_metrics()
+                        # injected faults land BEFORE the checkpoint
+                        # trigger: a crash at step k must never leave a
+                        # step-k tag
+                        faults.maybe_fault(st.step)
+                        epoch_samples += batch_size
+                        epoch_losses.append(loss)
+                        if profiling and st.step >= profile_end_step:
+                            # trace covers real work
+                            jax.block_until_ready(loss)
+                            _stop_profile()
+                        it_record = {"epoch": st.epoch,
+                                     "iteration": st.step, "loss": loss}
+                        if self._ckpt_path and not isinstance(
+                                self._ckpt_trigger,
+                                trigger_lib.EveryEpoch) \
+                                and self._ckpt_trigger(it_record):
+                            if span is not None:
+                                span.phase_start("ckpt_save")
+                            save = (save_sharded
+                                    if faults.sync_checkpoints()
+                                    else async_save_sharded)
+                            with profile_lib.annotate("train/ckpt_save"):
+                                save(self._ckpt_path, st.step,
+                                     st.as_tree(),
+                                     meta={"step": st.step,
+                                           "epoch": st.epoch,
+                                           "epoch_step":
+                                               st.step - epoch_start_step})
+                            if span is not None:
+                                span.phase_end()
                     if span is not None:
                         prof.finish_step(span, st.step)
                     if end_trigger(it_record):
@@ -856,9 +883,11 @@ class Trainer:
                 dev_it.close()
                 st.epoch += 1
                 # one bulk host transfer for the whole epoch's scalars
-                losses_host = ([float(v) for v in
-                                np.asarray(jax.device_get(epoch_losses))]
-                               if epoch_losses else [])
+                with profile_lib.annotate("train/loss_fetch",
+                                          steps=len(epoch_losses)):
+                    losses_host = ([float(v) for v in np.asarray(
+                        jax.device_get(epoch_losses))]
+                        if epoch_losses else [])
                 base_step = st.step - len(losses_host)
                 history["loss"].extend(losses_host)
                 elapsed = max(time.time() - epoch_start, 1e-9)

@@ -337,7 +337,7 @@ def test_step_profiler_phases_and_timeline(tmp_path):
     for step in (1, 2):
         prof.last_wait_s = 0.002
         span = prof.begin_step(step, h2d_s=0.001)
-        with span.phase("step_compute"):
+        with span.phase("step_dispatch"):
             time.sleep(0.001)
         if step == 2:
             with span.phase("ckpt_save"):
@@ -345,12 +345,12 @@ def test_step_profiler_phases_and_timeline(tmp_path):
         prof.finish_step(span, step)
     assert prof.steps == 2
     snap = prof.snapshot()
-    assert set(snap["phases"]) >= {"data_wait", "h2d", "step_compute"}
+    assert set(snap["phases"]) >= {"data_wait", "h2d", "step_dispatch"}
     assert snap["phases"]["ckpt_save"]["count"] == 1
     text = render_prometheus(prof.families())
     s = parse_prometheus_text(text)["samples"]
     assert s[("zoo_train_step_seconds_count",
-              (("phase", "step_compute"),))] == 2
+              (("phase", "step_dispatch"),))] == 2
     assert prof.write_timeline() == tl
     lines = [json.loads(ln) for ln in open(tl)]
     assert [e["step"] for e in lines] == [1, 2]
@@ -396,7 +396,7 @@ def test_trainer_step_profiler_end_to_end(tmp_path):
         flightrec.shutdown()
     assert h_plain["loss"] == h_traced["loss"]  # bit-identical
     assert prof.steps == 8
-    for phase in ("data_wait", "h2d", "step_compute"):
+    for phase in ("data_wait", "h2d", "step_dispatch"):
         assert prof.windows[phase].count == 8, phase
     entries = [json.loads(ln) for ln in open(tl)]
     assert len(entries) == 8
@@ -407,7 +407,7 @@ def test_trainer_step_profiler_end_to_end(tmp_path):
     h = flightrec.harvest(str(tmp_path / "fr"))
     assert h[0]["last_step"] == 8
     assert [e["step"] for e in h[0]["steps"]] == list(range(1, 9))
-    assert "step_compute_ms" in h[0]["steps"][0]
+    assert "step_dispatch_ms" in h[0]["steps"][0]
     s = parse_prometheus_text(open(h[0]["metrics_path"]).read())["samples"]
     assert s[("zoo_train_steps_total", ())] >= 8.0
     assert any(k[0] == "zoo_train_step_seconds" for k in s)

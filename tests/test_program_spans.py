@@ -1,0 +1,350 @@
+"""The names a device profile goes by (ISSUE 26): the program's host
+spans on the profiler's clock (``profile.annotate``), the decode
+dispatcher's always-on loop counters, the ``jax.named_scope`` regions,
+the pinned program names and the flash kernels' names.
+
+All on the CPU: a profiler session here records host events only, which
+is what the spans are.  Read back with ``jax.profiler.ProfileData``, the
+same way the benchmark's ``program_spans.py`` reads a chip's trace."""
+
+import glob
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from analytics_zoo_tpu.models import TransformerLM
+from analytics_zoo_tpu.observability import profile
+from analytics_zoo_tpu.ops.attention import flash_attention
+from analytics_zoo_tpu.pipeline.inference import DecodeEngine
+from analytics_zoo_tpu.pipeline.inference.decode import LOOP_PHASES
+from analytics_zoo_tpu.serving.metrics import registry_families
+
+VOCAB, SEQ, BUCKET = 64, 48, 16
+
+
+def zoo(name):
+    full = profile.SPAN_PREFIX + name
+    assert full in profile.SPANS, full      # no name is retyped unpinned
+    return full
+
+
+class Traced:
+    """A profiler session around a block (the python tracer off, as the
+    benchmark has it); afterwards ``events`` holds the ``zoo/`` host
+    events as (thread line, name, start_ns, end_ns, stats)."""
+
+    def __init__(self, directory):
+        self.dir = str(directory)
+        self.events = []
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        [path] = glob.glob(self.dir + "/plugins/profile/*/*.xplane.pb")
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            # threads may share a name: a line is one thread
+            for thread, line in enumerate(plane.lines):
+                for ev in line.events:
+                    if ev.name.startswith(profile.SPAN_PREFIX):
+                        self.events.append(
+                            (thread, ev.name, ev.start_ns,
+                             ev.start_ns + ev.duration_ns, dict(ev.stats)))
+
+    def named(self, name):
+        return [e for e in self.events if e[1] == zoo(name)]
+
+    def threads(self, *names):
+        return {e[0] for n in names for e in self.named(n)}
+
+
+def inside(child, parent):
+    return (child[0] == parent[0] and parent[2] <= child[2]
+            and child[3] <= parent[3])
+
+
+@pytest.fixture(scope="module")
+def lm():
+    model = TransformerLM(vocab_size=VOCAB, seq_len=SEQ, n_layers=2,
+                          d_model=32, n_heads=2)
+    model.ensure_inference_ready()
+    return model
+
+
+def new_engine(lm, **kw):
+    eng = DecodeEngine(lm.trainer.state.params, lm.hyper, capacity=3,
+                       max_len=SEQ, prompt_buckets=(BUCKET,), **kw)
+    eng.warmup()
+    return eng
+
+
+def prompts(n, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, VOCAB, int(k))
+            for k in rng.integers(2, BUCKET, n)]
+
+
+# ------------------------------------------------------------ host spans
+def test_annotate_is_inert_without_a_session_and_cheap():
+    """No switch: with no profiler session an annotation does nothing,
+    for well under the issue's budget (10 us a training step for 3)."""
+    t0 = time.perf_counter()
+    for _ in range(2000):
+        with profile.annotate("decode/dispatch", k=4, live=3):
+            pass
+    assert (time.perf_counter() - t0) / 2000 < 20e-6
+    assert type(profile.annotate("train/step", step_num=1)).__name__ \
+        == "StepTraceAnnotation"
+    assert type(profile.annotate("train/data_wait")).__name__ \
+        == "TraceAnnotation"
+
+
+def test_decode_spans_land_in_the_profile_with_their_stats(lm, tmp_path):
+    eng = new_engine(lm)
+    try:
+        eng.generate(prompts(2), 4, timeout=120)    # dispatcher started
+        with Traced(tmp_path) as tr:
+            eng.generate(prompts(5, seed=1), [9, 4, 12, 7, 3],
+                         timeout=120)
+    finally:
+        eng.close()
+    assert {e[1] for e in tr.events} <= set(profile.SPANS)
+    work = ("decode/admit", "decode/admit_fetch", "decode/dispatch",
+            "decode/fetch", "decode/fanout")
+    for name in work:
+        assert tr.named(name), name
+    assert len(tr.threads(*work)) == 1      # the dispatcher's line
+    admits = tr.named("decode/admit")
+    assert len(admits) == 5
+    for ev in admits:
+        stats = ev[4]
+        assert stats["bucket"] == BUCKET and 0 <= stats["slot"] < 3
+        assert 2 <= stats["length"] < BUCKET
+        assert stats["queue_wait_us"] >= 0
+    # five requests into three slots: the last two waited for an eviction
+    assert max(e[4]["queue_wait_us"] for e in admits) > 0
+    for fetch in tr.named("decode/admit_fetch"):
+        assert sum(inside(fetch, a) for a in admits) == 1
+    for ev in tr.named("decode/dispatch"):
+        assert ev[4]["k"] in (1, 2, 4) and 1 <= ev[4]["live"] <= 3
+    fanouts = tr.named("decode/fanout")
+    # first tokens leave at the admission; the rest through the fan-out
+    assert sum(e[4]["tokens"] for e in fanouts) == 9 + 4 + 12 + 7 + 3 - 5
+    assert sum(e[4]["evicted"] for e in fanouts) == 5
+
+
+def test_decode_idle_span(lm, tmp_path):
+    eng = new_engine(lm)
+    try:
+        eng.generate(prompts(1), 2, timeout=120)
+        with Traced(tmp_path) as tr:
+            time.sleep(0.2)         # nothing to do: the loop waits for work
+    finally:
+        eng.close()
+    idles = tr.named("decode/idle")
+    assert idles and len(tr.threads("decode/idle")) == 1
+    # the wait is at most 50 ms long and the loop does nothing else
+    assert sum(e[3] - e[2] for e in idles) > 0.1e9
+
+
+def test_fit_spans_land_in_the_profile(tmp_path):
+    from analytics_zoo_tpu.pipeline.api.keras import Sequential
+    from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
+    from analytics_zoo_tpu.train import triggers
+
+    m = Sequential()
+    m.add(Dense(8, activation="relu", input_shape=(4,)))
+    m.add(Dense(2))
+    m.compile(optimizer="sgd", loss="sparse_categorical_crossentropy")
+    rs = np.random.RandomState(0)
+    x = rs.rand(32, 4).astype(np.float32)
+    y = rs.randint(0, 2, 32).astype(np.int32)
+    m.fit(x, y, batch_size=8, nb_epoch=1)           # compiled
+    m.trainer.set_checkpoint(str(tmp_path / "ckpt"),
+                             trigger=triggers.SeveralIteration(2))
+    first = m.trainer.state.step + 1
+    with Traced(tmp_path / "trace") as tr:
+        m.fit(x, y, batch_size=8, nb_epoch=1)
+    assert {e[1] for e in tr.events} <= set(profile.SPANS)
+    steps = tr.named("train/step")
+    dispatches = tr.named("train/step_dispatch")
+    assert len(dispatches) == 4
+    # one span a step, numbered, plus the one whose wait found the
+    # epoch's source exhausted
+    assert [e[4]["step_num"] for e in steps] \
+        == [first, first + 1, first + 2, first + 3, first + 4]
+    for name in ("train/data_wait", "train/step_dispatch",
+                 "train/ckpt_save"):
+        children = tr.named(name)
+        assert children, name
+        for child in children:
+            assert sum(inside(child, s) for s in steps) == 1, name
+    assert len(tr.named("train/ckpt_save")) == 2
+    [fetch] = tr.named("train/loss_fetch")
+    assert fetch[4]["steps"] == 4
+    loop = tr.threads("train/step", "train/loss_fetch")
+    assert len(loop) == 1
+    feeder = tr.threads("input/produce", "input/h2d")
+    assert len(feeder) == 1 and feeder != loop
+    assert len(tr.named("input/h2d")) == 4
+
+
+# ---------------------------------------------------- always-on counters
+def test_loop_counters_are_monotone_and_sum_to_the_loops_wall():
+    keys = ["queue_wait_s"] + [f"loop_{p}_s" for p in LOOP_PHASES]
+    # wide enough that a step outweighs the loop's own bookkeeping
+    model = TransformerLM(vocab_size=VOCAB, seq_len=SEQ, n_layers=2,
+                          d_model=128, n_heads=2)
+    model.ensure_inference_ready()
+    eng = new_engine(model)
+
+    def snapshot():
+        ta = time.perf_counter()
+        stats = eng.stats()
+        return ta, time.perf_counter(), stats
+
+    try:
+        assert [eng.stats()[k] for k in keys] == [0.0] * 7
+        snaps = [snapshot()]
+        # a backlog keeps the dispatcher busy, so at each snapshot the
+        # phase in flight (counted only when it ends) is a short one
+        streams = [eng.submit(p, 12) for p in prompts(200)]
+        for k in (5, 60, 120, 190):
+            streams[k].result(timeout=120)
+            snaps.append(snapshot())
+        [s.result(timeout=120) for s in streams]
+        time.sleep(0.12)        # idle: waits of at most 50 ms
+        snaps.append(snapshot())
+    finally:
+        eng.close()
+    for (_, _, a), (_, _, b) in zip(snaps, snaps[1:]):
+        for k in keys:
+            assert b[k] >= a[k], k
+    for k in keys:
+        assert snaps[-1][2][k] > 0.0, k
+    (t0a, t0b, s0), (t1a, t1b, s1) = snaps[1], snaps[4]
+    loop_s = sum(s1[f"loop_{p}_s"] - s0[f"loop_{p}_s"]
+                 for p in LOOP_PHASES)
+    assert 0.95 * (t1a - t0b) <= loop_s <= 1.05 * (t1b - t0a), \
+        (loop_s, t1a - t0b, t1b - t0a)
+    assert s1["admitted"] > s0["admitted"]
+    assert s1["queue_wait_s"] > s0["queue_wait_s"]
+
+
+def test_loop_counters_reach_prometheus(lm):
+    from analytics_zoo_tpu.serving import ModelRegistry
+
+    reg = ModelRegistry()
+    try:
+        reg.deploy("lm", lm, decode_capacity=2,
+                   decode_prompt_buckets=(BUCKET,))
+        reg.generate("lm", prompts(1), 4)
+        fams = {f.name: f for f in registry_families(reg.metrics())}
+    finally:
+        reg.shutdown()
+    wait = fams["zoo_decode_queue_wait_seconds_total"]
+    assert wait.mtype == "counter" and wait.samples[0][1] >= 0
+    loop = fams["zoo_decode_loop_seconds_total"]
+    assert {labels["phase"] for labels, _ in loop.samples} \
+        == set(LOOP_PHASES)
+    assert sum(v for _, v in loop.samples) > 0
+
+
+# ------------------------------------------------- names inside programs
+def test_train_step_holds_its_scopes_and_its_name():
+    from analytics_zoo_tpu.pipeline.api.keras import Sequential
+    from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
+
+    m = Sequential()
+    m.add(Dense(8, activation="relu", input_shape=(4,)))
+    m.add(Dense(2))
+    m.compile(optimizer="adam", loss="sparse_categorical_crossentropy")
+    tr = m.trainer
+    x = np.zeros((8, 4), np.float32)
+    y = np.zeros((8,), np.int32)
+    lowered = tr.lower_train_step(x, y)
+    text = lowered.as_text(debug_info=True)
+    assert f"module @{profile.PROGRAM_TRAIN_STEP} " in text
+    for scope in (profile.SCOPE_LOSS, profile.SCOPE_OPTIMIZER_UPDATE):
+        assert scope in text, scope
+    assert profile.SCOPE_GRAD_ACCUM not in text
+    tr.accum_steps = 2
+    tr.invalidate_compiled()
+    text = tr.lower_train_step(x, y).as_text(debug_info=True)
+    assert profile.SCOPE_GRAD_ACCUM in text
+
+
+def lowered_plans(eng):
+    """Every jitted decode program of ``eng``, lowered: {module name:
+    text with the scopes}."""
+    texts = {}
+    real = eng._plan
+
+    def capture(name, jitted, arg_specs):
+        weights = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            eng._weights)
+        text = jitted.lower(*arg_specs, weights).as_text(debug_info=True)
+        texts[text.split("module @", 1)[1].split(" ", 1)[0]] = text
+        return real(name, jitted, arg_specs)
+
+    eng._plan = capture
+    eng.warmup()
+    return texts
+
+
+def test_decode_programs_hold_their_scopes_and_their_names(lm):
+    eng = DecodeEngine(lm.trainer.state.params, lm.hyper, capacity=2,
+                       max_len=SEQ, prompt_buckets=(BUCKET,),
+                       prefix_pool=2)
+    try:
+        texts = lowered_plans(eng)
+    finally:
+        eng.close()
+    draft = TransformerLM(vocab_size=VOCAB, seq_len=SEQ, n_layers=1,
+                          d_model=16, n_heads=2)
+    draft.ensure_inference_ready()
+    eng = DecodeEngine(lm.trainer.state.params, lm.hyper, capacity=2,
+                       max_len=SEQ, prompt_buckets=(BUCKET,),
+                       draft_params=draft.trainer.state.params,
+                       draft_hyper=draft.hyper, spec_tokens=3)
+    try:
+        texts.update(lowered_plans(eng))
+    finally:
+        eng.close()
+    decode = set(profile.PROGRAMS) - {profile.PROGRAM_TRAIN_STEP}
+    assert set(texts) == decode
+    for name in (profile.PROGRAM_STEP, profile.PROGRAM_STEPK,
+                 profile.PROGRAM_SPEC):
+        for scope in (profile.SCOPE_SAMPLE, profile.SCOPE_DECODE_ATTENTION,
+                      profile.SCOPE_DECODE_MLP):
+            assert scope in texts[name], (name, scope)
+    for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_PADMIT):
+        assert profile.SCOPE_SAMPLE in texts[name], name
+    for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_FILL):
+        assert profile.SCOPE_PREFILL in texts[name], name
+
+
+def test_flash_kernels_carry_their_names():
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=True).sum()
+
+    fwd = str(jax.make_jaxpr(loss)(q, q, q))
+    assert profile.KERNEL_FLASH_FWD in fwd
+    assert profile.KERNEL_FLASH_BWD_DQ not in fwd
+    bwd = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    for kernel in profile.KERNELS:
+        assert kernel in bwd, kernel

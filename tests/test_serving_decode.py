@@ -256,8 +256,12 @@ def test_crash_net_fails_all_streams(lm):
                        max_len=SEQ, prompt_buckets=(BUCKET,))
     eng.warmup()
     boom = RuntimeError("injected decode crash")
+    # the crash waits for the last submit: a submit AFTER the crash is
+    # refused (DecodeEngineClosedError), which is the case further down
+    all_in = threading.Event()
 
     def exploding(*a, **kw):
+        all_in.wait(timeout=30)
         raise boom
 
     eng._step_fn = exploding
@@ -265,6 +269,7 @@ def test_crash_net_fails_all_streams(lm):
     rng = np.random.default_rng(2)
     streams = [eng.submit(rng.integers(0, VOCAB, 4), 8)
                for _ in range(4)]
+    all_in.set()
     for s in streams:
         with pytest.raises(RuntimeError, match="injected decode crash"):
             s.result(timeout=60)

@@ -93,6 +93,26 @@ def test_flash_kernels_compile_at_main_path_shapes(one_chip, shape, masked):
     assert calls == {"fwd": 1, "dq": 2, "dkv": 2}
 
 
+def test_compiled_step_names_the_three_kernels(one_chip):
+    """The names a device profile goes by survive the chip's compiler:
+    the custom VJP at the benchmark's train shape (4 x 16 heads x 1024 x
+    64, bf16, causal) compiles to custom calls that carry them."""
+    from analytics_zoo_tpu.observability import profile
+    x = jax.ShapeDtypeStruct((4, 16, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(A.flash_attention(q, k, v, causal=True,
+                                         layout="bhsd")
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in profile.KERNELS:
+        assert text.count(kernel) >= 1, kernel
+
+
 def test_prompt_bucket_prefill_compiles(one_chip):
     """The decode engine's admit plan prefills a (1, heads, bucket, d)
     f32 prompt; bucket 48 is not a multiple of 128, so it pads."""
