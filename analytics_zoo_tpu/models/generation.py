@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..observability import profile as _profile
-from ..ops.attention import attention_bhsd
+from ..ops.attention import (attention_bhsd, decode_attention, kv_heads,
+                             kv_pad, kv_rows, kv_write_row)
 from ..parallel.expert import MoEParams, expert_capacity, switch_moe
 from ..pipeline.api.keras.activations import get as get_activation
 
@@ -93,8 +94,9 @@ def _embed_token(params, tok, pos):
 def _prefill(params, hyper, prompt, cache_len):
     """Batched prompt pass: causal attention over the whole prompt in one
     forward (the training-shaped compute), writing each layer's K/V into
-    position [0, s_p) of a (b, heads, cache_len, d) cache and returning
-    the last position's hidden state."""
+    position [0, s_p) of a (b, cache_len, heads * d) slab (the layout is
+    ``ops.attention.kv_*``'s) and returning the last position's hidden
+    state."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
     s_p = prompt.shape[1]
     x = jnp.take(params["tok_embed"]["embeddings"],
@@ -113,27 +115,22 @@ def _prefill(params, hyper, prompt, cache_len):
         x = x + jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
         f = _layer_norm(bp["ln_m"], x)
         x = x + _mlp(bp, f)
-        pad = [(0, 0), (0, 0), (0, cache_len - s_p), (0, 0)]
-        caches.append((jnp.pad(k, pad), jnp.pad(v, pad)))
+        caches.append((kv_pad(kv_rows(k), cache_len),
+                       kv_pad(kv_rows(v), cache_len)))
     return x, caches
 
 
-def _cache_write(c, x_new, pos):
-    """Write one step's (b, h, d) k or v into the (b, h, t, d) cache at
-    ``pos`` — a shared scalar position, or (b,) per-row positions for
-    ragged prompts."""
-    xn = x_new[:, :, None, :]
-    if jnp.ndim(pos) == 0:
-        return lax.dynamic_update_slice_in_dim(c, xn, pos, axis=2)
-    return jax.vmap(
-        lambda cb, xb, pb: lax.dynamic_update_slice_in_dim(
-            cb, xb, pb, axis=1))(c, xn, pos)
+def _rows_proj(a, w):
+    """``a (..., e)`` through a ``(e, heads, d)`` projection, straight
+    into slab rows ``(..., heads * d)``."""
+    return a @ w.reshape(w.shape[0], -1)
 
 
-def _decode_step(params, hyper, caches, x_tok, pos):
+def _decode_step(params, hyper, caches, x_tok, pos, mesh=None):
     """One cached decode step: ``x_tok`` is the (b, d_model) embedding of
     the current token (token + positional), ``pos`` its position —
-    scalar, or (b,) per-row for ragged prompts.
+    scalar, or (b,) per-row for ragged prompts.  ``mesh``: the mesh a
+    sharded engine has laid the rows out on (``decode_attention``).
     Returns (logits, updated caches)."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
     n_heads = hyper["n_heads"]
@@ -145,20 +142,13 @@ def _decode_step(params, hyper, caches, x_tok, pos):
         ck, cv = caches[i]
         with jax.named_scope(_profile.SCOPE_DECODE_ATTENTION):
             a = _layer_norm(bp["ln_a"], x)
-            q = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wq"])
-            k = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wk"])
-            v = jnp.einsum("be,ehd->bhd", a, bp["attn"]["Wv"])
-            ck = _cache_write(ck, k, pos)
-            cv = _cache_write(cv, v, pos)
-            d = q.shape[-1]
-            scores = jnp.einsum("bhd,bhtd->bht", q, ck) / math.sqrt(d)
-            t = ck.shape[2]
-            posv = jnp.broadcast_to(pos, (ck.shape[0],))
-            valid = jnp.arange(t)[None, None, :] <= posv[:, None, None]
-            scores = jnp.where(valid, scores, -1e30)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            o = jnp.einsum("bht,bhtd->bhd", probs.astype(cv.dtype), cv)
-            x = x + jnp.einsum("bhd,hde->be", o, bp["attn"]["Wo"])
+            o, ck, cv = decode_attention(
+                _rows_proj(a, bp["attn"]["Wq"]),
+                _rows_proj(a, bp["attn"]["Wk"]),
+                _rows_proj(a, bp["attn"]["Wv"]), ck, cv, pos, n_heads,
+                mesh=mesh)
+            wo = bp["attn"]["Wo"]
+            x = x + o @ wo.reshape(-1, wo.shape[-1])
         with jax.named_scope(_profile.SCOPE_DECODE_MLP):
             f = _layer_norm(bp["ln_m"], x)
             x = x + _mlp(bp, f)
@@ -184,8 +174,9 @@ def _decode_window(params, hyper, caches, x_toks, pos):
     single-query body and uses this window only to certify draft
     proposals (decode.py §speculative)."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
+    n_heads = hyper["n_heads"]
     k = x_toks.shape[1]
-    t = caches[0][0].shape[2]
+    t = caches[0][0].shape[1]
     x = x_toks
     qpos = jnp.minimum(pos[:, None] + jnp.arange(k)[None, :], t - 1)
     new_caches = []
@@ -196,18 +187,20 @@ def _decode_window(params, hyper, caches, x_toks, pos):
         with jax.named_scope(_profile.SCOPE_DECODE_ATTENTION):
             a = _layer_norm(bp["ln_a"], x)
             q = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wq"])
-            kk = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wk"])
-            vv = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wv"])
+            kk = _rows_proj(a, bp["attn"]["Wk"])
+            vv = _rows_proj(a, bp["attn"]["Wv"])
             for j in range(k):
-                ck = _cache_write(ck, kk[:, :, j], qpos[:, j])
-                cv = _cache_write(cv, vv[:, :, j], qpos[:, j])
+                ck = kv_write_row(ck, kk[:, j], qpos[:, j])
+                cv = kv_write_row(cv, vv[:, j], qpos[:, j])
             d = q.shape[-1]
-            scores = jnp.einsum("bhkd,bhtd->bhkt", q, ck) / math.sqrt(d)
+            scores = jnp.einsum("bhkd,bthd->bhkt", q,
+                                kv_heads(ck, n_heads)) / math.sqrt(d)
             valid = (jnp.arange(t)[None, None, None, :]
                      <= qpos[:, None, :, None])
             scores = jnp.where(valid, scores, -1e30)
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            o = jnp.einsum("bhkt,bhtd->bhkd", probs.astype(cv.dtype), cv)
+            o = jnp.einsum("bhkt,bthd->bhkd", probs.astype(cv.dtype),
+                           kv_heads(cv, n_heads))
             x = x + jnp.einsum("bhkd,hde->bke", o, bp["attn"]["Wo"])
         with jax.named_scope(_profile.SCOPE_DECODE_MLP):
             f = _layer_norm(bp["ln_m"], x)
@@ -223,14 +216,15 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
     """Prefix-conditioned tail prefill — the prefix-KV-pool admit
     compute.  ``tail`` is (1, s_t) token ids occupying positions
     ``[p_len, p_len + s_t)``; ``prefix_kv`` the per-layer (k, v)
-    blocks of the first ``p_len`` positions, each (1, heads, p_len,
-    d_head) — pooled (a memcpy) or freshly computed by the same
+    blocks of the first ``p_len`` positions, each slab rows (1, p_len,
+    heads * d_head) — pooled (a memcpy) or freshly computed by the same
     prefix-prefill plan (bit-identical either way, which is what makes
     pool hit vs miss streams indistinguishable).  Causal attention of
     the tail queries over prefix + tail in one batched forward.
     Returns (tail hidden states (1, s_t, d_model), per-layer tail
-    (k, v) blocks (1, heads, s_t, d_head))."""
+    (k, v) blocks, slab rows (1, s_t, heads * d_head))."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
+    n_heads = hyper["n_heads"]
     s_t = tail.shape[1]
     x = jnp.take(params["tok_embed"]["embeddings"],
                  tail.astype(jnp.int32), axis=0)
@@ -244,24 +238,24 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
     for i in range(n_layers):
         moe = bool(moe_every) and (i + 1) % moe_every == 0
         bp = _block_params(params, i, moe)
-        pk, pv = prefix_kv[i]
+        pk, pv = (kv_heads(c, n_heads) for c in prefix_kv[i])
         a = _layer_norm(bp["ln_a"], x)
         q = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wq"])
         k = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wk"])
         v = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wv"])
         d = q.shape[-1]
-        sp = jnp.einsum("bhsd,bhtd->bhst", q, pk) / math.sqrt(d)
+        sp = jnp.einsum("bhsd,bthd->bhst", q, pk) / math.sqrt(d)
         st = jnp.einsum("bhsd,bhtd->bhst", q, k) / math.sqrt(d)
         st = jnp.where(causal, st, -1e30)
         scores = jnp.concatenate([sp, st], axis=-1)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        vall = jnp.concatenate([pv, v], axis=2)
-        o = jnp.einsum("bhst,bhtd->bhsd", probs.astype(vall.dtype),
+        vall = jnp.concatenate([pv, jnp.swapaxes(v, 1, 2)], axis=1)
+        o = jnp.einsum("bhst,bthd->bhsd", probs.astype(vall.dtype),
                        vall)
         x = x + jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
         f = _layer_norm(bp["ln_m"], x)
         x = x + _mlp(bp, f)
-        tail_caches.append((k, v))
+        tail_caches.append((kv_rows(k), kv_rows(v)))
     return x, tail_caches
 
 

@@ -33,9 +33,9 @@ Install once per process (the web service does), plug
 The same module owns the names a DEVICE profile goes by: :func:`annotate`
 writes the program's host spans (``SPANS``) into the profiler's own
 trace, on the device's clock; ``KERNELS``, ``SCOPES`` and ``PROGRAMS``
-are the names the flash kernels, the ``jax.named_scope`` regions and the
-jitted programs carry there.  Tests and the benchmark's readers import
-them from here.
+are the names the attention kernels, the ``jax.named_scope`` regions
+and the jitted programs carry there.  Tests and the benchmark's readers
+import them from here.
 """
 
 from __future__ import annotations
@@ -60,11 +60,15 @@ SPANS = tuple(SPAN_PREFIX + n for n in (
     "input/produce", "input/h2d",
     "decode/admit", "decode/admit_fetch", "decode/dispatch",
     "decode/fetch", "decode/fanout", "decode/idle"))
-#: ``name=`` of the flash attention ``pallas_call``s (ops/attention.py)
+#: ``name=`` of the ``pallas_call``s of ops/attention.py: the three flash
+#: kernels of the train step and the prefill, and the length-bounded
+#: decode-attention kernel of the decode step
 KERNEL_FLASH_FWD = "zoo_flash_fwd"
 KERNEL_FLASH_BWD_DQ = "zoo_flash_bwd_dq"
 KERNEL_FLASH_BWD_DKV = "zoo_flash_bwd_dkv"
-KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV)
+KERNEL_DECODE_ATTN = "zoo_decode_attn"
+KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV,
+           KERNEL_DECODE_ATTN)
 #: regions inside the jitted programs: ``jax.named_scope``s, which are
 #: HLO metadata (an executable answered from the persistent compilation
 #: cache keeps the metadata of whoever compiled it first), and

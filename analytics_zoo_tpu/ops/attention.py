@@ -616,7 +616,7 @@ def _tiled_block(n: int, cap: int) -> int:
 # and 15 MiB (d 64), and between 13 and 16 MiB the outcome is irregular
 # (what else the kernel keeps varies) — hence a 4 MiB reserve, not a
 # derived figure.  So bf16 runs to sk 12288 and f32 to 6144 (d <= 128).
-# Streaming K/V would lift the bound (ROADMAP S7).
+# Streaming K/V would lift the bound (ROADMAP S5).
 _VMEM_LIMIT = 16 * 2 ** 20
 _VMEM_RESERVE = 4 * 2 ** 20
 _VMEM_BUDGET = _VMEM_LIMIT - _VMEM_RESERVE
@@ -812,3 +812,390 @@ def attention(q, k, v, causal: bool = False, implementation: str = "auto",
         return naive_attention(q, k, v, causal=causal,
                                kv_lengths=kv_lengths)
     raise ValueError(f"Unknown implementation {implementation!r}")
+
+
+# ------------------------------------------- decode: the key/value slab
+#
+# The decode paths keep each layer's keys and values in a pair of SLABS
+# of shape (capacity, max_len, n_heads * d_head): one row is one whole
+# position, all heads side by side.  A decode step needs whole positions
+# of every head, and a row of n_heads * d_head floats is a multiple of
+# the chip's 128 lanes where d_head alone (64) is not: the compiler
+# tiles this shape without padding and keeps no padded copy of it.  The
+# functions below are the only code that knows the layout; every site
+# that allocates, describes, fills, writes or views a slab calls them.
+
+def kv_slab_shape(capacity: int, max_len: int, n_heads: int,
+                  d_head: int):
+    """Shape of one slab (keys, or values) of one layer."""
+    return (capacity, max_len, n_heads * d_head)
+
+
+def kv_slab_spec(capacity: int, max_len: int, n_heads: int, d_head: int,
+                 sharding=None, dtype=jnp.float32):
+    """``(k, v)`` ShapeDtypeStructs of one layer's pair."""
+    spec = jax.ShapeDtypeStruct(
+        kv_slab_shape(capacity, max_len, n_heads, d_head), dtype,
+        sharding=sharding)
+    return spec, spec
+
+
+def kv_slab_zeros(capacity: int, max_len: int, n_heads: int, d_head: int,
+                  dtype=jnp.float32):
+    """One layer's ``(k, v)`` pair, empty."""
+    shape = kv_slab_shape(capacity, max_len, n_heads, d_head)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def kv_rows(x):
+    """Projected keys or values ``(b, heads, s, d_head)``, as the
+    prefill's attention takes them, as slab rows ``(b, s, heads *
+    d_head)``."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def kv_heads(rows, n_heads: int):
+    """Slab rows ``(b, t, heads * d_head)`` with the heads apart,
+    ``(b, t, heads, d_head)``: the view a many-query attention contracts
+    against (``einsum("bhkd,bthd->bhkt", q, kv_heads(ck, h))``).  A
+    reshape, no copy."""
+    b, t, hd = rows.shape
+    return rows.reshape(b, t, n_heads, hd // n_heads)
+
+
+def kv_pad(rows, cache_len: int):
+    """Rows of a prompt, zero-padded on the right to a slab's length."""
+    return jnp.pad(rows, [(0, 0), (0, cache_len - rows.shape[1]), (0, 0)])
+
+
+def kv_insert(slab, rows, slot, start=0):
+    """Write ``rows`` (1, s, heads * d_head) of one sequence into slot
+    ``slot`` of the slab, at positions ``[start, start + s)``."""
+    return lax.dynamic_update_slice(slab, rows.astype(slab.dtype),
+                                    (slot, start, 0))
+
+
+def kv_write_row(slab, row, pos):
+    """Write one position's row ``(b, heads * d_head)`` of every
+    sequence at ``pos``: a position all share, or ``(b,)`` positions of
+    their own."""
+    new = row[:, None, :].astype(slab.dtype)
+    if jnp.ndim(pos) == 0:
+        return lax.dynamic_update_slice_in_dim(slab, new, pos, axis=1)
+    return jax.vmap(
+        lambda sb, nb, pb: lax.dynamic_update_slice_in_dim(
+            sb, nb, pb, axis=0))(slab, new, pos)
+
+
+# -------------------------------------------- decode: the attention op
+
+#: positions a grid step of the decode kernel takes from a slab.  At a
+#: mean of 280 live positions a slot, 128 reads 80 % of what it fetches
+#: and 256 reads 68 %.
+_DECODE_BLOCK = 128
+#: rows of the tile in which the kernel rewrites a slab's new row
+_ROW_TILE = 8
+
+
+def _decode_plan(slots: int, max_len: int, row_width: int, n_heads: int,
+                 dtype):
+    """The key block of the decode kernel for one slab shape, or
+    ``(None, reason)``: the single source of eligibility, as
+    ``_flash_plan`` is for flash.  The kernel wants whole blocks
+    (``max_len`` a multiple of the block), a lane-dense row
+    (``heads * d_head`` a multiple of 128, ``d_head`` a divisor or a
+    multiple of 128 so that a lane tile holds whole heads, at most 128
+    heads: their scores share one lane tile), float32 (the 8-row write
+    tile is the float32 one) and its per-slot operands in VMEM."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return None, f"the slab is {jnp.dtype(dtype).name}, not float32"
+    d = row_width // max(n_heads, 1)
+    if (row_width % 128 or row_width % n_heads or n_heads > 128
+            or (128 % d and d % 128)):
+        return None, (f"a row of {row_width} floats in {n_heads} heads is "
+                      "not lane-dense (a multiple of 128, d_head a "
+                      "divisor or a multiple of 128, at most 128 heads)")
+    if max_len % _DECODE_BLOCK:
+        return None, (f"max_len {max_len} is not a multiple of the key "
+                      f"block ({_DECODE_BLOCK})")
+    # double-buffered: q, the two new rows and the accumulator of every
+    # slot, their per-head scalars, two blocks of each slab, the 0/1
+    # matrix; the same reserve as the flash kernels keep
+    slots8 = -(-slots // 8) * 8
+    resident = 2 * 4 * (4 * slots8 * row_width + 2 * slots8 * 128
+                        + 2 * _DECODE_BLOCK * row_width) \
+        + 2 * 2 * row_width * 128
+    if resident > _VMEM_BUDGET:
+        return None, (f"{slots} slots of {row_width} floats pin {resident} "
+                      f"bytes of VMEM, past the {_VMEM_BUDGET} the kernel "
+                      "may")
+    return _DECODE_BLOCK, None
+
+
+def _kernel_block(slots: int, max_len: int, row_width: int, n_heads: int,
+                  dtype):
+    """The kernel's key block where the kernel runs (a TPU, a shape the
+    plan admits), else ``None``."""
+    if not _on_tpu():
+        return None
+    return _decode_plan(slots, max_len, row_width, n_heads, dtype)[0]
+
+
+def decode_read_block(slots: int, max_len: int, row_width: int,
+                      n_heads: int, dtype=jnp.float32) -> int:
+    """Positions of a slot's slab that one decode step reads at a time:
+    the kernel's key block where :func:`decode_attention` runs the
+    kernel (it then reads the blocks up to the slot's length and no
+    more), else ``max_len`` (the masked full-length softmax reads the
+    whole slab).  What the engine's ``kv_positions_read`` counts by."""
+    return _kernel_block(slots, max_len, row_width, n_heads,
+                         dtype) or max_len
+
+
+@functools.lru_cache(maxsize=None)
+def _segments(row_width: int, n_heads: int):
+    """``(row_width, 128)`` 0/1 matrix ``S[i, h] = (i // d_head == h)``
+    (numpy, made once per shape): ``x @ S`` sums a row's lanes head by
+    head.  bfloat16 holds 0 and 1 exactly."""
+    d = row_width // n_heads
+    return (np.arange(row_width)[:, None] // d
+            == np.arange(128)[None, :]).astype(np.float32)
+
+
+def _dot_exact(a, seg):
+    """``a @ seg`` in float32 for a 0/1 ``seg``: ``a`` split into three
+    bfloat16 terms that add up to it (8 + 8 + 8 bits of mantissa), one
+    MXU pass each, summed in float32.  The 0/1 operand is exact in
+    bfloat16, so nothing is lost but the float32 sums' own rounding:
+    the products of queries and keys stay float32, as in the masked
+    full-softmax this kernel replaces."""
+    a1 = a.astype(jnp.bfloat16)
+    r = a - a1.astype(jnp.float32)
+    a2 = r.astype(jnp.bfloat16)
+    a3 = (r - a2.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    def dot(x):
+        return jnp.dot(x, seg, preferred_element_type=jnp.float32)
+
+    return dot(a1) + dot(a2) + dot(a3)
+
+
+def _spread(x, row_width: int, d_head: int):
+    """``x (rows, 128)``, a value per head in lane ``h``, over the lanes
+    of the heads: ``(rows, row_width)`` with ``x[:, i // d_head]`` in
+    lane ``i``.  Lane broadcasts and selects, one lane tile at a time:
+    exact, and cheaper here than a product with the transposed 0/1
+    matrix."""
+    rows = x.shape[0]
+    lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+
+    def of_head(h):
+        return jnp.broadcast_to(x[:, h:h + 1], (rows, 128))
+
+    tiles = []
+    for c in range(row_width // 128):
+        if d_head >= 128:
+            tiles.append(of_head(128 * c // d_head))
+            continue
+        per = 128 // d_head             # heads in this lane tile
+        tile = of_head(per * c + per - 1)
+        for i in range(per - 2, -1, -1):
+            tile = jnp.where(lane < (i + 1) * d_head, of_head(per * c + i),
+                             tile)
+        tiles.append(tile)
+    return jnp.concatenate(tiles, axis=1)
+
+
+def _decode_attn_kernel(pos_ref, slot_ref, blk_ref, q_ref, kn_ref, vn_ref,
+                        m0_ref, ck_ref, cv_ref, seg_ref,
+                        acc_out, l_out, cko_ref, cvo_ref,
+                        m_ref, l_ref, acc_ref, *, block: int, d_head: int):
+    """One LIVE key block of one slot.  The grid is flat and runs over
+    the live blocks only, slot after slot (``slot_ref`` / ``blk_ref``,
+    scalar prefetch, say which; ``pos_ref`` holds every slot's position:
+    the new row's index, so positions ``< pos`` of the slab are live).
+    The softmax state (running max and sum per head, accumulator per
+    lane) starts from the NEW row, which never leaves VMEM; each block
+    then adds its positions ``< pos`` (online softmax); a slot's last
+    block, the one that holds row ``pos``, also rewrites that row's
+    8-row tile in place and hands out the slot's sum and accumulator.
+    The per-slot operands (query, new rows, the new row's scores) and
+    results are whole-array blocks that stay in VMEM: nothing small is
+    fetched or written back between slots, so one slot's blocks stream
+    in behind the last one's."""
+    g = pl.program_id(0)
+    s, j = slot_ref[g], blk_ref[g]
+    pos = pos_ref[s]
+    mine = pl.ds(s, 1)                  # this slot's row of the operands
+    row = ck_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _start():
+        m_ref[...] = m0_ref[mine, :]
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = vn_ref[mine, :].astype(jnp.float32)
+
+    live = (j * block + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+            < pos)
+    scores = jnp.where(
+        live, _dot_exact(ck_ref[...] * q_ref[mine, :], seg_ref[...]),
+        NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new)         # a dead row: exp(-1e30 - m) = 0
+    l_new = l_ref[...] * corr + jnp.sum(p, axis=0, keepdims=True)
+    l_ref[...] = l_new
+    m_ref[...] = m_new
+    # a dead row may hold anything (NaN times 0 is NaN): select it out
+    acc = (acc_ref[...] * _spread(corr, row, d_head)
+           + jnp.sum(_spread(p, row, d_head)
+                     * jnp.where(live, cv_ref[...], 0.0),
+                     axis=0, keepdims=True))
+    acc_ref[...] = acc
+
+    @pl.when(j == pos // block)
+    def _finish():
+        tile = pl.multiple_of((pos % block) // _ROW_TILE * _ROW_TILE,
+                              _ROW_TILE)
+        new = (lax.broadcasted_iota(jnp.int32, (_ROW_TILE, 1), 0)
+               == pos % _ROW_TILE)
+        cko_ref[...] = jnp.where(new, kn_ref[mine, :],
+                                 ck_ref[pl.ds(tile, _ROW_TILE), :])
+        cvo_ref[...] = jnp.where(new, vn_ref[mine, :],
+                                 cv_ref[pl.ds(tile, _ROW_TILE), :])
+        acc_out[mine, :] = acc
+        l_out[mine, :] = l_new
+
+
+def _live_blocks(pos, block: int, n_blocks: int):
+    """The kernel's grid: slot ``b`` takes ``pos[b] // block + 1`` steps
+    (the blocks up to the one that holds its new row), slot after slot.
+    Returns ``(slot of step, block of step, steps in all)``; entries
+    past the last step are never read."""
+    b = pos.shape[0]
+    mine = pos // block + 1
+    ends = jnp.cumsum(mine)
+    steps = jnp.arange(b * n_blocks, dtype=jnp.int32)
+    slot_of = jnp.minimum(
+        jnp.sum(ends[None, :] <= steps[:, None], axis=1), b - 1)
+    blk_of = steps - (ends - mine)[slot_of]
+    return slot_of.astype(jnp.int32), blk_of.astype(jnp.int32), ends[-1]
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "block",
+                                             "interpret"))
+def _decode_attn_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
+                      block: int, interpret: bool):
+    """The kernel over ``(b, row)`` q / new rows, ``(b, max_len, row)``
+    slabs and ``(b,)`` int32 positions.  Around it, in plain jax: the
+    new row's own scores (where the softmax state starts), the list of
+    live blocks, and the division by the softmax's sum.
+
+    A jit of its own inside the step: a model's layers all call it with
+    the same shapes, so it is traced once and lowered once a program (a
+    private function the layers call) instead of once a layer; lowering
+    a Mosaic kernel 24 times over took seconds of every plan's build and
+    made each executable carry 24 copies of it.  XLA inlines the calls,
+    so the slabs are still updated in place."""
+    b, t, row = ck.shape
+    d = row // n_heads
+    qs = q * (1.0 / math.sqrt(d))
+    m0 = jnp.pad((qs * k_new).reshape(b, n_heads, d).sum(-1),
+                 ((0, 0), (0, 128 - n_heads)))
+    slot_of, blk_of, n_steps = _live_blocks(pos, block, t // block)
+    seg = jnp.asarray(_segments(row, n_heads), jnp.bfloat16)
+
+    def whole(*shape):      # fetched once, stays in VMEM
+        return pl.BlockSpec(shape, lambda g, pos, so, bo: (0,) * len(shape))
+
+    slab = pl.BlockSpec((None, block, row),
+                        lambda g, pos, so, bo: (so[g], bo[g], 0))
+    tile = pl.BlockSpec(
+        (None, _ROW_TILE, row),
+        lambda g, pos, so, bo: (so[g], pos[so[g]] // _ROW_TILE, 0))
+    acc, l, ck, cv = pl.pallas_call(
+        functools.partial(_decode_attn_kernel, block=block, d_head=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_steps,),        # as many steps as blocks are live
+            in_specs=[whole(b, row), whole(b, row), whole(b, row),
+                      whole(b, 128), slab, slab, whole(*seg.shape)],
+            out_specs=[whole(b, row), whole(b, 128), tile, tile],
+            scratch_shapes=[pltpu.VMEM((1, 128), jnp.float32),
+                            pltpu.VMEM((1, 128), jnp.float32),
+                            pltpu.VMEM((1, row), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, row), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 128), jnp.float32),
+                   jax.ShapeDtypeStruct(ck.shape, ck.dtype),
+                   jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
+        # operands count from the scalar prefetch: the slabs are 7 and 8
+        input_output_aliases={7: 2, 8: 3},
+        interpret=interpret,
+        name=_profile.KERNEL_DECODE_ATTN,
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}),
+    )(pos, slot_of, blk_of, qs, k_new, v_new, m0, ck, cv, seg)
+    out = acc / jnp.repeat(l[:, :n_heads], d, axis=-1)
+    return out.astype(q.dtype), ck, cv
+
+
+def _decode_attention_reference(q, k_new, v_new, ck, cv, pos,
+                                n_heads: int):
+    """The masked full-length softmax in ``jax.numpy``: what runs off
+    the chip and for shapes the kernel refuses, and what the kernel is
+    tested against.  ``pos`` may be a scalar all rows share."""
+    ck = kv_write_row(ck, k_new, pos)
+    cv = kv_write_row(cv, v_new, pos)
+    b, t, row = ck.shape
+    d = row // n_heads
+    scores = jnp.einsum("bhd,bthd->bht", q.reshape(b, n_heads, d),
+                        kv_heads(ck, n_heads)) / math.sqrt(d)
+    posv = jnp.broadcast_to(pos, (b,))
+    valid = jnp.arange(t)[None, None, :] <= posv[:, None, None]
+    scores = jnp.where(valid, scores, NEG_INF)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    o = jnp.einsum("bht,bthd->bhd", probs.astype(cv.dtype),
+                   kv_heads(cv, n_heads))
+    return o.reshape(b, row), ck, cv
+
+
+def decode_attention(q, k_new, v_new, ck, cv, pos, n_heads: int,
+                     mesh=None):
+    """One decode step's attention of every sequence over its own slab.
+
+    ``q``, ``k_new``, ``v_new``: ``(b, heads * d_head)``, the step's
+    query and the new position's key and value; ``ck``, ``cv``:
+    ``(b, max_len, heads * d_head)`` slabs (``kv_slab_*``); ``pos``: the
+    new position, a scalar all sequences share or ``(b,)`` of their
+    own, in ``[0, max_len)``.  Writes the new row at ``pos`` and attends
+    to positions ``<= pos``.  Returns ``(o (b, heads * d_head), ck,
+    cv)``.
+
+    On a TPU, for a shape ``_decode_plan`` admits, this is the pallas
+    kernel ``zoo_decode_attn``: its grid runs over the live key blocks
+    only (a slot's slab up to the block that holds ``pos``) and it
+    rewrites the new row in place, so a step moves the live positions
+    and nothing else.  Otherwise it is the
+    masked full-length softmax.  ``mesh``: the mesh over whose axes the
+    caller has sharded the sequences (a mesh-sharded decode engine); the
+    kernel then runs inside a ``shard_map`` over them, each device on
+    its own sequences (GSPMD cannot partition a Mosaic kernel)."""
+    b, t, row = ck.shape
+    block = _kernel_block(b, t, row, n_heads, ck.dtype)
+    if block is None:
+        return _decode_attention_reference(q, k_new, v_new, ck, cv, pos,
+                                           n_heads)
+    pos = jnp.clip(jnp.broadcast_to(pos, (b,)).astype(jnp.int32), 0, t - 1)
+    call = functools.partial(_decode_attn_call, n_heads=n_heads,
+                             block=block, interpret=False)
+    if mesh is None or mesh.size == 1:
+        return call(q, k_new, v_new, ck, cv, pos)
+    axes = tuple(mesh.axis_names)
+    rows, slabs = P(axes, None), P(axes, None, None)
+    return jax.shard_map(
+        call, mesh=mesh,
+        in_specs=(rows, rows, rows, slabs, slabs, P(axes)),
+        out_specs=(rows, slabs, slabs), check_vma=False)(
+            q, k_new, v_new, ck, cv, pos)

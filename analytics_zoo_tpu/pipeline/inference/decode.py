@@ -14,9 +14,10 @@ its slowest member does, and short requests idle in finished rows.
   K/V into a free slot of the decode state — one ``admit`` executable
   per (prompt bucket, capacity), compiled once.
 * **A single persistent slot-array decode executable.**  The decode
-  state is a fixed-capacity slot array — per-layer K/V caches of shape
-  ``(capacity, heads, max_len, d_head)`` plus per-slot current token
-  and write position — stepped by ONE jitted function whose shapes
+  state is a fixed-capacity slot array — per-layer K/V slabs of shape
+  ``(capacity, max_len, heads * d_head)`` (``ops.attention.kv_*`` owns
+  the layout) plus per-slot current token and write position — stepped
+  by ONE jitted function whose shapes
   never depend on occupancy.  Attention masks derive from per-slot
   positions, so occupied and free slots coexist in the same dispatch:
   admission and eviction are state writes, never recompiles.  Exactly
@@ -104,6 +105,8 @@ from ...models.generation import (_decode_step, _decode_window,
                                   _embed_token, _head_logits, _prefill,
                                   _prefill_ext, _sample)
 from ...observability import profile as _profile
+from ...ops.attention import (decode_read_block, kv_insert, kv_slab_spec,
+                              kv_slab_zeros)
 from ...observability.log import get_logger as _get_logger
 from .serving import _execstore, bucket_ladder
 
@@ -510,21 +513,13 @@ class DecodeEngine:
         # free slots are don't-cares — their writes land in cache
         # positions a future occupant always overwrites before
         # attending (write-then-attend, see _build_step_fn).
-        d_head = int(hyper["d_model"]) // int(hyper["n_heads"])
-        shape = (self.capacity, int(hyper["n_heads"]), self.max_len,
-                 d_head)
         with jax.default_device(self._device):
-            caches = [(jnp.zeros(shape, jnp.float32),
-                       jnp.zeros(shape, jnp.float32))
+            caches = [kv_slab_zeros(*self._slab_dims(hyper))
                       for _ in range(self._n_layers)]
             dcaches = []
             if self._draft_hyper is not None:
                 dh = self._draft_hyper
-                dshape = (self.capacity, int(dh["n_heads"]),
-                          self.max_len,
-                          int(dh["d_model"]) // int(dh["n_heads"]))
-                dcaches = [(jnp.zeros(dshape, jnp.float32),
-                            jnp.zeros(dshape, jnp.float32))
+                dcaches = [kv_slab_zeros(*self._slab_dims(dh))
                            for _ in range(int(dh["n_layers"]))]
             tok = jnp.zeros((self.capacity,), jnp.int32)
             pos = jnp.zeros((self.capacity,), jnp.int32)
@@ -545,8 +540,14 @@ class DecodeEngine:
         # so an uncommitted first call would cost every admit plan a
         # SECOND compile the first time it sees steady-state inputs,
         # breaking the one-compile-per-(bucket, capacity) invariant
-        self._caches = jax.device_put(caches, self._slot_sharding(4))
-        self._dcaches = jax.device_put(dcaches, self._slot_sharding(4))
+        self._caches = jax.device_put(caches, self._slot_sharding(3))
+        self._dcaches = jax.device_put(dcaches, self._slot_sharding(3))
+        # positions of a slot's slab that one step reads at a time (the
+        # decode kernel's block, or the whole slab where it does not
+        # run): what ``kv_positions_read`` rounds a length up to
+        self._kv_block = decode_read_block(
+            self.capacity, self.max_len, int(hyper["d_model"]),
+            int(hyper["n_heads"]))
         self._tok = jax.device_put(tok, self._slot_sharding(1))
         self._pos = jax.device_put(pos, self._slot_sharding(1))
         self._samp = jax.device_put(samp, self._slot_sharding(1))
@@ -606,6 +607,10 @@ class DecodeEngine:
                           "prefix_hits": 0, "prefix_misses": 0,
                           "prefix_evictions": 0, "spec_windows": 0,
                           "spec_proposed": 0, "spec_accepted": 0,
+                          # positions of the target model's slabs that
+                          # the dispatched steps had live, and that they
+                          # read (_kv_positions)
+                          "kv_positions_live": 0, "kv_positions_read": 0,
                           # submit -> admission, summed (beside
                           # ``admitted``), and the dispatcher thread's
                           # time by what it was doing (_LoopPhase)
@@ -636,6 +641,12 @@ class DecodeEngine:
         self._thread = threading.Thread(
             target=self._decode_loop, name="zoo-decode-dispatch",
             daemon=True)
+
+    def _slab_dims(self, hyper):
+        """(capacity, max_len, heads, d_head) of a model's slabs."""
+        n_heads = int(hyper["n_heads"])
+        return (self.capacity, self.max_len, n_heads,
+                int(hyper["d_model"]) // n_heads)
 
     # ---- placement shardings --------------------------------------------
     def _rep_sharding(self):
@@ -702,7 +713,8 @@ class DecodeEngine:
         params, hyper, max_len = weights[0], self._hyper, self.max_len
         posc = jnp.minimum(pos, max_len - 1)
         emb = _embed_token(params, tok, posc)
-        logits, caches = _decode_step(params, hyper, caches, emb, posc)
+        logits, caches = _decode_step(params, hyper, caches, emb, posc,
+                                      mesh=self._mesh)
         nxt = self._select(logits, samp)
         seed, stepc, temp, topk, topp = samp
         return (caches, nxt, jnp.minimum(pos + 1, max_len),
@@ -732,27 +744,21 @@ class DecodeEngine:
         one plan signature)."""
         if self._draft_hyper is None:
             return []
-        s0 = self._slot_sharding(4)
         dh = self._draft_hyper
-        dspec = jax.ShapeDtypeStruct(
-            (self.capacity, int(dh["n_heads"]), self.max_len,
-             int(dh["d_model"]) // int(dh["n_heads"])), jnp.float32,
-            sharding=s0)
-        return [(dspec, dspec) for _ in range(int(dh["n_layers"]))]
+        pair = kv_slab_spec(*self._slab_dims(dh),
+                            sharding=self._slot_sharding(3))
+        return [pair for _ in range(int(dh["n_layers"]))]
 
     def _state_specs(self):
         """ShapeDtypeStructs matching the persistent decode state —
         the AOT lowering inputs for the step/admit plans (committed to
         the engine's device — or slot-sharded over its mesh — exactly
         like the live state)."""
-        d_head = (int(self._hyper["d_model"])
-                  // int(self._hyper["n_heads"]))
-        cspec = jax.ShapeDtypeStruct(
-            (self.capacity, int(self._hyper["n_heads"]), self.max_len,
-             d_head), jnp.float32, sharding=self._slot_sharding(4))
+        pair = kv_slab_spec(*self._slab_dims(self._hyper),
+                            sharding=self._slot_sharding(3))
         ispec = jax.ShapeDtypeStruct((self.capacity,), jnp.int32,
                                      sharding=self._slot_sharding(1))
-        caches = [(cspec, cspec) for _ in range(self._n_layers)]
+        caches = [pair for _ in range(self._n_layers)]
         return caches, ispec, ispec, self._samp_specs()
 
     def _plan(self, name: str, jitted, arg_specs):
@@ -822,7 +828,7 @@ class DecodeEngine:
         """The persistent single-step plan: (caches, tok, pos, samp)
         -> (caches', tok', pos', samp')."""
         # the caches are DONATED: without donation every step copies
-        # the whole (capacity, heads, max_len, d_head) cache array per
+        # the whole (capacity, max_len, heads * d_head) slab per
         # layer just to update one position — the in-place update the
         # scan path gets for free from its loop carry.  Measured ~40%
         # off the per-step wall on CPU; the loop always rebinds the
@@ -1003,23 +1009,15 @@ class DecodeEngine:
             logits0 = _head_logits(params, last[None, :])[0]
             tok0 = self._sample_first(logits0, seed0, temp0, topk0,
                                       topp0)
-            new_caches = []
-            for (ck, cv), (pk, pv) in zip(caches, pc):
-                ck = lax.dynamic_update_slice(
-                    ck, pk.astype(ck.dtype), (slot, 0, 0, 0))
-                cv = lax.dynamic_update_slice(
-                    cv, pv.astype(cv.dtype), (slot, 0, 0, 0))
-                new_caches.append((ck, cv))
+            new_caches = [
+                (kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))
+                for (ck, cv), (pk, pv) in zip(caches, pc)]
             new_dcaches = dcaches
             if dhyper is not None:
                 _, dpc = _prefill(dparams, dhyper, prompt, s_b)
-                new_dcaches = []
-                for (ck, cv), (pk, pv) in zip(dcaches, dpc):
-                    ck = lax.dynamic_update_slice(
-                        ck, pk.astype(ck.dtype), (slot, 0, 0, 0))
-                    cv = lax.dynamic_update_slice(
-                        cv, pv.astype(cv.dtype), (slot, 0, 0, 0))
-                    new_dcaches.append((ck, cv))
+                new_dcaches = [
+                    (kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))
+                    for (ck, cv), (pk, pv) in zip(dcaches, dpc)]
             tok, pos, samp = self._slot_write(
                 (tok, pos, samp), slot, tok0, length, seed0, temp0,
                 topk0, topp0)
@@ -1059,7 +1057,8 @@ class DecodeEngine:
 
     def _build_pfxfill_fn(self, p_b: int):
         """The prefix-prefill plan: (1, p_b) prefix ids -> (per-layer
-        (k, v) blocks (1, heads, p_b, d_head), last hidden (d,)).
+        (k, v) blocks, slab rows (1, p_b, heads * d_head), last hidden
+        (d,)).
         Runs ONCE per distinct prefix content (the pool miss); its
         outputs are exactly what a pool hit memcpys, which is why hit
         and miss admissions are bit-identical."""
@@ -1084,14 +1083,11 @@ class DecodeEngine:
 
     def _pfx_block_specs(self, p_b: int):
         s0 = self._rep_sharding()
-        h = self._hyper
-        d_head = int(h["d_model"]) // int(h["n_heads"])
-        bspec = jax.ShapeDtypeStruct(
-            (1, int(h["n_heads"]), p_b, d_head), jnp.float32,
-            sharding=s0)
-        hspec = jax.ShapeDtypeStruct((int(h["d_model"]),), jnp.float32,
+        _, _, n_heads, d_head = self._slab_dims(self._hyper)
+        pair = kv_slab_spec(1, p_b, n_heads, d_head, sharding=s0)
+        hspec = jax.ShapeDtypeStruct((n_heads * d_head,), jnp.float32,
                                      sharding=s0)
-        return [(bspec, bspec) for _ in range(self._n_layers)], hspec
+        return [pair for _ in range(self._n_layers)], hspec
 
     def _build_pfxadmit_fn(self, p_b: int, s_b: int):
         """The pooled admission plan for (prefix bucket, prompt
@@ -1114,16 +1110,11 @@ class DecodeEngine:
             new_caches = []
             for i, (ck, cv) in enumerate(caches):
                 pk, pv = pkv[i]
-                ck = lax.dynamic_update_slice(
-                    ck, pk.astype(ck.dtype), (slot, 0, 0, 0))
-                cv = lax.dynamic_update_slice(
-                    cv, pv.astype(cv.dtype), (slot, 0, 0, 0))
+                ck, cv = kv_insert(ck, pk, slot), kv_insert(cv, pv, slot)
                 if tail_pad:
                     tk, tv = tc[i]
-                    ck = lax.dynamic_update_slice(
-                        ck, tk.astype(ck.dtype), (slot, 0, p_b, 0))
-                    cv = lax.dynamic_update_slice(
-                        cv, tv.astype(cv.dtype), (slot, 0, p_b, 0))
+                    ck = kv_insert(ck, tk, slot, p_b)
+                    cv = kv_insert(cv, tv, slot, p_b)
                 new_caches.append((ck, cv))
             if tail_pad:
                 ti = jnp.clip(length - p_b - 1, 0, tail_pad - 1)
@@ -1678,6 +1669,25 @@ class DecodeEngine:
                 return k
         return 1
 
+    def _kv_positions(self, k: int) -> Tuple[int, int]:
+        """What the next ``k`` steps of the live slots find in one
+        layer's slab: the positions that are live (a slot's length, its
+        new row included, step by step) and the positions the step reads
+        for them (each length rounded up to ``_kv_block``: the decode
+        kernel's key block, or the whole slab where the kernel does not
+        run).  Their ratio says how much of what a step moves it uses;
+        free slots are in neither."""
+        live = read = 0
+        block = self._kv_block
+        for req in self._slots:
+            if req is not None:
+                for n in range(req.length + req.scheduled,
+                               req.length + req.scheduled + k):
+                    n = min(n, self.max_len)
+                    live += n
+                    read += -(-n // block) * block
+        return live, read
+
     def _dispatch_step(self):
         """Dispatch the next decode window WITHOUT fetching (jax
         dispatch is asynchronous) and snapshot the slot->request map as
@@ -1693,7 +1703,10 @@ class DecodeEngine:
         if self._draft_hyper is not None:
             return self._dispatch_spec()
         k = self._choose_fuse()
-        with self._phase("dispatch", k=k, live=self._occupancy):
+        kv_live, kv_read = self._kv_positions(k)
+        with self._phase("dispatch", k=k, live=self._occupancy,
+                         kv_positions_live=kv_live,
+                         kv_positions_read=kv_read):
             if k > 1:
                 (self._caches, self._tok, self._pos, self._samp,
                  toks) = self._stepk_fns[k](self._caches, self._tok,
@@ -1705,6 +1718,8 @@ class DecodeEngine:
                                              self._pos, self._samp)
                 toks = self._tok
             self._counters["steps"] += k
+            self._counters["kv_positions_live"] += kv_live
+            self._counters["kv_positions_read"] += kv_read
             for req in self._slots:
                 if req is not None:
                     req.scheduled += k
@@ -1717,12 +1732,19 @@ class DecodeEngine:
         pending tuple so the fetch side knows how many of each slot's
         ``spec_tokens`` candidates are valid."""
         k = self.spec_tokens
-        with self._phase("dispatch", k=k, live=self._occupancy):
+        # of the target's slabs, the window's one exact step goes
+        # through the decode-attention op; the verify reads them whole
+        kv_live, kv_read = self._kv_positions(1)
+        with self._phase("dispatch", k=k, live=self._occupancy,
+                         kv_positions_live=kv_live,
+                         kv_positions_read=kv_read):
             (self._caches, self._dcaches, self._tok, self._pos,
              self._samp, toks, acc) = self._spec_fn(
                 self._caches, self._dcaches, self._tok, self._pos,
                 self._samp)
             self._counters["steps"] += k
+            self._counters["kv_positions_live"] += kv_live
+            self._counters["kv_positions_read"] += kv_read
             self._counters["spec_windows"] += 1
             for req in self._slots:
                 if req is not None:

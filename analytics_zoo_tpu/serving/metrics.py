@@ -100,6 +100,7 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
         "zoo_decode_spec_accepted_total": [],
         "zoo_decode_queue_wait_seconds_total": [],
         "zoo_decode_loop_seconds_total": [],
+        "zoo_decode_kv_positions_total": [],
     }
     decode_gauges: Dict[str, List] = {
         "zoo_decode_slot_occupancy": [],
@@ -223,6 +224,9 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
                 ({**ml, "phase": key[len("loop_"):-len("_s")]}, v)
                 for key, v in dec.items()
                 if key.startswith("loop_") and key.endswith("_s"))
+            decode_counters["zoo_decode_kv_positions_total"].extend(
+                ({**ml, "kind": kind}, dec.get(f"kv_positions_{kind}", 0))
+                for kind in ("live", "read"))
             decode_gauges["zoo_decode_slot_occupancy"].append(
                 (ml, dec.get("slots_active", 0)))
             decode_gauges["zoo_decode_slot_capacity"].append(
@@ -339,6 +343,12 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
             "decode dispatcher thread seconds by phase: host work "
             "(admit/dispatch/fanout), waiting on the device "
             "(admit_fetch/fetch), waiting for work (idle)",
+        "zoo_decode_kv_positions_total":
+            "positions of a layer's key/value slab over the dispatched "
+            "decode steps of the live slots: live (each slot's length) "
+            "and read (rounded up to what a step fetches: the decode "
+            "kernel's key block, or the whole slab where it does not "
+            "run); live/read is how much of what a step moves it uses",
         "zoo_decode_slot_occupancy":
             "decode slots currently holding a live sequence",
         "zoo_decode_slot_capacity":

@@ -4,7 +4,7 @@
 executable: after the call, the arrays passed at donated positions are
 INVALID — XLA may already have reused their memory as the output (the
 whole point: the DecodeEngine's slot-array step updates its
-(capacity, heads, max_len, d_head) caches in place instead of copying
+(capacity, max_len, heads * d_head) slabs in place instead of copying
 them per token).  Reading a donated buffer afterwards is at best a
 ``RuntimeError: Array has been deleted`` and at worst silent garbage
 on a backend that aliased eagerly.  The protocol the decode loop pins

@@ -1,7 +1,8 @@
 """The names a device profile goes by (ISSUE 26): the program's host
 spans on the profiler's clock (``profile.annotate``), the decode
 dispatcher's always-on loop counters, the ``jax.named_scope`` regions,
-the pinned program names and the flash kernels' names.
+the pinned program names and the kernels' names; and (ISSUE 27) the
+dispatcher's count of the slab positions its steps had live and read.
 
 All on the CPU: a profiler session here records host events only, which
 is what the spans are.  Read back with ``jax.profiler.ProfileData``, the
@@ -135,7 +136,13 @@ def test_decode_spans_land_in_the_profile_with_their_stats(lm, tmp_path):
     for fetch in tr.named("decode/admit_fetch"):
         assert sum(inside(fetch, a) for a in admits) == 1
     for ev in tr.named("decode/dispatch"):
-        assert ev[4]["k"] in (1, 2, 4) and 1 <= ev[4]["live"] <= 3
+        stats = ev[4]
+        assert stats["k"] in (1, 2, 4) and 1 <= stats["live"] <= 3
+        # every live slot has its new row at least, and no more than the
+        # slab; off the chip a step reads the whole slab of each
+        floor = stats["k"] * stats["live"]
+        assert floor <= stats["kv_positions_live"] <= floor * SEQ
+        assert stats["kv_positions_read"] == floor * SEQ
     fanouts = tr.named("decode/fanout")
     # first tokens leave at the admission; the rest through the fan-out
     assert sum(e[4]["tokens"] for e in fanouts) == 9 + 4 + 12 + 7 + 3 - 5
@@ -240,6 +247,59 @@ def test_loop_counters_are_monotone_and_sum_to_the_loops_wall():
     assert s1["queue_wait_s"] > s0["queue_wait_s"]
 
 
+def test_kv_position_counters_add_up_on_a_scripted_run(lm):
+    """One request of ``L`` prompt tokens stepped one at a time: step
+    ``i`` finds ``L + 1 + i`` live positions (the prompt, the tokens so
+    far, its own new row) and, off the chip, reads the whole slab."""
+    eng = DecodeEngine(lm.trainer.state.params, lm.hyper, capacity=2,
+                       max_len=SEQ, prompt_buckets=(BUCKET,), step_fuse=1)
+    L, new = 7, 12
+    try:
+        s0 = eng.stats()
+        assert s0["kv_positions_live"] == s0["kv_positions_read"] == 0
+        eng.generate([np.arange(L)], new, timeout=120)
+        s1 = eng.stats()
+    finally:
+        eng.close()
+    # the loop is one step ahead of what it has fetched: it may have
+    # dispatched one step past the request's last
+    assert new - 1 <= s1["steps"] <= new
+    assert s1["kv_positions_live"] == sum(L + 1 + i
+                                          for i in range(s1["steps"]))
+    assert s1["kv_positions_read"] == s1["steps"] * SEQ
+
+
+def test_kv_positions_read_rounds_up_to_the_kernels_block(lm, monkeypatch):
+    """Where the kernel runs, a step reads a slot's slab up to the block
+    that holds its newest row, and the window's steps one after
+    another."""
+    import importlib
+    A = importlib.import_module("analytics_zoo_tpu.ops.attention")
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    wide = TransformerLM(vocab_size=VOCAB, seq_len=256, n_layers=1,
+                         d_model=128, n_heads=2)
+    wide.ensure_inference_ready()
+    eng = DecodeEngine(wide.trainer.state.params, wide.hyper, capacity=3,
+                       max_len=256, prompt_buckets=(BUCKET,))
+    try:
+        assert eng._kv_block == 128
+
+        class Req:
+            def __init__(self, length, scheduled):
+                self.length, self.scheduled = length, scheduled
+
+        eng._slots = [Req(100, 27), None, Req(10, 1)]
+        # lengths 127, 128, 129 and 11, 12, 13
+        assert eng._kv_positions(3) == (127 + 128 + 129 + 11 + 12 + 13,
+                                        128 + 128 + 256 + 3 * 128)
+        eng._slots = [None, Req(200, 55), None]     # clamped to the slab
+        assert eng._kv_positions(2) == (255 + 256, 256 + 256)
+        eng._slots = [None] * 3
+        assert eng._kv_positions(4) == (0, 0)
+    finally:
+        eng.close()
+
+
 def test_loop_counters_reach_prometheus(lm):
     from analytics_zoo_tpu.serving import ModelRegistry
 
@@ -257,6 +317,11 @@ def test_loop_counters_reach_prometheus(lm):
     assert {labels["phase"] for labels, _ in loop.samples} \
         == set(LOOP_PHASES)
     assert sum(v for _, v in loop.samples) > 0
+    kv = fams["zoo_decode_kv_positions_total"]
+    assert kv.mtype == "counter"
+    by_kind = {labels["kind"]: v for labels, v in kv.samples}
+    assert set(by_kind) == {"live", "read"}
+    assert 0 < by_kind["live"] <= by_kind["read"]
 
 
 # ------------------------------------------------- names inside programs
@@ -346,5 +411,41 @@ def test_flash_kernels_carry_their_names():
     assert profile.KERNEL_FLASH_FWD in fwd
     assert profile.KERNEL_FLASH_BWD_DQ not in fwd
     bwd = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
-    for kernel in profile.KERNELS:
+    for kernel in (profile.KERNEL_FLASH_FWD, profile.KERNEL_FLASH_BWD_DQ,
+                   profile.KERNEL_FLASH_BWD_DKV):
         assert kernel in bwd, kernel
+
+
+def test_decode_step_carries_its_kernels_name(monkeypatch):
+    """On the chip the step's attention is the kernel ``zoo_decode_attn``
+    (in ``profile.KERNELS``, which a trace's reader goes by), under the
+    scope ``zoo_decode_attention``; off the chip there is no kernel."""
+    import importlib
+    A = importlib.import_module("analytics_zoo_tpu.ops.attention")
+    assert profile.KERNEL_DECODE_ATTN == "zoo_decode_attn"
+    assert profile.KERNEL_DECODE_ATTN in profile.KERNELS
+    wide = TransformerLM(vocab_size=VOCAB, seq_len=128, n_layers=1,
+                         d_model=128, n_heads=2)
+    wide.ensure_inference_ready()
+
+    def kernel_calls():
+        """Scope paths of the step's ``pallas_call``s of that name."""
+        eng = DecodeEngine(wide.trainer.state.params, wide.hyper,
+                           capacity=2, max_len=128,
+                           prompt_buckets=(BUCKET,))
+        try:
+            caches, tok, pos, samp = eng._state_specs()
+            closed = jax.make_jaxpr(eng._step_core)(
+                caches, tok, pos, samp, eng._weights)
+        finally:
+            eng.close()
+        # the kernel sits in a jit of its own (``_decode_attn_call``):
+        # the scope is on the call, the kernel's name inside it
+        return [str(eqn.source_info.name_stack)
+                for eqn in closed.jaxpr.eqns
+                if f"name={profile.KERNEL_DECODE_ATTN}" in str(eqn)]
+
+    assert kernel_calls() == []
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    [scope] = kernel_calls()        # one layer, one call
+    assert profile.SCOPE_DECODE_ATTENTION in scope

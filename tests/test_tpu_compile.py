@@ -5,8 +5,9 @@ DESCRIBED, not attached.  Interpret-mode tests cannot see what it
 refuses — a block that breaks the tiling rule, a kernel past the scoped
 VMEM limit, a Mosaic call GSPMD will not partition — and each of those
 stopped the program on first contact with a v5e.  These cases hold the
-flash kernels (forward, dq, dkv) to the compiler at the shapes the main
-path sends them, so a later change is refused here at no chip time.
+flash kernels (forward, dq, dkv) and the decode-attention kernel to the
+compiler at the shapes the main paths send them, so a later change is
+refused here at no chip time.
 
 Nothing runs (there is no device), so nothing here says anything about
 results or speed.
@@ -109,7 +110,8 @@ def test_compiled_step_names_the_three_kernels(one_chip):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x) \
         .compile().as_text()
     assert text.count("tpu_custom_call") == 3
-    for kernel in profile.KERNELS:
+    for kernel in (profile.KERNEL_FLASH_FWD, profile.KERNEL_FLASH_BWD_DQ,
+                   profile.KERNEL_FLASH_BWD_DKV):
         assert text.count(kernel) >= 1, kernel
 
 
@@ -177,3 +179,120 @@ def test_flash_under_a_four_chip_mesh_compiles(topo, monkeypatch):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
             .lower(q, q, q).compile().as_text()
     assert text.count("tpu_custom_call") == 3
+
+
+# ------------------------------------------------- the decode step's op
+HEADS, D_HEAD, MAX_LEN = 16, 64, 1024     # GPT-2 medium's, as published
+SLAB_BYTES = MAX_LEN * HEADS * D_HEAD * 4     # one slot of one slab
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The described chip is a TPU; ``jax.devices()`` here says cpu."""
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+
+
+def _op_args(slots, sharding_of):
+    """ShapeDtypeStructs of ``decode_attention``'s operands;
+    ``sharding_of(rank)`` places each."""
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=sharding_of(len(shape)))
+    row, slab = (slots, HEADS * D_HEAD), A.kv_slab_shape(
+        slots, MAX_LEN, HEADS, D_HEAD)
+    return (spec(row), spec(row), spec(row), spec(slab), spec(slab),
+            spec((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("slots", [16, 32])
+def test_decode_attention_compiles_in_place(one_chip, on_the_chip, slots):
+    """The op at the benchmark's widths: one Mosaic call that carries
+    its name, the slabs aliased through it, and no temporary at all the
+    size of a slot's slab (a copy of an operand would be ``slots`` of
+    them)."""
+    from analytics_zoo_tpu.observability import profile
+
+    def op(q, k, v, ck, cv, pos):
+        return A.decode_attention(q, k, v, ck, cv, pos, HEADS)
+
+    compiled = jax.jit(op, donate_argnums=(3, 4)).lower(
+        *_op_args(slots, lambda rank: one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert profile.KERNEL_DECODE_ATTN in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < SLAB_BYTES
+    assert mem.alias_size_in_bytes == 2 * slots * SLAB_BYTES
+
+
+def test_decode_attention_compiles_under_a_four_chip_mesh(topo,
+                                                          on_the_chip):
+    """A mesh-sharded engine splits the slots over its devices: handed
+    to GSPMD bare the kernel is refused ('Mosaic kernels cannot be
+    automatically partitioned'), so it sits in a shard_map."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "tensor"))
+    axes = ("data", "tensor")
+
+    def sharded(rank):
+        return NamedSharding(mesh, P(axes, *([None] * (rank - 1))))
+
+    def op(q, k, v, ck, cv, pos):
+        return A.decode_attention(q, k, v, ck, cv, pos, HEADS, mesh=mesh)
+
+    compiled = jax.jit(op, donate_argnums=(3, 4)).lower(
+        *_op_args(16, sharded)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # each device holds, and aliases, its own four slots
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * 4 * SLAB_BYTES
+
+
+@pytest.fixture(scope="module")
+def two_layers():
+    """Two layers at GPT-2 medium's widths (the vocabulary is not: it
+    has no part in the slabs)."""
+    from analytics_zoo_tpu.models import TransformerLM
+    lm = TransformerLM(vocab_size=512, seq_len=MAX_LEN, n_layers=2,
+                       d_model=HEADS * D_HEAD, n_heads=HEADS)
+    lm.ensure_inference_ready()
+    return lm
+
+
+@pytest.mark.parametrize("slots", [16, 32])
+def test_fused_step_plan_compiles_without_a_slab_sized_temporary(
+        topo, one_chip, on_the_chip, two_layers, slots):
+    """``jit_stepk`` as the engine builds it, for the described chip, at
+    16 x 1024 (the benchmark's cell) and at 32 x 1024, which the chip's
+    compiler refused before the slab was lane-dense ('Used 20.21G of
+    15.75G hbm': a padded copy of every layer's keys and values).  The
+    engine is made without its state: a described device holds no
+    array."""
+    from analytics_zoo_tpu.observability import profile
+    from analytics_zoo_tpu.pipeline.inference.decode import DecodeEngine
+    eng = object.__new__(DecodeEngine)
+    eng.capacity, eng.max_len = slots, MAX_LEN
+    eng._hyper, eng._n_layers = dict(two_layers.hyper), 2
+    eng._draft_hyper = eng._mesh = None
+    eng._device = topo.devices[0]
+    weights = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (two_layers.trainer.state.params, None))
+    plan = {}
+    eng._plan = lambda name, jitted, specs: plan.update(
+        name=name, lowered=jitted.lower(*specs, weights))
+    eng._build_stepk_plan(4)
+    assert plan["name"] == "step4"
+    compiled = plan["lowered"].compile()
+    text = compiled.as_text()
+    assert f"HloModule {profile.PROGRAM_STEPK}" in text
+    assert profile.KERNEL_DECODE_ATTN in text
+    assert text.count("tpu_custom_call") == 2       # one a layer
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < slots * SLAB_BYTES   # one layer's slab
+    assert mem.alias_size_in_bytes >= 4 * slots * SLAB_BYTES
+    # no operation but the kernel takes or makes a whole slab
+    slab = f"f32[{slots},{MAX_LEN},{HEADS * D_HEAD}]"
+    for line in text.splitlines():
+        if slab in line.split(" = ", 1)[-1].split("(", 1)[0] \
+                and " fusion(" in line:
+            pytest.fail("a fusion makes a slab: " + line[:200])
