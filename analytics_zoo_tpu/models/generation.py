@@ -278,8 +278,11 @@ def _sample(logits, rng, temperature, top_k: Optional[int] = None,
     indices), both truncation thresholds off the same sorted array,
     and an inverse-CDF draw from ONE uniform per row — deliberately
     not V gumbels + two sorts: this runs per decode step (and per
-    speculative window position), where the cheap transform keeps
-    sampled decode within the bench's overhead bound of greedy."""
+    speculative window position).  With traced knobs a greedy row
+    computes all of it and keeps none, so the engine calls this only
+    from the branch a dispatch takes when one of its live slots samples
+    (``decode._pick_tokens``); an all-greedy dispatch never gets
+    here."""
     greedy = jnp.argmax(logits, axis=-1)
     static_t = isinstance(temperature, (int, float))
     if static_t and float(temperature) == 0.0:

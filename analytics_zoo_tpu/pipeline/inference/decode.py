@@ -235,15 +235,39 @@ def _pick(logits, seed, index, temperature, top_k, top_p):
                    top_p).astype(jnp.int32)
 
 
+def _pick_tokens(logits, seed, index, temperature, top_k, top_p,
+                 pick_sorted):
+    """The next token of every row of ``logits`` ((rows, V) with
+    per-row knobs, or one row (V,) with scalars), branching ON THE
+    DEVICE on the scalar ``pick_sorted``: false, every row is greedy
+    and the tokens are :func:`_sample`'s static-greedy plan, a bare
+    ``argmax(logits)`` and nothing else (no sort, no ``exp``, no
+    cumulative sum, no key, no uniform); true, each row goes through
+    :func:`_pick`, whose in-graph select keeps a ``temperature == 0``
+    row's token the same bare argmax.  So a row's token does not depend
+    on the branch as long as ``pick_sorted`` is true whenever a row that
+    matters samples.
+
+    The ``lax.cond`` stands OUTSIDE the ``vmap`` over rows: inside it a
+    ``cond`` on a per-row predicate lowers to a select and both branches
+    run."""
+    pick = _pick if logits.ndim == 1 else jax.vmap(_pick)
+    return lax.cond(
+        pick_sorted,
+        lambda: pick(logits, seed, index, temperature, top_k, top_p),
+        lambda: _sample(logits, None, 0.0).astype(jnp.int32))
+
+
 # The pick of the next token is a jitted function of its own INSIDE the
 # plans, named ``zoo_sample``: a device profile then shows its
-# operations under ``jit(zoo_sample)``.  A ``jax.named_scope`` would not
-# do here: scopes are metadata, jax leaves metadata out of the
-# persistent compilation cache's key, and a plan whose instructions did
-# not change is answered from the cache with the metadata (or none) of
-# whoever compiled it first.  The function's symbol is part of the
-# program, so it cannot go stale; XLA inlines the call.
-_pick = jax.jit(_profile.named(_profile.SCOPE_SAMPLE, _pick))
+# operations (either branch's) under ``jit(zoo_sample)``.  A
+# ``jax.named_scope`` would not do here: scopes are metadata, jax leaves
+# metadata out of the persistent compilation cache's key, and a plan
+# whose instructions did not change is answered from the cache with the
+# metadata (or none) of whoever compiled it first.  The function's
+# symbol is part of the program, so it cannot go stale; XLA inlines the
+# call.
+_pick_tokens = jax.jit(_profile.named(_profile.SCOPE_SAMPLE, _pick_tokens))
 
 
 #: what the dispatcher thread can be doing: host work (``admit``,
@@ -551,6 +575,10 @@ class DecodeEngine:
         self._tok = jax.device_put(tok, self._slot_sharding(1))
         self._pos = jax.device_put(pos, self._slot_sharding(1))
         self._samp = jax.device_put(samp, self._slot_sharding(1))
+        # the two values of the step plans' ``pick_sorted`` input
+        # (_select), put once: a dispatch passes one of them
+        self._pick_flags = tuple(jax.device_put(np.bool_(b), self._rep)
+                                 for b in (False, True))
 
         # one AOT-compiled single-step plan plus a halving ladder of
         # fused window plans (step_fuse, step_fuse/2, ... 2) per
@@ -601,7 +629,8 @@ class DecodeEngine:
         # counters (dispatcher-owned ints; reads copy — GIL-atomic
         # enough for a metrics scrape, same convention as the
         # coalescer's hedge counters)
-        self._counters = {"tokens": 0, "steps": 0, "prefills": 0,
+        self._counters = {"tokens": 0, "steps": 0, "steps_sorted": 0,
+                          "prefills": 0,
                           "admitted": 0, "evicted": 0,
                           "fused_dispatches": 0, "sampled_tokens": 0,
                           "prefix_hits": 0, "prefix_misses": 0,
@@ -677,7 +706,7 @@ class DecodeEngine:
                 self._thread.start()
 
     # ---- compiled plans -------------------------------------------------
-    def _select(self, logits, samp, offset: int = 0):
+    def _select(self, logits, samp, pick_sorted, offset: int = 0):
         """Per-slot token selection over (capacity, V) logits: each
         slot draws with ``fold_in(PRNGKey(seed), step + offset)`` —
         the absolute-token-index RNG that makes streams independent,
@@ -686,22 +715,23 @@ class DecodeEngine:
         ``temperature == 0`` slots select the bare argmax
         (bit-identical to the v1 greedy step).
 
-        Deliberate trade-off: greedy slots ride the same in-graph
-        select, so a pure-greedy dispatch still computes the sampled
-        branch it discards — that is what keeps sampling a STATE
-        write (one step plan at every sampling mix, never a
-        recompile), and the sampled path was engineered cheap (one
-        top_k + one uniform, see ``_sample``) precisely so this dead
-        work stays inside the bench's sampled-vs-greedy overhead
-        bound.  A ``lax.cond`` fast path would shave the greedy step
-        further at the cost of divergent step timing between modes —
-        revisit if a production vocab makes the sort visible next to
-        the transformer step."""
+        A dispatch whose live slots are all greedy does no more than
+        that argmax: ``pick_sorted`` (a device scalar the dispatcher
+        hands every step plan, :meth:`_any_sampled`) selects, in an XLA
+        ``conditional``, between the argmax alone and the per-slot
+        :func:`_sample` (sort of the vocabulary, two cumulative sums,
+        one uniform), which runs only when a live slot samples.
+        Sampling stays a STATE write plus this one runtime scalar: one
+        step plan at every sampling mix, never a recompile.  The
+        predicate is the dispatcher's, not ``any(temp > 0)`` on the
+        device, because eviction is host-side only: a slot freed by a
+        sampled request keeps its temperature until the next admission
+        overwrites it, and must not hold the sorted branch on."""
         seed, stepc, temp, topk, topp = samp
-        return jax.vmap(_pick)(logits, seed, stepc + offset, temp, topk,
-                               topp)
+        return _pick_tokens(logits, seed, stepc + offset, temp, topk,
+                            topp, pick_sorted)
 
-    def _step_core(self, caches, tok, pos, samp, weights):
+    def _step_core(self, caches, tok, pos, samp, pick_sorted, weights):
         """ONE slot-array decode step over ALL ``capacity`` slots —
         the body the step, fused, and speculative plans all trace, so
         every plan's per-token numerics are identical by construction.
@@ -715,13 +745,14 @@ class DecodeEngine:
         emb = _embed_token(params, tok, posc)
         logits, caches = _decode_step(params, hyper, caches, emb, posc,
                                       mesh=self._mesh)
-        nxt = self._select(logits, samp)
+        nxt = self._select(logits, samp, pick_sorted)
         seed, stepc, temp, topk, topp = samp
         return (caches, nxt, jnp.minimum(pos + 1, max_len),
                 (seed, stepc + 1, temp, topk, topp))
 
-    def _step_body(self, caches, tok, pos, samp, weights):
-        return self._step_core(caches, tok, pos, samp, weights)
+    def _step_body(self, caches, tok, pos, samp, pick_sorted, weights):
+        return self._step_core(caches, tok, pos, samp, pick_sorted,
+                               weights)
 
     def _samp_specs(self):
         s0 = self._slot_sharding(1)
@@ -760,6 +791,12 @@ class DecodeEngine:
                                      sharding=self._slot_sharding(1))
         caches = [pair for _ in range(self._n_layers)]
         return caches, ispec, ispec, self._samp_specs()
+
+    def _step_specs(self):
+        """The step plans' inputs: the state, then ``pick_sorted``
+        (:meth:`_select`)."""
+        return self._state_specs() + (jax.ShapeDtypeStruct(
+            (), jnp.bool_, sharding=self._rep_sharding()),)
 
     def _plan(self, name: str, jitted, arg_specs):
         """AOT-build one decode plan: lower, consult the persistent
@@ -825,8 +862,8 @@ class DecodeEngine:
         return bound(compiled)
 
     def _build_step_plan(self):
-        """The persistent single-step plan: (caches, tok, pos, samp)
-        -> (caches', tok', pos', samp')."""
+        """The persistent single-step plan: (caches, tok, pos, samp,
+        pick_sorted) -> (caches', tok', pos', samp')."""
         # the caches are DONATED: without donation every step copies
         # the whole (capacity, max_len, heads * d_head) slab per
         # layer just to update one position — the in-place update the
@@ -840,13 +877,14 @@ class DecodeEngine:
         # free).
         # jitted under a name of its own: from the bound method the
         # module would be ``jit__step_body``
-        def step(caches, tok, pos, samp, weights):
-            return self._step_body(caches, tok, pos, samp, weights)
+        def step(caches, tok, pos, samp, pick_sorted, weights):
+            return self._step_body(caches, tok, pos, samp, pick_sorted,
+                                   weights)
 
         return self._plan(
             "step1", jax.jit(_profile.named(_profile.PROGRAM_STEP, step),
                              donate_argnums=(0,)),
-            self._state_specs())
+            self._step_specs())
 
     def _build_stepk_plan(self, k: int):
         """One fused window plan: ``k`` consecutive decode steps as
@@ -860,10 +898,11 @@ class DecodeEngine:
         ``_choose_fuse``), so batching stays iteration-level exactly
         when iteration-level matters."""
 
-        def stepk(caches, tok, pos, samp, weights):
+        def stepk(caches, tok, pos, samp, pick_sorted, weights):
             def body(carry, _):
                 c, t, p, sm = carry
-                c, t, p, sm = self._step_body(c, t, p, sm, weights)
+                c, t, p, sm = self._step_body(c, t, p, sm, pick_sorted,
+                                              weights)
                 return (c, t, p, sm), t
 
             (caches, tok, pos, samp), toks = lax.scan(
@@ -874,14 +913,14 @@ class DecodeEngine:
             f"step{k}", jax.jit(_profile.named(_profile.PROGRAM_STEPK,
                                                stepk),
                                 donate_argnums=(0,)),
-            self._state_specs())
+            self._step_specs())
 
     def _build_spec_plan(self):
         """The speculative window plan — draft proposal scan, ONE
         exact target step, windowed verify, and in-graph acceptance,
         all one dispatch:
 
-            (caches, dcaches, tok, pos, samp) ->
+            (caches, dcaches, tok, pos, samp, pick_sorted) ->
             (caches', dcaches', tok', pos', samp',
              T (spec_tokens, capacity), accepted (capacity,))
 
@@ -903,7 +942,7 @@ class DecodeEngine:
         hyper, max_len = self._hyper, self.max_len
         dhyper = self._draft_hyper
 
-        def spec(caches, dcaches, tok, pos, samp, weights):
+        def spec(caches, dcaches, tok, pos, samp, pick_sorted, weights):
             params, dparams = weights
 
             def dbody(carry, _):
@@ -921,7 +960,7 @@ class DecodeEngine:
             # the exact fallback token — bit-identical to the
             # non-speculative step plan by shared trace
             caches, t0, _, _ = self._step_core(caches, tok, pos, samp,
-                                               weights)
+                                               pick_sorted, weights)
             # windowed verify of the proposals at pos+1 .. pos+k-1
             embs = [_embed_token(params, dprops[j],
                                  jnp.minimum(pos + 1 + j, max_len - 1))
@@ -929,7 +968,8 @@ class DecodeEngine:
             wlogits, caches = _decode_window(
                 params, hyper, caches, jnp.stack(embs, axis=1),
                 pos + 1)
-            wtoks = [self._select(wlogits[:, j], samp, offset=1 + j)
+            wtoks = [self._select(wlogits[:, j], samp, pick_sorted,
+                                  offset=1 + j)
                      for j in range(k - 1)]
             T = jnp.concatenate([t0[None], jnp.stack(wtoks, axis=0)],
                                 axis=0)  # (k, capacity)
@@ -942,12 +982,12 @@ class DecodeEngine:
             samp = (seed, stepc + acc, temp, topk, topp)
             return caches, dcaches, newtok, newpos, samp, T, acc
 
-        caches, ispec, _, samp = self._state_specs()
+        caches, ispec, _, samp, flag = self._step_specs()
         return self._plan(
             f"spec{k}", jax.jit(_profile.named(_profile.PROGRAM_SPEC,
                                                spec),
                                 donate_argnums=(0, 1)),
-            (caches, self._draft_specs(), ispec, ispec, samp))
+            (caches, self._draft_specs(), ispec, ispec, samp, flag))
 
     def _ensure_step_plans(self):
         """Build (or store-load) the decode-loop plans — the
@@ -987,9 +1027,10 @@ class DecodeEngine:
     def _sample_first(self, logits0, seed0, temp0, topk0, topp0):
         """First-token selection at absolute index 0 (the same
         :func:`_sample` + fold_in discipline every later index
-        uses)."""
-        return _pick(logits0, seed0, jnp.zeros((), jnp.int32), temp0,
-                     topk0, topp0)
+        uses, behind the same branch: a greedy request's admission
+        sorts nothing)."""
+        return _pick_tokens(logits0, seed0, jnp.zeros((), jnp.int32),
+                            temp0, topk0, topp0, temp0 > 0.0)
 
     def _build_admit_fn(self, s_b: int):
         """One prompt bucket's monolithic admission plan: batched
@@ -1223,21 +1264,23 @@ class DecodeEngine:
                             zero, fzero, zero, fone)
                         jax.device_get(tok0)
             self._ensure_step_plans()
+            greedy = self._pick_flags[False]
             if self._draft_hyper is not None:
                 (self._caches, self._dcaches, self._tok, self._pos,
                  self._samp, toks, acc) = self._spec_fn(
                     self._caches, self._dcaches, self._tok, self._pos,
-                    self._samp)
+                    self._samp, greedy)
                 jax.device_get(acc)
             else:
                 (self._caches, self._tok, self._pos,
                  self._samp) = self._step_fn(
-                    self._caches, self._tok, self._pos, self._samp)
+                    self._caches, self._tok, self._pos, self._samp,
+                    greedy)
                 jax.device_get(self._tok)
                 for fn in self._stepk_fns.values():
                     (self._caches, self._tok, self._pos, self._samp,
                      toks) = fn(self._caches, self._tok, self._pos,
-                                self._samp)
+                                self._samp, greedy)
                     jax.device_get(toks)
         finally:
             with self._start_cond:
@@ -1688,6 +1731,18 @@ class DecodeEngine:
                     read += -(-n // block) * block
         return live, read
 
+    def _any_sampled(self) -> bool:
+        """Whether a slot the dispatcher holds live samples
+        (``temperature > 0``): the ``pick_sorted`` the next dispatch
+        hands its plan (:meth:`_select`).  From the requests in the
+        slots, not from the device's per-slot temperatures, which keep a
+        finished request's value until the slot is admitted into again.
+        The dispatcher learns of an eviction one window late, so this is
+        true for every step a sampled request takes, and a window
+        longer."""
+        return any(req is not None and req.temperature > 0.0
+                   for req in self._slots)
+
     def _dispatch_step(self):
         """Dispatch the next decode window WITHOUT fetching (jax
         dispatch is asynchronous) and snapshot the slot->request map as
@@ -1704,20 +1759,25 @@ class DecodeEngine:
             return self._dispatch_spec()
         k = self._choose_fuse()
         kv_live, kv_read = self._kv_positions(k)
+        pick_sorted = self._any_sampled()
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
-                         kv_positions_read=kv_read):
+                         kv_positions_read=kv_read,
+                         pick_sorted=int(pick_sorted)):
+            flag = self._pick_flags[pick_sorted]
             if k > 1:
                 (self._caches, self._tok, self._pos, self._samp,
                  toks) = self._stepk_fns[k](self._caches, self._tok,
-                                            self._pos, self._samp)
+                                            self._pos, self._samp, flag)
                 self._counters["fused_dispatches"] += 1
             else:
                 (self._caches, self._tok, self._pos,
                  self._samp) = self._step_fn(self._caches, self._tok,
-                                             self._pos, self._samp)
+                                             self._pos, self._samp, flag)
                 toks = self._tok
             self._counters["steps"] += k
+            if pick_sorted:
+                self._counters["steps_sorted"] += k
             self._counters["kv_positions_live"] += kv_live
             self._counters["kv_positions_read"] += kv_read
             for req in self._slots:
@@ -1735,14 +1795,18 @@ class DecodeEngine:
         # of the target's slabs, the window's one exact step goes
         # through the decode-attention op; the verify reads them whole
         kv_live, kv_read = self._kv_positions(1)
+        pick_sorted = self._any_sampled()
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
-                         kv_positions_read=kv_read):
+                         kv_positions_read=kv_read,
+                         pick_sorted=int(pick_sorted)):
             (self._caches, self._dcaches, self._tok, self._pos,
              self._samp, toks, acc) = self._spec_fn(
                 self._caches, self._dcaches, self._tok, self._pos,
-                self._samp)
+                self._samp, self._pick_flags[pick_sorted])
             self._counters["steps"] += k
+            if pick_sorted:
+                self._counters["steps_sorted"] += k
             self._counters["kv_positions_live"] += kv_live
             self._counters["kv_positions_read"] += kv_read
             self._counters["spec_windows"] += 1

@@ -1,8 +1,9 @@
 """The names a device profile goes by (ISSUE 26): the program's host
 spans on the profiler's clock (``profile.annotate``), the decode
 dispatcher's always-on loop counters, the ``jax.named_scope`` regions,
-the pinned program names and the kernels' names; and (ISSUE 27) the
-dispatcher's count of the slab positions its steps had live and read.
+the pinned program names and the kernels' names; (ISSUE 27) the
+dispatcher's count of the slab positions its steps had live and read;
+and (ISSUE 32) which branch of the pick a dispatch's steps take.
 
 All on the CPU: a profiler session here records host events only, which
 is what the spans are.  Read back with ``jax.profiler.ProfileData``, the
@@ -143,6 +144,7 @@ def test_decode_spans_land_in_the_profile_with_their_stats(lm, tmp_path):
         floor = stats["k"] * stats["live"]
         assert floor <= stats["kv_positions_live"] <= floor * SEQ
         assert stats["kv_positions_read"] == floor * SEQ
+        assert stats["pick_sorted"] == 0        # nobody samples
     fanouts = tr.named("decode/fanout")
     # first tokens leave at the admission; the rest through the fan-out
     assert sum(e[4]["tokens"] for e in fanouts) == 9 + 4 + 12 + 7 + 3 - 5
@@ -269,6 +271,62 @@ def test_kv_position_counters_add_up_on_a_scripted_run(lm):
     assert s1["kv_positions_read"] == s1["steps"] * SEQ
 
 
+@pytest.mark.parametrize("spec", [False, True])
+def test_a_finished_sampled_request_does_not_hold_the_sort_on(
+        lm, tmp_path, spec):
+    """A sampled request finishes beside two greedy ones and nobody is
+    admitted into its slot, which keeps the request's temperature on the
+    device.  The dispatches' ``pick_sorted`` is 1 while the dispatcher
+    holds the request, 0 for the rest of the run; it is the flag the
+    plan was called with, so the branch the device takes; and
+    ``steps_sorted`` is the ``k`` of the sorted dispatches."""
+    more = {}
+    if spec:
+        params = lm.trainer.state.params
+        more = dict(draft_params={k: params[k] for k in (
+            "tok_embed", "pos_embed", "ln_final", "lm_head")},
+            draft_hyper=dict(lm.hyper, n_layers=0, moe_every=0),
+            spec_tokens=2)
+    eng = new_engine(lm, **more)
+    flags = []
+
+    def recording(fn):
+        def call(*args):
+            flags.append(bool(args[-1]))
+            return fn(*args)
+        return call
+
+    if spec:
+        eng._spec_fn = recording(eng._spec_fn)
+    else:
+        eng._step_fn = recording(eng._step_fn)
+        eng._stepk_fns = {k: recording(f)
+                          for k, f in eng._stepk_fns.items()}
+    p = prompts(3, seed=2)
+    try:
+        with Traced(tmp_path) as tr:
+            greedy = [eng.submit(q, 30) for q in p[:2]]
+            sampled = eng.submit(p[2], 3, temperature=0.9, top_k=8,
+                                 seed=4)
+            sampled.result(timeout=120)
+            [s.result(timeout=120) for s in greedy]
+        stats = eng.stats()
+        stale = np.asarray(eng._samp[2])
+    finally:
+        eng.close()
+    dispatches = sorted(tr.named("decode/dispatch"), key=lambda e: e[2])
+    took = [e[4]["pick_sorted"] for e in dispatches]
+    assert took == [int(f) for f in flags]
+    last = max(i for i, t in enumerate(took) if t)
+    assert 1 in took[:last + 1] and set(took[last + 1:]) == {0}
+    assert len(took) - last > 5             # and stayed off
+    assert (stale > 0).sum() == 1           # the freed slot's, still there
+    assert stats["steps_sorted"] == sum(
+        e[4]["k"] for e in dispatches if e[4]["pick_sorted"])
+    assert 0 < stats["steps_sorted"] < stats["steps"] == sum(
+        e[4]["k"] for e in dispatches)
+
+
 def test_kv_positions_read_rounds_up_to_the_kernels_block(lm, monkeypatch):
     """Where the kernel runs, a step reads a slot's slab up to the block
     that holds its newest row, and the window's steps one after
@@ -309,6 +367,8 @@ def test_loop_counters_reach_prometheus(lm):
                    decode_prompt_buckets=(BUCKET,))
         reg.generate("lm", prompts(1), 4)
         fams = {f.name: f for f in registry_families(reg.metrics())}
+        reg.generate("lm", prompts(1), 4, temperature=0.7, seed=1)
+        after = {f.name: f for f in registry_families(reg.metrics())}
     finally:
         reg.shutdown()
     wait = fams["zoo_decode_queue_wait_seconds_total"]
@@ -322,6 +382,11 @@ def test_loop_counters_reach_prometheus(lm):
     by_kind = {labels["kind"]: v for labels, v in kv.samples}
     assert set(by_kind) == {"live", "read"}
     assert 0 < by_kind["live"] <= by_kind["read"]
+    steps = fams["zoo_decode_steps_total"].samples[0][1]
+    picked = fams["zoo_decode_steps_sorted_total"]
+    assert picked.mtype == "counter" and picked.samples[0][1] == 0 < steps
+    assert 0 < after["zoo_decode_steps_sorted_total"].samples[0][1] \
+        <= after["zoo_decode_steps_total"].samples[0][1] - steps
 
 
 # ------------------------------------------------- names inside programs
@@ -396,6 +461,10 @@ def test_decode_programs_hold_their_scopes_and_their_names(lm):
             assert scope in texts[name], (name, scope)
     for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_PADMIT):
         assert profile.SCOPE_SAMPLE in texts[name], name
+    # the pick branches on the device in every plan that picks a token
+    for name in decode - {profile.PROGRAM_FILL}:
+        assert "stablehlo.case" in texts[name], name
+    assert "stablehlo.case" not in texts[profile.PROGRAM_FILL]
     for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_FILL):
         assert profile.SCOPE_PREFILL in texts[name], name
 
@@ -434,9 +503,8 @@ def test_decode_step_carries_its_kernels_name(monkeypatch):
                            capacity=2, max_len=128,
                            prompt_buckets=(BUCKET,))
         try:
-            caches, tok, pos, samp = eng._state_specs()
             closed = jax.make_jaxpr(eng._step_core)(
-                caches, tok, pos, samp, eng._weights)
+                *eng._step_specs(), eng._weights)
         finally:
             eng.close()
         # the kernel sits in a jit of its own (``_decode_attn_call``):

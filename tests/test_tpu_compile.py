@@ -258,31 +258,45 @@ def two_layers():
     return lm
 
 
+_STEPK = {}
+
+
+@pytest.fixture
+def compiled_stepk(topo, one_chip, on_the_chip, two_layers):
+    """``jit_stepk`` (4 steps) as the engine builds it, compiled for the
+    described chip at ``slots`` x 1024, once a module.  The engine is
+    made without its state: a described device holds no array."""
+    def compiled(slots):
+        if slots not in _STEPK:
+            from analytics_zoo_tpu.pipeline.inference.decode import \
+                DecodeEngine
+            eng = object.__new__(DecodeEngine)
+            eng.capacity, eng.max_len = slots, MAX_LEN
+            eng._hyper, eng._n_layers = dict(two_layers.hyper), 2
+            eng._draft_hyper = eng._mesh = None
+            eng._device = topo.devices[0]
+            weights = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                (two_layers.trainer.state.params, None))
+            plan = {}
+            eng._plan = lambda name, jitted, specs: plan.update(
+                name=name, lowered=jitted.lower(*specs, weights))
+            eng._build_stepk_plan(4)
+            assert plan["name"] == "step4"
+            _STEPK[slots] = plan["lowered"].compile()
+        return _STEPK[slots]
+    return compiled
+
+
 @pytest.mark.parametrize("slots", [16, 32])
 def test_fused_step_plan_compiles_without_a_slab_sized_temporary(
-        topo, one_chip, on_the_chip, two_layers, slots):
-    """``jit_stepk`` as the engine builds it, for the described chip, at
-    16 x 1024 (the benchmark's cell) and at 32 x 1024, which the chip's
-    compiler refused before the slab was lane-dense ('Used 20.21G of
-    15.75G hbm': a padded copy of every layer's keys and values).  The
-    engine is made without its state: a described device holds no
-    array."""
+        compiled_stepk, slots):
+    """At 16 x 1024 (the benchmark's cell) and at 32 x 1024, which the
+    chip's compiler refused before the slab was lane-dense ('Used 20.21G
+    of 15.75G hbm': a padded copy of every layer's keys and values)."""
     from analytics_zoo_tpu.observability import profile
-    from analytics_zoo_tpu.pipeline.inference.decode import DecodeEngine
-    eng = object.__new__(DecodeEngine)
-    eng.capacity, eng.max_len = slots, MAX_LEN
-    eng._hyper, eng._n_layers = dict(two_layers.hyper), 2
-    eng._draft_hyper = eng._mesh = None
-    eng._device = topo.devices[0]
-    weights = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (two_layers.trainer.state.params, None))
-    plan = {}
-    eng._plan = lambda name, jitted, specs: plan.update(
-        name=name, lowered=jitted.lower(*specs, weights))
-    eng._build_stepk_plan(4)
-    assert plan["name"] == "step4"
-    compiled = plan["lowered"].compile()
+    compiled = compiled_stepk(slots)
     text = compiled.as_text()
     assert f"HloModule {profile.PROGRAM_STEPK}" in text
     assert profile.KERNEL_DECODE_ATTN in text
@@ -296,3 +310,44 @@ def test_fused_step_plan_compiles_without_a_slab_sized_temporary(
         if slab in line.split(" = ", 1)[-1].split("(", 1)[0] \
                 and " fusion(" in line:
             pytest.fail("a fusion makes a slab: " + line[:200])
+
+
+def _computations(hlo_text):
+    """{computation name: its instruction lines} of a compiled module's
+    text."""
+    import re
+    out, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def test_fused_step_plan_sorts_in_one_branch_of_a_conditional(
+        compiled_stepk):
+    """The compiler kept the pick's ``lax.cond`` a ``conditional`` (it
+    did not turn it into a select that runs both sides): the only sort
+    of the step sits in one of its branch computations, under the scope
+    ``zoo_sample``, and the other branch holds none."""
+    import re
+    from analytics_zoo_tpu.observability import profile
+    comps = _computations(compiled_stepk(16).as_text())
+    sorts = [(name, line) for name, lines in comps.items()
+             for line in lines if re.search(r" sort\(", line)]
+    [(home, sort)] = sorts
+    assert f"jit({profile.SCOPE_SAMPLE})/cond/branch_1_fun" in sort
+    [cond] = [line for lines in comps.values() for line in lines
+              if " conditional(" in line]
+    assert f"jit({profile.SCOPE_SAMPLE})/cond" in cond
+    branches = re.search(r"branch_computations=\{([^}]*)\}", cond)
+    argmax, sorted_ = [b.strip().lstrip("%")
+                       for b in branches.group(1).split(",")]
+    assert home == sorted_
+    assert not any("cumsum" in line or "top_k" in line
+                   for line in comps[argmax])
