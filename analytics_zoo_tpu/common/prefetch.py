@@ -3,8 +3,8 @@ k's device compute.
 
 ``jax.device_put`` is dispatch-asynchronous, but everything BEFORE it —
 decode, shuffle-gather, ``np.stack``, tail padding — runs on the host and
-serializes with the step loop unless it is moved off-thread.  PERF_NOTES
-§"Host input pipeline" measures overlap efficiency 0.65 for the
+serializes with the step loop unless it is moved off-thread.  An earlier
+round's notes (withdrawn) read overlap efficiency 0.65 for the
 synchronous put-then-step pattern: the host→device transfer plus batch
 materialization is the end-to-end wall.  ``prefetch`` runs the source
 iterator AND the transform (decode + ``device_put``) on a background

@@ -102,7 +102,7 @@ class ImageLoader:
         self._epoch = 0
         # out_dtype="uint8": emit raw resized pixels and DEFER
         # normalization to the device — a 4x smaller host→device transfer
-        # (the normalize belongs in the jit'd step; see bench.py)
+        # (the normalize belongs in the jit'd step, on the device)
         if out_dtype not in ("float32", "uint8"):
             raise ValueError(f"unsupported out_dtype {out_dtype!r}")
         if out_dtype == "uint8" and (mean is not None or std is not None

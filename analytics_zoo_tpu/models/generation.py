@@ -370,7 +370,7 @@ def build_generate_fn(hyper, s_p: int, max_new: int, temperature: float,
     if ragged:
         return jax.jit(run)
     # jit the 3-arg closure (not a bare lambda over a jitted fn) so the
-    # returned callable keeps .lower() — bench.py AOT-checks the plan
+    # returned callable keeps .lower(), for lowering the plan unrun
     return jax.jit(lambda params, prompt, rng: run(params, prompt, None,
                                                    rng))
 

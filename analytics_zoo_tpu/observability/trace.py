@@ -73,8 +73,8 @@ _SPAN_VAR: "contextvars.ContextVar[Optional[Span]]" = \
 # this process, True forever after.  A process that never traces pays
 # exactly one bool branch per predict; once tracing has happened the
 # branch falls through to a contextvar read (~100ns).  Sticky (rather
-# than refcounted) keeps activate() lock-free on the request path —
-# the bench overhead gate measures this.
+# than refcounted) keeps activate() lock-free on the request path;
+# what that costs on a chip is not measured (no cell traces requests).
 _ENABLED = False
 
 
@@ -113,7 +113,7 @@ def activate(span: "Optional[Span]"):
 
 
 # a fresh uuid4 per request costs ~40us on small hosts — material
-# against a ~1ms request (the bench overhead gate caught it).  One
+# against a ~1ms request (seen on a CPU host).  One
 # random prefix per process + a GIL-atomic counter is unique within
 # any ring/log scope and ~1us.
 _ID_PREFIX = uuid.uuid4().hex[:8]
@@ -387,9 +387,9 @@ class Tracer:
     def request(self, name: str = "request",
                 trace_id: Optional[str] = None, **labels: Any):
         """Start a span, activate it for the calling thread, finish it
-        on exit — the one-liner for benches and tests.  Activation is
-        inlined (no nested context manager): this wrapper sits inside
-        the overhead the bench gate bounds."""
+        on exit — the one-liner for scripts and tests.  Activation is
+        inlined (no nested context manager): this wrapper sits on the
+        request path of every traced request."""
         global _ENABLED
         span = Span(self, name, trace_id=trace_id, labels=labels)
         token = _SPAN_VAR.set(span)

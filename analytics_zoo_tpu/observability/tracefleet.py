@@ -80,8 +80,8 @@ def span_summary(span_dict: Dict[str, Any],
     are dropped and floats are rounded to 1us — the full tree stays in
     the worker's ring/flight recorder; the reply carries only what
     per-request attribution needs, and every extra byte here is paid
-    on the hot serve path (the traced/untraced throughput-ratio bench
-    gate prices this function)."""
+    on the hot serve path (its cost on a chip is not measured: no
+    benchmark cell drives the fleet)."""
     phases = [[p.get("name"), round(p.get("start_ms") or 0.0, 3),
                round(p["dur_ms"], 3)]
               for p in (span_dict.get("phases") or ())

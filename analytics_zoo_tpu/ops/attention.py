@@ -502,7 +502,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
       raw (b, s, h, d) layout cannot lower: the block's minor-two dims
       must be (sublane=s, lane=d), but h sits between them, so any
       (block_q, 1, d) tile puts a size-1 h in the sublane slot
-      (captured analysis, PERF_NOTES r3/r4).
+      (found in rounds 3 and 4 of the first chip runs).
     - ``"bhsd"``: q/k/v arrive (batch, heads, seq, head_dim).  Folding
       to the kernel's (b·h, s, d) is a pure reshape of two contiguous
       major axes — NO copy.  Transformer stacks should project straight

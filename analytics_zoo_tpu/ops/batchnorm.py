@@ -1,6 +1,6 @@
 """Train-mode BatchNorm core with a hand-written VJP.
 
-Why this exists (PERF_NOTES r3 / VERDICT r3 #2): train-mode BN batch
+Why this exists (round 3's chip run, record withdrawn): train-mode BN batch
 statistics cost ~10 ms of a 52 ms ResNet-50 step on the v5e.  The naive
 formulation autodiffed by XLA has two structural inefficiencies:
 
@@ -143,7 +143,7 @@ def set_naive_bn(flag: bool):
 
 def batch_norm_train_naive(x, gamma, beta, eps, ch_axis):
     """The pre-restructuring formulation (two reduction passes over an
-    f32 cast, XLA-autodiff backward) — kept for the bench's A/B."""
+    f32 cast, XLA-autodiff backward); nothing selects it (ROADMAP D3)."""
     axes, _ = _reduce_axes_and_count(x, ch_axis)
     bshape = [1] * x.ndim
     bshape[ch_axis] = x.shape[ch_axis]

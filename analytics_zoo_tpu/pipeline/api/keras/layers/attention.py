@@ -4,7 +4,7 @@ support is first-class here, so the ops-level stack
 (``ops/attention.py`` flash kernel, ``parallel/ring_attention``) gets a
 Keras-level consumer.
 
-Design note (the transpose-tax fix, PERF_NOTES r4): q/k/v are projected
+Design note (the transpose-tax fix of round 4): q/k/v are projected
 DIRECTLY into the (batch, heads, seq, head_dim) layout via
 ``einsum("bse,ehd->bhsd", x, W)`` — XLA folds the layout into the
 projection matmul's output, and the pallas kernel's batch/head fold

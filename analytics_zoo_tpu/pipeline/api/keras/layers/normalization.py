@@ -61,7 +61,7 @@ class BatchNormalization(Layer):
             # fused statistics + closed-form custom VJP — statistics
             # accumulate in f32 regardless of compute dtype, and the
             # moving-stat update is stop-gradient (BigDL running stats).
-            # USE_NAIVE is the bench's A/B switch (trace-time).
+            # USE_NAIVE: a trace-time switch nothing sets (ROADMAP D3).
             from .....ops import batchnorm as bn_lib
             bn_fn = (bn_lib.batch_norm_train_naive if bn_lib.USE_NAIVE
                      else batch_norm_train)

@@ -72,8 +72,8 @@ bit-exact-replay invariants:
   CONSTRUCTION (full rejection degrades to exactly the plain
   engine's computation).  Accepted window tokens are selected from
   the verify pass's own logits, which match the single-query step to
-  ~1 ulp — identical selections on this backend (tests and the bench
-  pin spec ≡ plain empirically); a near-tie flip under a backend
+  ~1 ulp — identical selections on this backend (test_serving_decode
+  pins spec ≡ plain empirically); a near-tie flip under a backend
   whose window kernels round differently is the only theoretical
   divergence channel.  Sampled verification draws each window
   position from the same per-slot fold_in key the non-speculative
@@ -472,8 +472,8 @@ class DecodeEngine:
         # line, sampling from that slot's own logits — stays entirely
         # on one device.  No cross-slot term exists in the step, so
         # the partitioned program is a pure per-device map: bit-exact
-        # vs the unsharded engine BY CONSTRUCTION (bench.py sharded
-        # gates it).  Params replicate across the group (the weights
+        # vs the unsharded engine BY CONSTRUCTION (test_serving_shardgroup
+        # pins it).  Params replicate across the group (the weights
         # ride the forward unsharded; rule-sharded decode weights
         # would put collectives inside the step — a later engine
         # version's trade).

@@ -180,7 +180,7 @@ class ReplicaSet:
     with only its device assignment rewritten.  Deserialization is a
     load, not a compile (~3-10 ms against a multi-hundred-ms compile)
     and fires no compile event — which is exactly the accounting the
-    sanitizer and the bench's one-compile-per-bucket gate enforce.
+    sanitizer and test_serving_replicas' one-compile-per-bucket pin hold.
 
     Dispatch bypasses the jit wrapper entirely: inputs are uploaded to
     the replica's device via explicit ``device_put`` (transfer-guard
@@ -1530,9 +1530,9 @@ class RequestCoalescer:
     # ---- resolve (plain + hedged) ----
     def _fetch_slot(self, dev, n: int, slot: int):
         """Blocking host fetch of a dispatched group.  A method (not a
-        bare ``fetch_rows`` call) so tests and the bench can patch a
-        per-slot straggler delay in — the injection point for the
-        hedging gates."""
+        bare ``fetch_rows`` call) so a test can patch a per-slot
+        straggler delay in — the injection point of the hedging tests
+        (test_serving_elastic)."""
         return fetch_rows(dev, n)
 
     def _fetch_hedge(self, dev, n: int, replica_index: int):

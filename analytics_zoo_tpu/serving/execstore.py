@@ -2,7 +2,7 @@
 cache for zero-compile cold start and instant fleet deploy.
 
 Every fresh process pays the full bucket-ladder + decode-plan XLA
-compile (~380 ms per executable, PERF_NOTES §PR 5) before it can
+compile (~380 ms per executable on a CPU host, PR 5) before it can
 serve — a restarted worker or a newly provisioned replica is cold for
 seconds.  PR 5 already proved the serialized-executable round trip
 loads in ~3-10 ms with only the device assignment rewritten; this
@@ -48,8 +48,8 @@ Enabling the store::
 
 With the store enabled, ``ModelRegistry.deploy()`` and
 ``DecodeEngine.warmup()`` in a process whose store is warm record
-ZERO ``backend_compile`` events (``bench.py coldstart`` gates this
-across two real processes).  Without configuration the store is
+ZERO ``backend_compile`` events (test_execstore holds this across
+two real processes).  Without configuration the store is
 entirely inert — no files, no lookups, identical serving behavior.
 
 Hygiene: the store is size-capped LRU.  Reads bump an entry's mtime;

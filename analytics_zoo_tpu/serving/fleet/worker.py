@@ -408,7 +408,7 @@ class ServingWorker:
         # the store hit/miss DELTA is the authoritative warm/cold
         # verdict for this activation: a decode-capable deployment
         # always fires a few trivial fill "compiles" allocating its
-        # slot-array state (PERF_NOTES §PR 8 — state allocation, not
+        # slot-array state (seen at PR 8 — state allocation, not
         # plan compilation), so misses==0 is the cross-process
         # zero-PLAN-compile claim; the raw compile count stays exact
         # for pure predict-plane deploys

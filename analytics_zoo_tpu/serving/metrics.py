@@ -83,8 +83,8 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
     # a fraction without a dashboard join).  Decode engine v2 adds the
     # sampled-token counter, the prefix-pool hit/miss pair (their
     # ratio is the shared-prefix win), and the speculative
-    # proposed/accepted pair (their ratio is the acceptance rate the
-    # spec bench gates on) — exported whenever a decode engine is
+    # proposed/accepted pair (their ratio is the draft's acceptance
+    # rate) — exported whenever a decode engine is
     # live, zeros until the feature serves traffic, so dashboards and
     # alerts can pre-wire at deploy.  The two seconds counters say
     # whether the host or the chip is the limit: how long requests

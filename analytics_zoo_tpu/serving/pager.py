@@ -9,7 +9,7 @@ entry into a resident/cold state machine instead:
 * **resident** — the deployment holds a live ``InferenceModel``
   (device-placed weights + compiled/rehydrated executables); requests
   serve on the existing hot path, which NEVER acquires the pager lock
-  (the density bench pins zero pager-lock acquisitions and zero
+  (test_serving_pager pins zero pager-lock acquisitions and zero
   compiles over a warmed resident window);
 * **cold** — the deployment's model handle is closed and dropped;
   the entry keeps only its *recipe*: host-side (numpy) weights plus
@@ -75,8 +75,8 @@ COLD = "cold"
 
 
 class _CountingLock:
-    """A plain mutex that counts successful acquisitions.  The density
-    bench's resident-hot-path gate reads the count around a warmed
+    """A plain mutex that counts successful acquisitions.  The
+    resident-hot-path test reads the count around a warmed
     serve window: a resident model's request path must never touch
     the pager, and this makes "never" measurable instead of asserted.
     (The increment happens while the lock is held, so the counter
@@ -149,8 +149,8 @@ class ModelPager:
         self._reap_interval_s = float(reap_interval_s)
         # THE pager lock: every residency transition (fault, evict,
         # attach, detach) serializes here.  The resident request path
-        # never acquires it — `lock_acquisitions` is the proof the
-        # bench reads.
+        # never acquires it — `lock_acquisitions` is the proof a
+        # test reads.
         self._lock = _CountingLock()
         self._cond = threading.Condition(self._lock)
         self._entries: Dict[str, Any] = {}
@@ -161,8 +161,8 @@ class ModelPager:
     # ---- introspection -------------------------------------------------
     @property
     def lock_acquisitions(self) -> int:
-        """Total pager-lock acquisitions ever (bench gate reads the
-        delta over a warmed resident window and requires 0)."""
+        """Total pager-lock acquisitions ever (test_serving_pager reads
+        the delta over a warmed resident window and requires 0)."""
         return self._lock.acquisitions
 
     def resident_count(self) -> int:

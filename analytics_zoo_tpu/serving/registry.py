@@ -602,7 +602,7 @@ class ModelRegistry:
         """Residency checkout for one admitted request.  The RESIDENT
         fast path is one state read, a lock-free LRU stamp, and the
         in-flight counter the evictor's quiesce reads — it NEVER
-        touches the pager lock (the density bench pins this).  Any
+        touches the pager lock (test_serving_pager pins this).  Any
         other state diverts to the shared fault-in, whose wait/build
         seconds are excluded from the admission service EWMA so a
         cold start cannot poison predictive shedding."""

@@ -31,7 +31,7 @@ every matched weight sharded along its LAST axis — XLA partitions the
 forward as all-gather + full local contraction, which performs the
 identical float operations in the identical order as the unsharded
 program, so 1-group-of-N output is bit-identical to single-device
-(``bench.py sharded`` gates this).  Contraction-dim (row) sharding
+(test_serving_shardgroup pins this).  Contraction-dim (row) sharding
 instead lowers to partial-dot + psum, whose float add order differs:
 supported, but NOT bit-exact — choose it for memory, not for the
 oracle.
@@ -297,7 +297,7 @@ class ShardGroupSet(ReplicaSet):
         assignment — one replica, ``group_size`` partitions spanning
         the group's devices — instead of the base class's ``(1, 1)``.
         Still a load, never a compile: zero ``backend_compile`` events
-        (the bench's ``SHARDED_ZERO_COMPILE`` gate counts)."""
+        (``test_warm_store_second_set_zero_compiles`` counts)."""
         return self._load_serialized(ser, group.devices)
 
     # ---- identity / introspection ----

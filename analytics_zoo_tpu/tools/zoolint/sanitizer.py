@@ -29,8 +29,8 @@ Usage::
             model.predict(x)
     assert rep.compiles == 0    # redundant — exit would have raised
 
-Tests get it as the ``zoolint_sanitize`` fixture; ``bench.py serving
---selfcheck`` runs the serving hot loop under it.
+Tests get it as the ``zoolint_sanitize`` fixture; the serving, replica
+and decode hot-loop tests run their warmed loops under it.
 """
 
 from __future__ import annotations

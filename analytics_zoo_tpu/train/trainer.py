@@ -104,8 +104,8 @@ def build_train_step(model, loss_fn, optimizer, compute_dtype=None,
     compute/activations, f32 master weights; grads return f32 through the
     cast's transpose so the optax update — moments included — runs in
     f32) and optional gradient accumulation.  Single source of truth —
-    the Trainer, bench.py and the driver dry run all compile this same
-    function.
+    the Trainer, the driver dry run and tests/test_tpu_compile.py all
+    lower this same function.
 
     ``accum_steps > 1``: ``x``/``y`` carry a LEADING microbatch axis
     ``(accum, micro, ...)`` and the step runs a ``lax.scan`` over it

@@ -249,7 +249,7 @@ def make_handler(registry, obs=None):
                 payload = self._body()
                 if self.path == "/predict":
                     # prefix+counter, not uuid4 — a fresh uuid costs
-                    # ~40us, material per request (PERF_NOTES §PR 4)
+                    # ~40us on a CPU host, material per request
                     from analytics_zoo_tpu.observability.trace import \
                         new_trace_id
                     rid = (self.headers.get("X-Request-Id")

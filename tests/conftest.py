@@ -76,6 +76,9 @@ _SLOW_TESTS = {
     "test_report_exposes_strategy_differences",
     "test_text_classifier_rnn_builds",
     "test_quantized_params_are_smaller",
+    # they start real worker processes (two interpreters; a fleet)
+    "test_second_process_deploys_from_a_warm_store_with_zero_compiles",
+    "test_real_workers_deploy_warm_and_answer_as_one_process_does",
 }
 
 

@@ -10,11 +10,15 @@ in-process assertions here pin the STORE's own verdicts (hit / miss /
 write / invalid counters) plus bit-exactness and sanitize-clean
 loops.  The genuine two-process zero-compile proof — a fresh process
 whose ``deploy()`` and ``DecodeEngine.warmup()`` record 0 compile
-events against a warmed store — is ``bench.py coldstart``'s gate,
-run by scripts/smoke_serving.sh.
+events against a warmed store — is the last test of this file
+(``slow``: it starts two interpreters).
 """
 
+import json
 import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -402,3 +406,109 @@ def test_no_store_io_on_warmed_dispatch_path(store, zoolint_sanitize,
                 im.predict(x)
     finally:
         im.close()
+
+
+# ------------------------------------------ two processes, one store
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# One process of the cold-start proof: deploy a seeded MLP through the
+# registry and warm a decode engine, counting ``backend_compile`` events
+# inside exactly those two calls.  The store engages through
+# ZOO_EXECSTORE_DIR alone.  argv: work directory, "cold" | "warm".
+_DEPLOY_ONCE = textwrap.dedent("""
+    import json, os, sys
+    import numpy as np
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    compiles = []
+    monitoring.register_event_duration_secs_listener(
+        lambda key, _s, **kw: (compiles.append(key)
+                               if "backend_compile" in key else None))
+
+    from analytics_zoo_tpu.models import TransformerLM
+    from analytics_zoo_tpu.pipeline.inference.decode import DecodeEngine
+    from analytics_zoo_tpu.serving import ModelRegistry, execstore
+
+    work, role = sys.argv[1], sys.argv[2]
+    store = execstore.current()
+    assert store is not None, "ZOO_EXECSTORE_DIR not honoured"
+    rng = np.random.default_rng(0)
+    params = {f"w{i}": rng.normal(size=(16, 16)).astype(np.float32) * 0.1
+              for i in range(4)}
+
+    def mlp(p, x):
+        for i in range(4):
+            x = jnp.tanh(x @ p[f"w{i}"])
+        return x
+
+    res = {}
+    reg = ModelRegistry(replicas="all", max_batch_size=8)
+    n0 = len(compiles)
+    reg.deploy("mlp", jax_fn=mlp, params=params, warmup_shapes=(16,))
+    res["deploy_compiles"] = len(compiles) - n0
+    out = np.asarray(reg.predict(
+        "mlp", rng.normal(size=(4, 16)).astype(np.float32)))
+
+    lm = TransformerLM(vocab_size=64, seq_len=48, n_layers=2,
+                       d_model=32, n_heads=4)
+    trainer = lm.ensure_inference_ready()
+    prompts = [rng.integers(0, 64, int(rng.integers(4, 16)))
+               for _ in range(3)]
+    # the slot array's zero fills are programs too: built outside the
+    # counted call, they are state, not plans a store could serve
+    engine = DecodeEngine(trainer.state.params, lm.hyper, capacity=2,
+                          max_len=48, prompt_buckets=(16,))
+    n1 = len(compiles)
+    engine.warmup()
+    res["warmup_compiles"] = len(compiles) - n1
+    toks = engine.generate(prompts, 6, timeout=300)
+    engine.close()
+    reg.shutdown()
+
+    expect = os.path.join(work, "expect.npz")
+    if role == "cold":
+        np.savez(expect, out, *toks)
+        res["same_answers"] = True
+    else:
+        with np.load(expect) as z:
+            res["same_answers"] = (
+                len(z.files) == 1 + len(toks)
+                and all(np.array_equal(got, z[f"arr_{i}"])
+                        for i, got in enumerate([out, *toks])))
+    res["store"] = {k: store.stats()[k]
+                    for k in ("hit", "write", "invalid")}
+    print("DEPLOYED " + json.dumps(res), flush=True)
+""")
+
+
+def test_second_process_deploys_from_a_warm_store_with_zero_compiles(
+        tmp_path):
+    """A first process deploys and decode-warms against an empty store
+    and exits; a second, fresh process (nothing shared but the store
+    directory) repeats the same deploy and records no compile event
+    inside ``deploy()`` or ``DecodeEngine.warmup()``, with the first
+    one's answers bit for bit."""
+    script = tmp_path / "deploy_once.py"
+    script.write_text(_DEPLOY_ONCE)
+    env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+           "ZOO_EXECSTORE_DIR": str(tmp_path / "store"),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
+
+    def deploy_once(role):
+        proc = subprocess.run(
+            [sys.executable, str(script), str(tmp_path), role], env=env,
+            cwd=REPO, capture_output=True, text=True, timeout=600)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        [line] = [ln for ln in proc.stdout.splitlines()
+                  if ln.startswith("DEPLOYED ")]
+        return json.loads(line[len("DEPLOYED "):])
+
+    cold = deploy_once("cold")
+    # a zero means something only where an empty store compiles
+    assert cold["deploy_compiles"] > 0 and cold["warmup_compiles"] > 0
+    assert cold["store"]["write"] > 0
+    warm = deploy_once("warm")
+    assert warm["deploy_compiles"] == 0 and warm["warmup_compiles"] == 0
+    assert warm["same_answers"]
+    assert warm["store"]["hit"] > 0 and warm["store"]["invalid"] == 0

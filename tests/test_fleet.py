@@ -6,7 +6,9 @@ ordering, least-outstanding routing, retry-on-dead-worker,
 crash-restart with version replay, priority-class pass-through, and
 the rank-merged fleet scrape.  Fake mode does zero jax work (stub
 data plane — no backend, no compiles), so these stay fast; the
-jax-real end of all of this is ``bench.py fleet`` (smoke-gated)."""
+jax-real end is the two tests that start workers with ``fake=False``:
+cross-process generate determinism, and (``slow``) the warm fan-out
+of real deploys answered as one process answers."""
 
 import json
 import os
@@ -734,8 +736,7 @@ def test_elastic_scale_down_drains_then_scale_up_revives(make_fleet):
 def test_autoscaler_drives_pool_through_load_signals(make_fleet):
     """fleet_autoscaler wires PR 6's Autoscaler to the router: the
     queue-depth signal crosses via load_signals() and apply_scale
-    resizes the pool through set_pool_size — ticked synthetically
-    (the bench drives it with real traffic)."""
+    resizes the pool through set_pool_size — ticked synthetically."""
     from analytics_zoo_tpu.serving.fleet import fleet_autoscaler
     r = make_fleet(n_workers=2)
     r.deploy("m", None, STUB)
@@ -835,6 +836,82 @@ def test_cross_process_generate_determinism(tmp_path):
             out2, _ = r.generate_ex(
                 "lm", np.asarray(prompt, np.int32), **c)
             assert [np.asarray(t).tolist() for t in out2] == got
+    finally:
+        r.close()
+
+
+# ------------------------------------------- real workers, one store
+def test_real_workers_deploy_warm_and_answer_as_one_process_does(
+        tmp_path):
+    """Two REAL worker processes (jax, registry, the share's execstore)
+    behind the router.  A deploy fans out in rank order: the first
+    activation of new weights compiles (it fills the store, and shows
+    that a zero below means something), every later worker warms with
+    0 compiles; weights the store has seen deploy with 0 everywhere;
+    a killed worker comes back replaying the current version with 0.
+    After each deploy every worker, asked directly, answers bit-equal
+    to a registry in this process built by the same builder."""
+    from analytics_zoo_tpu.serving import ModelRegistry
+    from analytics_zoo_tpu.serving.fleet import builders
+
+    n_layers, d = 6, 16
+    registry_kwargs = {"max_batch_size": 8}
+
+    def weights(seed):
+        rng = np.random.default_rng(seed)
+        return {f"w{i}": rng.normal(size=(d, d)).astype(np.float32) * 0.1
+                for i in range(n_layers)}
+
+    first, second = weights(7), weights(11)
+    x = np.random.default_rng(3).normal(size=(3, d)).astype(np.float32)
+    with ModelRegistry(**registry_kwargs) as reg:    # no store here
+        want = []
+        for name, w in (("first", first), ("second", second)):
+            reg.deploy(name, warmup_shapes=(d,),
+                       **builders.mlp({"n_layers": n_layers}, w))
+            want.append(np.asarray(reg.predict(name, x)).copy())
+
+    r = FleetRouter(str(tmp_path / "share"), n_workers=2, fake=False,
+                    registry_kwargs=registry_kwargs,
+                    env={"PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"},
+                    max_restarts=1, restart_backoff=0.2)
+
+    def fan_out(params):
+        rep = r.deploy("mlp", params,
+                       "analytics_zoo_tpu.serving.fleet.builders:mlp",
+                       builder_args={"n_layers": n_layers},
+                       warmup_shapes=[d])
+        acts = rep["activations"]
+        assert [a["rank"] for a in acts] == [0, 1], acts
+        assert all("error" not in a for a in acts), acts
+        return rep["version"], [a["compiles"] for a in acts]
+
+    def every_worker_answers(version, expect):
+        for h in r.handles:
+            resp = r._call(h, {"op": "predict", "model": "mlp",
+                               "inputs": x})
+            assert resp["info"]["version"] == version
+            assert np.array_equal(
+                protocol.decode_value(resp["result"]), expect), h.rank
+
+    try:
+        r.start(timeout=300)
+        version, compiles = fan_out(first)
+        assert version == 1 and compiles[0] > 0 and compiles[1] == 0
+        every_worker_answers(1, want[0])
+        version, compiles = fan_out(second)
+        assert version == 2 and compiles[0] > 0 and compiles[1] == 0
+        every_worker_answers(2, want[1])
+        version, compiles = fan_out(first)
+        assert version == 3 and compiles == [0, 0]
+        every_worker_answers(3, want[0])
+        r.supervisor.kill(1)
+        assert _wait(lambda: r.supervisor.postmortems
+                     and r.states().get("live") == 2
+                     and r.replays.get(1), timeout=120)
+        assert [(rep["model"], rep["version"], rep["compiles"])
+                for rep in r.replays[1]] == [("mlp", 3, 0)]
+        every_worker_answers(3, want[0])
     finally:
         r.close()
 

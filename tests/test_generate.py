@@ -207,7 +207,7 @@ def test_generate_validation():
     np.testing.assert_array_equal(
         m.generate(p0, max_new_tokens=0,
                    prompt_lengths=np.array([4, 2])), p0)
-    # the compiled plan object keeps .lower() — bench.py AOT-checks it
+    # the compiled plan object keeps .lower(): a plan can be lowered unrun
     from analytics_zoo_tpu.models.generation import build_generate_fn
     assert hasattr(build_generate_fn(m.hyper, 4, 2, 0.0, None), "lower")
 

@@ -3,6 +3,7 @@ single-process run and local multi-process fan-out forming a real
 jax.distributed cluster."""
 
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -72,6 +73,7 @@ def test_local_fanout_forms_cluster(tmp_path):
 
 
 TRAIN_DEMO = textwrap.dedent("""
+    import hashlib
     import os
     import numpy as np
     import optax
@@ -101,48 +103,73 @@ TRAIN_DEMO = textwrap.dedent("""
     trainer.set_checkpoint(ckpt_dir,
                            trigger=triggers.SeveralIteration(2))
     trainer.fit(ds, batch_size=16, end_trigger=triggers.MaxEpoch(3))
+    digest = hashlib.sha256(b"".join(
+        np.asarray(jax.device_get(leaf)).tobytes()
+        for leaf in jax.tree_util.tree_leaves(trainer.state.params)))
     print(f"RESULT proc={jax.process_index()}/{jax.process_count()} "
           f"step={trainer.state.step} "
-          f"resumed={1 if os.environ.get('ZOO_RESUME') else 0}",
+          f"resumed={1 if os.environ.get('ZOO_RESUME') else 0} "
+          f"params={digest.hexdigest()}",
           flush=True)
 """)
 
 
-@pytest.mark.slow
-def test_supervisor_recovers_sigkilled_worker_mid_epoch(tmp_path):
-    """The full recovery loop on a REAL 2-process jax.distributed
-    cluster: worker 1 SIGKILLs itself mid-epoch (ZOO_FAULT_CRASH_STEP),
-    the supervisor reaps + relaunches with ZOO_RESUME, and the resumed
-    pod restores the newest complete checkpoint and finishes all 12
-    steps."""
+def _train_pod(tmp_path, name, faults):
+    """One supervised 2-process pod of TRAIN_DEMO under ``faults``;
+    returns (stdout, the supervisor's summary, each RESULT as
+    (rank, step, resumed, digest of the parameters))."""
     import json
     script = tmp_path / "train_demo.py"
     script.write_text(TRAIN_DEMO)
-    ckpt = tmp_path / "ckpt"
-    summary = tmp_path / "summary.json"
+    summary = tmp_path / f"{name}.json"
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO
-    env["ZOO_FAULT_CRASH_STEP"] = "6"
-    env["ZOO_FAULT_CRASH_RANK"] = "1"
     env["ZOO_CKPT_SYNC"] = "1"
-    for k in ("ZOO_TPU_COORDINATOR", "ZOO_TPU_NUM_PROCESSES",
-              "ZOO_TPU_PROCESS_ID", "ZOO_RESUME"):
-        env.pop(k, None)
+    for k in list(env):
+        if k.startswith("ZOO_FAULT_") or k in (
+                "ZOO_TPU_COORDINATOR", "ZOO_TPU_NUM_PROCESSES",
+                "ZOO_TPU_PROCESS_ID", "ZOO_RESUME"):
+            del env[k]
+    env.update(faults)
     proc = subprocess.run(
         [sys.executable, "-m", "analytics_zoo_tpu.launcher",
          "--num-processes", "2", "--devices-per-process", "1",
          "--max-restarts", "2", "--restart-backoff", "0.25",
          "--summary-json", str(summary),
-         str(script), str(ckpt)],
+         str(script), str(tmp_path / name)],
         env=env, cwd=REPO, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True, timeout=600)
     assert proc.returncode == 0, proc.stdout[-3000:]
-    summ = json.loads(summary.read_text())
+    # the two ranks share one pipe: a line may hold both RESULTs
+    return (proc.stdout, json.loads(summary.read_text()),
+            re.findall(r"RESULT proc=(\d)/2 step=(\d+) resumed=(\d) "
+                       r"params=([0-9a-f]{64})", proc.stdout))
+
+
+@pytest.mark.slow
+def test_supervisor_recovers_sigkilled_worker_mid_epoch(tmp_path):
+    """The full recovery loop on a REAL 2-process jax.distributed
+    cluster: worker 1 SIGKILLs itself mid-epoch (ZOO_FAULT_CRASH_STEP)
+    and the step-4 checkpoint's shard is byte-flipped after its commit;
+    the supervisor reaps + relaunches with ZOO_RESUME, the restore
+    convicts the corrupt tag and falls back to the one before, and the
+    resumed pod finishes all 12 steps with parameters BIT-EQUAL to a
+    pod that nothing interrupted."""
+    _, summ, results = _train_pod(tmp_path, "whole", {})
+    assert summ["restarts"] == 0
+    [whole] = {digest for *_, digest in results}    # replicated state
+    assert sorted(results) == [("0", "12", "0", whole),
+                               ("1", "12", "0", whole)]
+
+    out, summ, results = _train_pod(tmp_path, "killed", {
+        "ZOO_FAULT_CRASH_STEP": "6", "ZOO_FAULT_CRASH_RANK": "1",
+        "ZOO_FAULT_CORRUPT_TAG": "4"})
     assert summ["restarts"] == 1 and summ["reasons"] == ["exit"]
-    lines = [l for l in proc.stdout.splitlines() if "RESULT" in l]
-    # the final incarnation completed on both ranks, resumed
-    assert any("proc=0/2 step=12 resumed=1" in l for l in lines), lines
-    assert any("proc=1/2 step=12 resumed=1" in l for l in lines), lines
+    assert "discarding corrupt checkpoint" in out
+    # the final incarnation completed on both ranks, resumed, and
+    # holds the uninterrupted pod's parameters
+    assert sorted(results) == [("0", "12", "1", whole),
+                               ("1", "12", "1", whole)]
 
 
 def test_pod_mode_requires_coordinator(tmp_path):

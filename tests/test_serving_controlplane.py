@@ -11,7 +11,7 @@ The pinned contracts (ISSUE 2 acceptance):
   accepted requests still meet their deadlines.
 
 Timing notes: this box has 2 cores and external contention
-(BASELINE/PERF_NOTES), so every latency bound here is an order of
+(a shared CPU sandbox), so every latency bound here is an order of
 magnitude looser than the mechanism's actual speed — the assertions
 distinguish "immediate rejection" from "queued until timeout", not
 microseconds from milliseconds.

@@ -527,6 +527,36 @@ def test_registry_exports_active_replica_gauge():
         assert total[1] == 2 and active == 1
 
 
+def test_hedge_and_autoscale_families_ride_one_clean_scrape():
+    """The registry bridge and the autoscaler's collector render into
+    ONE exposition that the stdlib parser takes back, with the hedging
+    and scaling families beside the admission ones."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.observability.metrics import (
+        MetricsRegistry, parse_prometheus_text)
+    from analytics_zoo_tpu.serving import (autoscaler_for,
+                                           registry_collector)
+
+    with ModelRegistry(max_concurrency=2, supported_concurrent_num=2,
+                       max_batch_size=4, coalescing=True, replicas=2,
+                       hedging=True) as reg:
+        reg.deploy("m", jax_fn=lambda p, x: jnp.tanh(x @ p["w"]),
+                   params={"w": np.eye(4, dtype=np.float32)},
+                   warmup_shapes=(4,))
+        reg.predict("m", np.ones((1, 4), np.float32))
+        scaler = autoscaler_for(reg, "m", min_replicas=1)
+        mreg = MetricsRegistry()
+        mreg.register_collector(registry_collector(reg))
+        mreg.register_collector(scaler.families)
+        samples = parse_prometheus_text(mreg.render_prometheus())["samples"]
+    names = {key[0] for key in samples}
+    assert {"zoo_hedge_total", "zoo_autoscale_events_total",
+            "zoo_model_replicas_active", "zoo_shed_total",
+            "zoo_class_admitted_total"} <= names, sorted(names)
+    assert samples[("zoo_autoscale_events_total",
+                    (("direction", "up"), ("model", "m")))] == 0
+
+
 def test_set_active_clamps():
     rs = ReplicaSet(lambda p, x: x * p["s"], {"s": np.float32(1.0)},
                     devices=jax.local_devices()[:4])

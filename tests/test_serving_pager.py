@@ -94,8 +94,8 @@ def test_budget_n_keeps_n_resident():
 
 
 def test_resident_hot_path_never_touches_pager_lock():
-    """The bench gate's mechanism, pinned: a warmed resident model's
-    requests acquire the pager lock zero times."""
+    """A warmed resident model's requests acquire the pager lock zero
+    times."""
     with _paged_registry(budget=2) as reg:
         _deploy_const(reg, "a", 1.0)
         reg.predict("a", X)
@@ -103,6 +103,36 @@ def test_resident_hot_path_never_touches_pager_lock():
         for _ in range(25):
             reg.predict("a", X)
         assert reg.pager.lock_acquisitions == la0
+
+
+def test_fault_in_from_a_warm_store_compiles_nothing(tmp_path):
+    """A fault is one weights ``device_put`` and an execstore rehydrate,
+    never a recompile: with the store on, a budget-1 registry churns two
+    models through fault and eviction and the whole window records no
+    ``backend_compile`` event."""
+    from jax._src import monitoring
+    from analytics_zoo_tpu.serving import execstore
+    compiles = []
+    monitoring.register_event_duration_secs_listener(
+        lambda key, _s, **kw: (compiles.append(key)
+                               if "backend_compile" in key else None))
+    execstore.configure(str(tmp_path / "store"))
+    try:
+        with _paged_registry(budget=1) as reg:
+            _deploy_const(reg, "a", 1.0)
+            _deploy_const(reg, "b", 2.0)
+            n0 = len(compiles)
+            assert n0 > 0           # the listener hears a deploy compile
+            for _ in range(3):
+                np.testing.assert_array_equal(
+                    reg.predict("a", X), np.ones((2, 3)))
+                np.testing.assert_array_equal(
+                    reg.predict("b", X), 2 * np.ones((2, 3)))
+            m = reg.metrics()
+            assert sum(v["pager"]["fault_ok"] for v in m.values()) >= 5
+            assert compiles[n0:] == []
+    finally:
+        execstore.disable()
 
 
 def test_keras_graph_paging_bitexact():

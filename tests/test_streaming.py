@@ -148,7 +148,7 @@ def _write_image_folder(root, n_per_class=12, size=(10, 10)):
 
 def test_image_loader_uint8_defers_normalization(tmp_path):
     """out_dtype='uint8' ships raw pixels (4x smaller host→device
-    transfer); normalization belongs on-device (bench.py input-fed)."""
+    transfer); normalization belongs on-device, in the jitted step."""
     from analytics_zoo_tpu.data.image_loader import ImageLoader
     _write_image_folder(str(tmp_path), n_per_class=4)
     loader = ImageLoader.from_folder(str(tmp_path), batch_size=4,

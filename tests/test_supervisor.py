@@ -6,8 +6,8 @@ coordinator port-race retry.
 These drive the REAL supervisor loop (`launcher._run_supervised`)
 through `python -m analytics_zoo_tpu.launcher`, but with trivial
 non-jax worker scripts so they stay fast enough for tier-1 — the full
-jax.distributed drill lives in test_launcher.py (slow) and
-`bench.py faulttrain`.
+jax.distributed drill (kill, resume, bit-equal parameters) lives in
+test_launcher.py (slow).
 """
 
 import json

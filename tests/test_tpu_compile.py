@@ -258,33 +258,46 @@ def two_layers():
     return lm
 
 
+def _engine_without_state(hyper, slots, device, weights):
+    """A ``DecodeEngine`` at ``slots`` x 1024 made without its state (a
+    described device holds no array), and the dict its ``_plan`` fills:
+    each plan it is asked for is lowered over the ``weights`` shapes
+    under its name, and no further."""
+    from analytics_zoo_tpu.pipeline.inference.decode import DecodeEngine
+    eng = object.__new__(DecodeEngine)
+    eng.capacity, eng.max_len = slots, MAX_LEN
+    eng._hyper, eng._n_layers = dict(hyper), int(hyper["n_layers"])
+    eng._draft_hyper = eng._mesh = None
+    eng._device = device
+    eng._admit_fns = {}
+    lowered = {}
+    eng._plan = lambda name, jitted, specs: lowered.__setitem__(
+        name, jitted.lower(*specs, weights))
+    return eng, lowered
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
 _STEPK = {}
 
 
 @pytest.fixture
 def compiled_stepk(topo, one_chip, on_the_chip, two_layers):
     """``jit_stepk`` (4 steps) as the engine builds it, compiled for the
-    described chip at ``slots`` x 1024, once a module.  The engine is
-    made without its state: a described device holds no array."""
+    described chip at ``slots`` x 1024, once a module."""
     def compiled(slots):
         if slots not in _STEPK:
-            from analytics_zoo_tpu.pipeline.inference.decode import \
-                DecodeEngine
-            eng = object.__new__(DecodeEngine)
-            eng.capacity, eng.max_len = slots, MAX_LEN
-            eng._hyper, eng._n_layers = dict(two_layers.hyper), 2
-            eng._draft_hyper = eng._mesh = None
-            eng._device = topo.devices[0]
-            weights = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=one_chip),
-                (two_layers.trainer.state.params, None))
-            plan = {}
-            eng._plan = lambda name, jitted, specs: plan.update(
-                name=name, lowered=jitted.lower(*specs, weights))
+            eng, lowered = _engine_without_state(
+                two_layers.hyper, slots, topo.devices[0], _abstract(
+                    (two_layers.trainer.state.params, None), one_chip))
             eng._build_stepk_plan(4)
-            assert plan["name"] == "step4"
-            _STEPK[slots] = plan["lowered"].compile()
+            [(name, plan)] = lowered.items()
+            assert name == "step4"
+            _STEPK[slots] = plan.compile()
         return _STEPK[slots]
     return compiled
 
@@ -351,3 +364,78 @@ def test_fused_step_plan_sorts_in_one_branch_of_a_conditional(
     assert home == sorted_
     assert not any("cumsum" in line or "top_k" in line
                    for line in comps[argmax])
+
+
+# ------------------------------------- the benchmark's cells, whole model
+def _gpt2_medium():
+    """The benchmark's configuration at its published depth and widths
+    (read from its file), as ``benchmark/adapters/gpt2.py`` builds it,
+    and its parameters and model state as shapes: nothing is
+    allocated."""
+    import json
+    from analytics_zoo_tpu.models import TransformerLM
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "gpt2-medium.json")) as f:
+        cfg = json.load(f)
+    lm = TransformerLM(
+        vocab_size=cfg["vocab_size"], seq_len=cfg["n_positions"],
+        max_len=cfg["n_positions"], n_layers=cfg["n_layer"],
+        d_model=cfg["n_embd"], n_heads=cfg["n_head"],
+        d_ff=4 * cfg["n_embd"], dropout=cfg["resid_pdrop"])
+    assert (lm.hyper["n_layers"], lm.hyper["d_model"], lm.hyper["n_heads"],
+            lm.hyper["d_ff"], lm.hyper["vocab_size"]) \
+        == (24, HEADS * D_HEAD, HEADS, 4096, 50257)
+    params, model_state = jax.eval_shape(
+        lambda key: lm.to_graph().init(key), jax.random.PRNGKey(0))
+    # 406.2 M: the published 354.8 M and an untied head with its bias
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree_util.tree_leaves(params)) == 406_238_289
+    return lm, params, model_state
+
+
+def _module_name(lowered):
+    return lowered.as_text().split("module @", 1)[1].split(" ", 1)[0]
+
+
+def test_pretrain_cell_train_step_lowers_at_published_widths(
+        one_chip, on_the_chip):
+    """``gpt2m-pretrain-1k``'s step, whole: 24 layers, 4 microbatches of
+    4 x 1024 tokens scanned in one program, bf16 compute over f32 master
+    weights and Adam, the flash kernels lowered for the described chip.
+    Only that it lowers, and under the name a profile goes by."""
+    from analytics_zoo_tpu.observability import profile
+    from analytics_zoo_tpu.train.trainer import build_train_step
+    lm, params, model_state = _gpt2_medium()
+    lm.compile("adam", "class_nll", seed=0, compute_dtype=jnp.bfloat16)
+    tr = lm.trainer
+    opt_state = jax.eval_shape(tr.optimizer.init, params)
+    rows = jax.ShapeDtypeStruct((4, 4, MAX_LEN), jnp.int32)
+    operands = _abstract((params, model_state, opt_state,
+                          jax.random.PRNGKey(0), rows, rows), one_chip)
+    step = build_train_step(tr.model, tr.loss_fn, tr.optimizer,
+                            compute_dtype=jnp.bfloat16, accum_steps=4)
+    lowered = step.lower(*operands)
+    assert _module_name(lowered) == profile.PROGRAM_TRAIN_STEP
+    text = lowered.as_text()
+    for kernel in (profile.KERNEL_FLASH_FWD, profile.KERNEL_FLASH_BWD_DQ,
+                   profile.KERNEL_FLASH_BWD_DKV):
+        assert kernel in text, kernel
+
+
+def test_chat_cell_plans_lower_at_published_widths(topo, one_chip,
+                                                   on_the_chip):
+    """``gpt2m-chat-closed``'s engine, whole: 24 layers, 16 slots x 1024,
+    the admit plan of its 128-token bucket and the fused window of 4
+    steps.  Only that they lower, and under the names a profile goes
+    by."""
+    from analytics_zoo_tpu.observability import profile
+    lm, params, _ = _gpt2_medium()
+    eng, lowered = _engine_without_state(
+        lm.hyper, 16, topo.devices[0], _abstract((params, None), one_chip))
+    eng._admit_fn_for(128)
+    eng._build_stepk_plan(4)
+    assert {name: _module_name(plan) for name, plan in lowered.items()} \
+        == {"admit128": profile.PROGRAM_ADMIT,
+            "step4": profile.PROGRAM_STEPK}
+    assert profile.KERNEL_DECODE_ATTN in lowered["step4"].as_text()

@@ -168,6 +168,19 @@ def test_grad_accum_phase_attributed_in_profiler():
     assert all("grad_accum_ms" in e for e in prof.timeline())
 
 
+def test_sharded_fit_compiles_its_step_once():
+    """Two epochs under fsdp with accumulation: exactly one XLA compile
+    lands in the profiled steps.  The sharded layout never re-traces or
+    reshards a step (the second epoch runs the first one's executable,
+    and the state the step returns goes back in as it is)."""
+    mesh = _mesh({"data": 1, "fsdp": 2})
+    t = _trainer(mesh, strategy="fsdp", accum_steps=2)
+    prof = t.enable_step_profiler()
+    t.fit(_dataset(), batch_size=32, end_trigger=triggers.MaxEpoch(2))
+    assert prof.steps == 4
+    assert prof.compiles == 1
+
+
 # -------------------------------------------------------------- bf16
 
 
