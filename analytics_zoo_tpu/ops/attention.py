@@ -133,35 +133,118 @@ def blockwise_attention(q, k, v, causal: bool = False,
 
 
 # ------------------------------------------------------------ pallas kernel
+#
+# The three kernels tile the (query, key) plane in (block_q, block_k)
+# tiles and visit, for a causal call, only the tiles that hold a
+# position on or below the diagonal ``q_pos = i + (sk - sq) >= k_pos``.
+# Which tiles those are is arithmetic on block indices, the same for a
+# Python int (``_flash_tile_counts``, the static account) and for a
+# traced ``program_id`` (the kernels' loop bounds): ``_row_tiles`` and
+# ``_col_tiles`` are the one place it is written.  Every visited tile
+# of a causal or length-masked call builds its mask: running a bare
+# body on the tiles wholly below the diagonal was measured and gave
+# nothing (under 2 % at s 4096, where 28 of 36 tiles are such; PERF.md
+# §6, PR 35), so there is one body.
+#
+# Every kernel holds its tile TRANSPOSED, (block_k, block_q) = k·qᵀ:
+# keys along sublanes, queries along lanes.  The softmax statistics (m,
+# l, lse, Δ) are then one lane-dense row a q block, as lse and Δ are
+# stored: a reduction over keys is elementwise across vregs, a
+# broadcast over keys is a sublane broadcast, and the rescaling of the
+# statistics by exp(m_prev − m_new) touches block_q / 128 vregs instead
+# of block_q / 8.  With queries along sublanes (the textbook layout,
+# measured at the same 512 x 512 blocks: forward 466 us a call against
+# 259, dq 384 against 307) every tile paid two cross-lane reductions
+# and two lane broadcasts a row group, which made small tiles slower
+# than one 1024-wide tile that skips nothing.
 
-def _score_mask(scores, causal, lens_val, qi, j, block_q, block_k, sq, sk):
-    """Compose the causal and key-padding masks onto one score block.
-    ``lens_val`` is this (batch·head)'s valid key count (f32 scalar) or
-    None when the call has no padding mask."""
-    valid = None
-    if causal or lens_val is not None:
-        k_pos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-    if causal:
-        q_pos = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0) + (sk - sq)
-        valid = q_pos >= k_pos
+def _row_tiles(qi, block_q: int, block_k: int, n_kblocks: int, off: int,
+               causal: bool):
+    """Key tiles of q block ``qi`` (forward and dq walk a row of tiles):
+    tiles ``[0, n)`` hold a visible position, the rest lies above the
+    diagonal and is skipped.  ``off`` is ``sk - sq``, where the diagonal
+    sits."""
+    if not causal:
+        return n_kblocks
+    # the block's last row sees keys up to (qi + 1)·block_q − 1 + off
+    n = ((qi + 1) * block_q - 1 + off) // block_k + 1
+    return min(n, n_kblocks) if isinstance(n, int) \
+        else jnp.minimum(n, n_kblocks)
+
+
+def _col_tiles(kj, block_q: int, block_k: int, off: int, causal: bool):
+    """Query tiles of k block ``kj`` (dkv walks a column of tiles):
+    the first tile that holds a visible position; those before it lie
+    above the diagonal and are skipped."""
+    if not causal:
+        return 0
+    # first q block whose LAST row reaches this key block:
+    # i·block_q + block_q − 1 + off ≥ kj·block_k
+    first = (kj * block_k - off) // block_q
+    return max(first, 0) if isinstance(first, int) \
+        else jnp.maximum(first, 0)
+
+
+def _row_end(qi, lens_val, block_q, block_k, sq, sk, causal):
+    """``_row_tiles`` under a valid key count: key blocks entirely past
+    the length are skipped too."""
+    n = _row_tiles(qi, block_q, block_k, sk // block_k, sk - sq, causal)
     if lens_val is not None:
-        kmask = k_pos.astype(jnp.float32) < lens_val
+        n = jnp.minimum(n, jnp.ceil(lens_val / block_k).astype(jnp.int32))
+    return n
+
+
+def _fold_scale(x, scale: float):
+    """``(x * scale, 1.0)`` when that is exact in ``x``'s dtype (a power
+    of two, as 1/8 at d_head 64), so the softmax scale is paid on a
+    (block, d) operand once a grid cell; else ``(x, scale)`` and the
+    scale stays on every score tile."""
+    if math.frexp(scale)[0] == 0.5:
+        return (x.astype(jnp.float32) * scale).astype(x.dtype), 1.0
+    return x, scale
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _score_tile(k_blk, q_blk, post: float, causal: bool, q_pos0, k0,
+                lens_val):
+    """One transposed score tile ``k·qᵀ`` (keys along axis 0), scaled by
+    what ``_fold_scale`` left over and masked: causal where asked, key
+    padding where ``lens_val`` (the valid key count, f32) is given.
+    The tile's place enters as two scalars: ``q_pos0``, the diagonal
+    position of its first query, and ``k0``, its first key."""
+    st = _dot(k_blk, q_blk, _NT)
+    if post != 1.0:
+        st = st * post
+    if not causal and lens_val is None:
+        return st
+    k_iota = lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    valid = None
+    if causal:
+        q_iota = lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        valid = q_iota - k_iota >= k0 - q_pos0
+    if lens_val is not None:
+        kmask = k_iota.astype(jnp.float32) < lens_val - k0
         valid = kmask if valid is None else valid & kmask
-    if valid is None:
-        return scores
-    return jnp.where(valid, scores, NEG_INF)
+    return jnp.where(valid, st, NEG_INF)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
                       sk: int, causal: bool, sq: int, scale: float,
                       block_q: int, masked: bool):
-    """One (batch·head, q-block) cell: iterate key blocks in VMEM with
-    online softmax.  Matmuls run at the INPUT dtype (bf16 on the MXU's
-    native rate) with f32 accumulation via ``preferred_element_type`` —
-    casting inputs up to f32 first (the round-2 version) forfeited ~4× of
-    MXU throughput.  Softmax statistics stay f32 for stability.
+    """One (batch·head, q-block) cell: walk the key tiles of this row
+    of the (q, k) plane, K and V whole in VMEM, with online softmax.
+    Matmuls run at the INPUT dtype (bf16 on the MXU's native rate) with
+    f32 accumulation via ``preferred_element_type``.  Softmax
+    statistics stay f32 for stability.  The output accumulates
+    transposed, (d, block_q), and is turned once a cell.
 
     Also writes the row logsumexp (``lse_ref``, (1, block_q) f32) — the
     residual the custom-VJP backward kernels replay the softmax from
@@ -175,160 +258,118 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
         lens_val = lens_ref[0, 0]
     else:
         (o_ref, lse_ref), lens_val = rest, None
-    q = q_ref[...]  # (block_q, d), input dtype
+    q, post = _fold_scale(q_ref[...], scale)  # (block_q, d), input dtype
     qi = pl.program_id(1)
-    n_kblocks = sk // block_k
+    d = q.shape[-1]
 
-    def body(j, carry):
-        m_prev, l_prev, o_prev = carry
-        k_blk = k_ref[pl.dslice(j * block_k, block_k), :]
-        v_blk = v_ref[pl.dslice(j * block_k, block_k), :]
-        scores = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        scores = _score_mask(scores, causal, lens_val, qi, j, block_q,
-                             block_k, sq, sk)
-        m_blk = jnp.max(scores, axis=-1)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(scores - m_new[:, None])
+    def tile(j, carry):
+        m_prev, l_prev, o_prev = carry  # (1, bq), (1, bq), (d, bq)
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k_blk = k_ref[pl.ds(k0, block_k), :]
+        v_blk = v_ref[pl.ds(k0, block_k), :]
+        st = _score_tile(k_blk, q, post, causal, qi * block_q + sk - sq,
+                         k0, lens_val)          # (block_k, block_q)
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1)
-        pv = lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_new = o_prev * corr[:, None] + pv
+        l_new = l_prev * corr + jnp.sum(pt, axis=0, keepdims=True)
+        o_new = o_prev * corr + _dot(v_blk, pt.astype(v_blk.dtype), _TN)
         return m_new, l_new, o_new
 
-    d = q.shape[-1]
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    o0 = jnp.zeros((block_q, d), jnp.float32)
-    if causal:
-        # skip key blocks strictly after this q block's last position
-        last_q = (qi + 1) * block_q - 1 + (sk - sq)
-        n_iter = jnp.minimum(last_q // block_k + 1, n_kblocks)
-    else:
-        n_iter = n_kblocks
-    if masked:
-        # skip key blocks entirely past the valid length
-        n_valid = jnp.ceil(lens_val / block_k).astype(jnp.int32)
-        n_iter = jnp.minimum(n_iter, n_valid)
-    m, l, o = lax.fori_loop(0, n_iter, body, (m0, l0, o0))
+    m, l, o = lax.fori_loop(
+        0, _row_end(qi, lens_val, block_q, block_k, sq, sk, causal), tile,
+        (jnp.full((1, block_q), NEG_INF, jnp.float32),
+         jnp.zeros((1, block_q), jnp.float32),
+         jnp.zeros((d, block_q), jnp.float32)))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :] = m + jnp.log(l_safe)
+    o_ref[...] = (o / l_safe).T.astype(o_ref.dtype)
+    lse_ref[...] = m + jnp.log(l_safe)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          *rest, block_k: int, sk: int, causal: bool,
                          sq: int, scale: float, block_q: int,
                          masked: bool):
-    """dq for one (batch·head, q-block) cell.  Replays the softmax from
-    the saved logsumexp (p = exp(s - lse), exact — no renormalization
-    pass), then dq += (p ∘ (do·vᵀ − Δ)) · k per key block, where
-    Δ = rowsum(do ∘ o) is precomputed outside the kernel."""
+    """dq for one (batch·head, q-block) cell, over the same row of
+    tiles as the forward.  Replays the softmax from the saved logsumexp
+    (p = exp(s - lse), exact — no renormalization pass), then
+    dqᵀ += kᵀ · (pᵀ ∘ (v·doᵀ − Δ)) per key tile, where Δ = rowsum(do ∘ o)
+    is precomputed outside the kernel; the softmax scale multiplies the
+    (d, block_q) sum once."""
     if masked:
         lens_ref, dq_ref = rest
         lens_val = lens_ref[0, 0]
     else:
         (dq_ref,), lens_val = rest, None
-    q = q_ref[...]
+    q, post = _fold_scale(q_ref[...], scale)
     do = do_ref[...]
-    lse = lse_ref[0, :]      # (block_q,) f32
-    delta = delta_ref[0, :]  # (block_q,) f32
+    lse = lse_ref[...]      # (1, block_q) f32
+    delta = delta_ref[...]  # (1, block_q) f32
     qi = pl.program_id(1)
-    n_kblocks = sk // block_k
     d = q.shape[-1]
 
-    def body(j, dq_acc):
-        k_blk = k_ref[pl.dslice(j * block_k, block_k), :]
-        v_blk = v_ref[pl.dslice(j * block_k, block_k), :]
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _score_mask(s, causal, lens_val, qi, j, block_q, block_k,
-                        sq, sk)
-        p = jnp.exp(s - lse[:, None])  # masked scores underflow to 0
-        dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq_acc + lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(j, dq_acc):
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k_blk = k_ref[pl.ds(k0, block_k), :]
+        v_blk = v_ref[pl.ds(k0, block_k), :]
+        st = _score_tile(k_blk, q, post, causal, qi * block_q + sk - sq,
+                         k0, lens_val)
+        pt = jnp.exp(st - lse)          # masked scores underflow to 0
+        dst = pt * (_dot(v_blk, do, _NT) - delta)
+        return dq_acc + _dot(k_blk, dst.astype(k_blk.dtype), _TN)
 
-    if causal:
-        last_q = (qi + 1) * block_q - 1 + (sk - sq)
-        n_iter = jnp.minimum(last_q // block_k + 1, n_kblocks)
-    else:
-        n_iter = n_kblocks
-    if masked:
-        n_valid = jnp.ceil(lens_val / block_k).astype(jnp.int32)
-        n_iter = jnp.minimum(n_iter, n_valid)
-    dq = lax.fori_loop(0, n_iter, body,
-                       jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dq = lax.fori_loop(
+        0, _row_end(qi, lens_val, block_q, block_k, sq, sk, causal), tile,
+        jnp.zeros((d, block_q), jnp.float32))
+    dq_ref[...] = (dq * scale).T.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           *rest, block_q: int, sq: int,
                           causal: bool, sk: int, scale: float,
                           block_k: int, masked: bool):
-    """dk/dv for one (batch·head, k-block) cell: iterate q blocks (full-
-    sequence q/do refs resident in VMEM), accumulating dv += pᵀ·do and
-    dk += dsᵀ·q.  Causality skips q blocks entirely before this key
-    block (start index), mirroring the forward's key-block skip.
-    Padding-masked key blocks need no skip: their replayed p underflows
-    to exactly 0, so dk/dv of padded keys come out zero."""
+    """dk/dv for one (batch·head, k-block) cell: walk the q tiles of
+    this COLUMN of the (q, k) plane (full-sequence q/do/lse/Δ refs
+    resident in VMEM), accumulating dv += pᵀ·do and dk += dsᵀ·q: pᵀ
+    and dsᵀ are the left operands of plain matmuls.  Causality skips q
+    blocks entirely before this key block (start index), mirroring the
+    forward's key-block skip.  Padding-masked keys need no more than
+    the mask: their replayed p underflows to exactly 0, so dk/dv of
+    padded keys come out zero; a key block entirely past the valid
+    length is written as zeros without iterating."""
     if masked:
         lens_ref, dk_ref, dv_ref = rest
         lens_val = lens_ref[0, 0]
     else:
         (dk_ref, dv_ref), lens_val = rest, None
-    k_blk = k_ref[...]
+    k_blk, post = _fold_scale(k_ref[...], scale)
     v_blk = v_ref[...]
     kj = pl.program_id(1)
-    n_qblocks = sq // block_q
     d = k_blk.shape[-1]
+    k0 = kj * block_k
+    start = _col_tiles(kj, block_q, block_k, sk - sq, causal)
+    end = sq // block_q
+    if masked:
+        end = jnp.where(k0 >= lens_val, start, end)
 
-    def body(i, carry):
+    def tile(i, carry):
         dk_acc, dv_acc = carry
-        q_blk = q_ref[pl.dslice(i * block_q, block_q), :]
-        do_blk = do_ref[pl.dslice(i * block_q, block_q), :]
-        lse_blk = lse_ref[0, pl.dslice(i * block_q, block_q)]
-        delta_blk = delta_ref[0, pl.dslice(i * block_q, block_q)]
-        s = lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _score_mask(s, causal, lens_val, i, kj, block_q, block_k,
-                        sq, sk)
-        p = jnp.exp(s - lse_blk[:, None])
-        dv_acc = dv_acc + lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk[:, None]) * scale
-        dk_acc = dk_acc + lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        q0 = pl.multiple_of(i * block_q, block_q)
+        q_blk = q_ref[pl.ds(q0, block_q), :]
+        do_blk = do_ref[pl.ds(q0, block_q), :]
+        lse_row = lse_ref[:, pl.ds(q0, block_q)]        # (1, block_q)
+        delta_row = delta_ref[:, pl.ds(q0, block_q)]
+        st = _score_tile(k_blk, q_blk, post, causal, q0 + sk - sq, k0,
+                         lens_val)                      # (block_k, block_q)
+        pt = jnp.exp(st - lse_row)
+        dv_acc = dv_acc + _dot(pt.astype(do_blk.dtype), do_blk, _NN)
+        dst = pt * (_dot(v_blk, do_blk, _NT) - delta_row)
+        dk_acc = dk_acc + _dot(dst.astype(q_blk.dtype), q_blk, _NN)
         return dk_acc, dv_acc
 
-    if causal:
-        # first q block whose LAST row reaches this key block:
-        # i·block_q + block_q − 1 + (sk − sq) ≥ kj·block_k
-        start = jnp.maximum(0, (kj * block_k - (sk - sq)) // block_q)
-    else:
-        start = 0
-    end = n_qblocks
-    if masked:
-        # a key block entirely past the valid length contributes zero
-        # dk/dv — write the zeros without iterating (fwd/dq skip's dual)
-        end = jnp.where(kj * block_k >= lens_val, start, end)
     z = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(start, end, body, (z, z))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dk, dv = lax.fori_loop(start, end, tile, (z, z))
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
@@ -340,42 +381,119 @@ def _mega(interpret: bool) -> dict:
         dimension_semantics=("parallel", "arbitrary"))}
 
 
+# Each call is a ``jax.jit`` of its own with the blocks static, as
+# ``_decode_attn_call`` is: a model's layers call it with the same
+# shapes, so the kernel is traced and lowered once a program, not once
+# a layer.
+#
+# per-row statistics (lse; lens/delta in the backward) carry an
+# explicit singleton dim — (bh, 1, sq) blocked (None, 1, block_q) —
+# because TPU lowering requires each of a block's minor two dims to
+# be tile-divisible (8/128) OR equal to the full array dim.  A 2-D
+# (bh, sq) stat blocked (1, block_q) puts a size-1 sublane against
+# bh and cannot lower (caught on the first live-chip run of the
+# custom-VJP path, r5).
+
+def _row_spec(block, d):
+    """A (block, d) tile of a (bh, s, d) array, by grid cell."""
+    return pl.BlockSpec((None, block, d), lambda i, j: (i, j, 0))
+
+
+def _whole_spec(s, d):
+    """A head's whole (s, d) (or (1, s)) array, resident across the
+    cell's second grid axis."""
+    return pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0))
+
+
+def _stat_spec(block):
+    return pl.BlockSpec((None, 1, block), lambda i, j: (i, 0, j))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sq", "sk", "causal", "masked", "block_q", "block_k", "scale",
+    "interpret"))
 def _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
                     block_k, scale, interpret):
     bh, _, d = qf.shape
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k, sk=sk,
                                causal=causal, sq=sq, scale=scale,
                                block_q=block_q, masked=masked)
-    in_specs = [
-        pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-    ]
+    in_specs = [_row_spec(block_q, d), _whole_spec(sk, d),
+                _whole_spec(sk, d)]
     args = [qf, kf, vf]
     if masked:
-        in_specs.append(pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0)))
+        in_specs.append(_whole_spec(1, 1))
         args.append(lens)
-    # per-row statistics (lse; lens/delta in the backward) carry an
-    # explicit singleton dim — (bh, 1, sq) blocked (None, 1, block_q) —
-    # because TPU lowering requires each of a block's minor two dims to
-    # be tile-divisible (8/128) OR equal to the full array dim.  A 2-D
-    # (bh, sq) stat blocked (1, block_q) puts a size-1 sublane against
-    # bh and cannot lower (caught on the first live-chip run of the
-    # custom-VJP path, r5).
     return pl.pallas_call(
         kernel,
         grid=(bh, sq // block_q),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda i, j: (i, 0, j)),
-        ],
+        out_specs=[_row_spec(block_q, d), _stat_spec(block_q)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=interpret,
         name=_profile.KERNEL_FLASH_FWD,
+        **_mega(interpret),
+    )(*args)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sq", "sk", "causal", "masked", "block_q", "block_k", "scale",
+    "interpret"))
+def _flash_bwd_dq_call(qf, kf, vf, do, lse, delta, lens, sq, sk, causal,
+                       masked, block_q, block_k, scale, interpret):
+    bh, _, d = qf.shape
+    kernel = functools.partial(
+        _flash_bwd_dq_kernel, block_k=block_k, sk=sk, causal=causal, sq=sq,
+        scale=scale, block_q=block_q, masked=masked)
+    in_specs = [_row_spec(block_q, d), _whole_spec(sk, d),
+                _whole_spec(sk, d), _row_spec(block_q, d),
+                _stat_spec(block_q), _stat_spec(block_q)]
+    args = [qf, kf, vf, do, lse, delta]
+    if masked:
+        in_specs.append(_whole_spec(1, 1))
+        args.append(lens)
+    return pl.pallas_call(
+        kernel,
+        grid=(bh, sq // block_q),
+        in_specs=in_specs,
+        out_specs=_row_spec(block_q, d),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
+        interpret=interpret,
+        name=_profile.KERNEL_FLASH_BWD_DQ,
+        **_mega(interpret),
+    )(*args)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sq", "sk", "causal", "masked", "block_q", "block_k", "scale",
+    "interpret"))
+def _flash_bwd_dkv_call(qf, kf, vf, do, lse, delta, lens, sq, sk, causal,
+                        masked, block_q, block_k, scale, interpret):
+    bh, _, d = qf.shape
+    kernel = functools.partial(
+        _flash_bwd_dkv_kernel, block_q=block_q, sq=sq, causal=causal,
+        sk=sk, scale=scale, block_k=block_k, masked=masked)
+    in_specs = [_whole_spec(sq, d), _row_spec(block_k, d),
+                _row_spec(block_k, d), _whole_spec(sq, d),
+                _whole_spec(1, sq), _whole_spec(1, sq)]
+    args = [qf, kf, vf, do, lse, delta]
+    if masked:
+        in_specs.append(_whole_spec(1, 1))
+        args.append(lens)
+    return pl.pallas_call(
+        kernel,
+        grid=(bh, sk // block_k),
+        in_specs=in_specs,
+        out_specs=[_row_spec(block_k, d), _row_spec(block_k, d)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
+        ],
+        interpret=interpret,
+        name=_profile.KERNEL_FLASH_BWD_DKV,
         **_mega(interpret),
     )(*args)
 
@@ -393,104 +511,71 @@ def _flash_core(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
     rule), and recomputing through the XLA blockwise path would forfeit
     the kernel's advantage exactly where the training step spends ~2/3 of
     its attention FLOPs."""
-    out, _ = _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked,
-                             block_q, block_k, scale, interpret)
-    return out
+    return _flash_core_fwd(qf, kf, vf, lens, sq, sk, causal, masked,
+                           block_q, block_k, scale, interpret)[0]
 
 
 def _flash_core_fwd(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
                     block_k, scale, interpret):
-    out, lse = _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked,
-                               block_q, block_k, scale, interpret)
+    out, lse = _flash_fwd_call(qf, kf, vf, lens, sq=sq, sk=sk,
+                               causal=causal, masked=masked,
+                               block_q=block_q, block_k=block_k,
+                               scale=scale, interpret=interpret)
     return out, (qf, kf, vf, lens, out, lse)
 
 
 def _flash_core_bwd(sq, sk, causal, masked, block_q, block_k, scale,
                     interpret, res, do):
     qf, kf, vf, lens, out, lse = res
-    bh, _, d = qf.shape
     do = do.astype(qf.dtype)
     # Δ_i = Σ_d do_id·o_id  (= Σ_j p_ij·dp_ij) — cheap elementwise, XLA
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (bh, 1, sq), like lse
-    # backward blocks: q-chunk stays at the forward's (which divides sq
-    # by construction); key-chunk halves when possible — the dkv cell's
-    # (block_q × block_k) f32 p/dp/ds live simultaneously — under the
-    # same tiling rule as the forward's, whose block it keeps otherwise.
-    bwd_bq = block_q
-    bwd_bk = _tiled_block(sk, min(block_k, 512)) or block_k
-
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, block_k=bwd_bk, sk=sk, causal=causal, sq=sq,
-        scale=scale, block_q=bwd_bq, masked=masked)
-    dq_specs = [
-        pl.BlockSpec((None, bwd_bq, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, bwd_bq, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, 1, bwd_bq), lambda i, j: (i, 0, j)),
-        pl.BlockSpec((None, 1, bwd_bq), lambda i, j: (i, 0, j)),
-    ]
-    dq_args = [qf, kf, vf, do, lse, delta]
-    if masked:
-        dq_specs.append(pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0)))
-        dq_args.append(lens)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, sq // bwd_bq),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((None, bwd_bq, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
-        interpret=interpret,
-        name=_profile.KERNEL_FLASH_BWD_DQ,
-        **_mega(interpret),
-    )(*dq_args)
-
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, block_q=bwd_bq, sq=sq, causal=causal,
-        sk=sk, scale=scale, block_k=bwd_bk, masked=masked)
-    dkv_specs = [
-        pl.BlockSpec((None, sq, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, bwd_bk, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, bwd_bk, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((None, sq, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, 1, sq), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, 1, sq), lambda i, j: (i, 0, 0)),
-    ]
-    dkv_args = [qf, kf, vf, do, lse, delta]
-    if masked:
-        dkv_specs.append(pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0)))
-        dkv_args.append(lens)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, sk // bwd_bk),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((None, bwd_bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, bwd_bk, d), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
-        ],
-        interpret=interpret,
-        name=_profile.KERNEL_FLASH_BWD_DKV,
-        **_mega(interpret),
-    )(*dkv_args)
+    static = dict(sq=sq, sk=sk, causal=causal, masked=masked,
+                  block_q=block_q, block_k=block_k, scale=scale,
+                  interpret=interpret)
+    dq = _flash_bwd_dq_call(qf, kf, vf, do, lse, delta, lens, **static)
+    dk, dv = _flash_bwd_dkv_call(qf, kf, vf, do, lse, delta, lens, **static)
     return dq, dk, dv, jnp.zeros_like(lens)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
-                    block_k: int = 1024, scale: float = None,
+def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
+                    block_k: int = 512, scale: float = None,
                     interpret: bool = False, layout: str = "bshd",
                     kv_lengths=None):
     """Pallas TPU flash attention.
 
-    Default block caps (q 256 × k 1024) date from an earlier round's
-    v5e tuning; they have not been re-measured on today's code.
+    ``block_q`` / ``block_k`` are CAPS: each kernel's blocks are the
+    largest multiples of 128 under them that divide the (padded)
+    lengths (``_flash_plan``), the same pair for forward, dq and dkv.
+    The defaults are measured (PR 35, one v5e; a kernel alone, 16
+    chained calls a program, which reads about a tenth above a device
+    trace; us a call; ``block_q x block_k``):
+
+    ======================================  ====  ====  ====
+    64 heads x 1024 x 64, bf16, causal       fwd    dq   dkv
+    ======================================  ====  ====  ====
+    512 x 512 (3 of 4 tiles)                 259   307   459
+    512 x 256                                311   347   447
+    1024 x 512 (no tile skipped)             279   326   526
+    1024 x 1024                              294   320   481
+    256 x 512                                392   382   572
+    256 x 256 (10 of 16 tiles)               437   409   561
+    128 x 128 (36 of 64 tiles)                 —     —   847
+    queries along sublanes, 512 x 512        466   384     —
+    the parent's kernels, 256 x 1024 / 512   476   903 (dq + dkv)
+    ======================================  ====  ====  ====
+
+    A tile costs a fixed amount beside its area, so 256-blocks lose to
+    512-blocks although they skip more of the causal square, and a
+    1024-wide block that skips nothing comes second.  The chat
+    engine's float32 prefill (16 heads, forward only; the parent's
+    kernel at its plan in brackets): s 128: 128 x 128 50 us [45];
+    s 256: 256 x 256 49 [55]; s 512: 512 x 512 59, 256 x 256 78 [79];
+    s 768: 384 x 384 93, 768 x 768 74, 256 x 256 105 [119].
 
     ``layout`` (VERDICT r3 #8 — the transpose tax):
 
@@ -542,6 +627,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
     if plan is None:
         raise ValueError(why)
     block_q, block_k, pad_q, pad_k = plan
+    _log_tiles(causal, sq + pad_q, sk + pad_k, block_q, block_k)
     if layout == "bshd":
         # fold batch and heads into the grid's first axis — a materialized
         # transpose (see docstring; pass layout="bhsd" to avoid it)
@@ -604,6 +690,22 @@ def _tiled_block(n: int, cap: int) -> int:
     return 0
 
 
+def _flash_tile_counts(causal: bool, sq: int, sk: int, block_q: int,
+                       block_k: int):
+    """The static account of the tiling: ``{kernel: (tiles computed,
+    tiles of the full square)}``, without ``kv_lengths`` (a length only
+    takes tiles away).  Python-int runs of the very ``_row_tiles`` /
+    ``_col_tiles`` the kernels bound their loops by: the forward and dq
+    walk rows of tiles, dkv columns, and the three agree."""
+    n_q, n_k, off = sq // block_q, sk // block_k, sk - sq
+    by_row = sum(_row_tiles(i, block_q, block_k, n_k, off, causal)
+                 for i in range(n_q))
+    by_col = sum(n_q - _col_tiles(j, block_q, block_k, off, causal)
+                 for j in range(n_k))
+    return {"fwd": (by_row, n_q * n_k), "dq": (by_row, n_q * n_k),
+            "dkv": (by_col, n_q * n_k)}
+
+
 # Scoped VMEM the TPU compiler grants one kernel (v5e, libtpu 0.0.34:
 # "limit 16.00M").  Every kernel takes a whole sequence as ONE block —
 # K and V (sk, d) in fwd/dq, Q and dO (sq, d) in dkv — and the pipeline
@@ -616,20 +718,33 @@ def _tiled_block(n: int, cap: int) -> int:
 # and 15 MiB (d 64), and between 13 and 16 MiB the outcome is irregular
 # (what else the kernel keeps varies) — hence a 4 MiB reserve, not a
 # derived figure.  So bf16 runs to sk 12288 and f32 to 6144 (d <= 128).
+# Asked again of the kernels as PR 35 left them (key blocks are still
+# slices of a resident K/V, not a grid axis, so the bound has not
+# moved): the reserve also holds the (block_k, block_q) f32 score tile
+# and its companions, 1 MiB each at 512 x 512, and the pipelined
+# (block, d) row tiles, which ``_ROW_TILE_BYTES`` keeps at 256 KiB; the
+# edges (12288 bf16 and 6144 f32 at d 32..128, 6144 bf16 and 3072 f32 at
+# d 256, causal and not, masked and not) compile for fwd, dq and dkv.
 # Streaming K/V would lift the bound (ROADMAP S5).
 _VMEM_LIMIT = 16 * 2 ** 20
 _VMEM_RESERVE = 4 * 2 ** 20
 _VMEM_BUDGET = _VMEM_LIMIT - _VMEM_RESERVE
+_ROW_TILE_BYTES = 256 * 2 ** 10
+
+
+def _row_bytes(d: int, dtype) -> int:
+    """One row of a (rows, d) block in VMEM: d padded to the 128-lane
+    tile."""
+    return -(-d // 128) * 128 * jnp.dtype(dtype).itemsize
 
 
 def _flash_resident_bytes(sq: int, sk: int, d: int, dtype) -> int:
     """VMEM the whole-sequence blocks pin (see ``_VMEM_LIMIT``)."""
-    lanes = -(-d // 128) * 128
-    return 4 * max(sq, sk) * lanes * jnp.dtype(dtype).itemsize
+    return 4 * max(sq, sk) * _row_bytes(d, dtype)
 
 
 def _flash_plan(causal: bool, sq: int, sk: int, d: int, dtype,
-                cap_q: int = 256, cap_k: int = 1024):
+                cap_q: int = 512, cap_k: int = 512):
     """The static block/pad decision for one shape: returns
     ``((block_q, block_k, pad_q, pad_k), None)``, or ``(None, reason)``
     when the kernels cannot run it.  The single source of eligibility:
@@ -646,6 +761,14 @@ def _flash_plan(causal: bool, sq: int, sk: int, d: int, dtype,
             f"causal flash attention needs sq <= sk (got sq={sq}, "
             f"sk={sk}): rows before the first key are fully masked — "
             "use blockwise/naive attention")
+    # a (block, d) tile of q, o, do (k, v, dk, dv in dkv) is pipelined
+    # in two buffers beside the whole-sequence blocks: at d 256 in f32
+    # a 512-row tile is 512 KiB and the forward ran out of VMEM at the
+    # bound below; 256 KiB (512 rows up to d 128 in f32, d 256 in bf16)
+    # compiled in every probe
+    rows = _ROW_TILE_BYTES // _row_bytes(d, dtype)
+    cap_q, cap_k = (min(cap, max(128, rows - rows % 128))
+                    for cap in (cap_q, cap_k))
     block_q, block_k = _tiled_block(sq, cap_q), _tiled_block(sk, cap_k)
     pad_q = pad_k = 0
     if not (block_q and block_k):
@@ -700,6 +823,16 @@ def _log_auto_fallback(causal, sq, sk, d, dtype_name, why):
     the once), never discovered by catching the compiler."""
     _slog.info("flash_ineligible_auto_blockwise", causal=causal, sq=sq,
                sk=sk, d=d, dtype=dtype_name, reason=why)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_tiles(causal, sq, sk, block_q, block_k):
+    """Which tiles the three kernels run is a static decision on shapes
+    too — said once per shape, as ``(computed, of the square)`` a
+    kernel."""
+    _slog.info("flash_tiles", causal=causal, sq=sq, sk=sk, block_q=block_q,
+               block_k=block_k,
+               **_flash_tile_counts(causal, sq, sk, block_q, block_k))
 
 
 def _on_tpu() -> bool:

@@ -125,10 +125,29 @@ def test_prompt_bucket_prefill_compiles(one_chip):
     assert calls == {"fwd": 1}
 
 
+@pytest.mark.parametrize("bucket,plan", [
+    (128, (128, 128, 0, 0)), (256, (256, 256, 0, 0)),
+    (512, (512, 512, 0, 0)), (768, (384, 384, 0, 0)),
+])
+def test_chat_cell_prefill_buckets_compile(one_chip, bucket, plan):
+    """``gpt2m-chat-closed``'s admit plans prefill (1, 16 heads, bucket,
+    64) float32 prompts, causal, forward only: one custom call, under
+    the forward kernel's name."""
+    from analytics_zoo_tpu.observability import profile
+    assert A._flash_plan(True, bucket, bucket, 64, jnp.float32)[0] == plan
+    x = jax.ShapeDtypeStruct((1, 16, bucket, 64), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(lambda q, k, v: A.flash_attention(
+        q, k, v, causal=True, layout="bhsd")).lower(x, x, x) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert profile.KERNEL_FLASH_FWD in text
+
+
 @pytest.mark.parametrize("s,plan", [
-    (320, (128, 384, 64, 64)),     # no 128-multiple divisor: pad to 384
-    (640, (128, 640, 0, 0)),       # 5 x 128
-    (1000, (256, 1024, 24, 24)),   # pad to 1024
+    (320, (384, 384, 64, 64)),     # no 128-multiple divisor: pad to 384
+    (640, (128, 128, 0, 0)),       # 5 x 128: no larger block divides it
+    (1000, (512, 512, 24, 24)),    # pad to 1024
 ])
 def test_dispatcher_choice_compiles_for_awkward_lengths(one_chip, s, plan):
     """What ``auto`` sends to the kernel must compile: the eligibility
