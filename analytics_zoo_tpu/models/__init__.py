@@ -1,6 +1,7 @@
 from .common import ZooModel, register_zoo_model
 from .textclassification import TextClassifier
 from .textgeneration import TransformerLM
+from .commandaplus import CommandAPlusLM
 from .recommendation import (Recommender, NeuralCF, WideAndDeep,
                              UserItemFeature, UserItemPrediction,
                              ColumnFeatureInfo)

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from types import SimpleNamespace
 from typing import Optional
 
 import numpy as np
@@ -28,8 +29,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..observability import profile as _profile
-from ..ops.attention import (attention_bhsd, decode_attention, kv_heads,
-                             kv_pad, kv_rows, kv_write_row)
+from ..ops.attention import (attention_bhsd, decode_attention,
+                             decode_read_block, kv_heads, kv_insert, kv_pad,
+                             kv_rows, kv_write_row)
 from ..parallel.expert import MoEParams, expert_capacity, switch_moe
 from ..pipeline.api.keras.activations import get as get_activation
 
@@ -257,6 +259,75 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
         x = x + _mlp(bp, f)
         tail_caches.append((kv_rows(k), kv_rows(v)))
     return x, tail_caches
+
+
+# ----------------------------------------------------- the family seam
+#
+# The decode engine serves more than one decoder family.  It asks the
+# model's family for its functions here instead of importing
+# TransformerLM's: ``embed(params, tok, pos)``, ``prefill(params, hyper,
+# prompt, cache_len)``, ``decode_step(params, hyper, caches, x_tok, pos,
+# mesh)`` and ``head(params, hyper, hidden)``, plus how the family lays
+# out its cache: ``slab_dims(hyper, capacity, max_len)`` (one ``(capacity,
+# rows, heads, d_head)`` a layer), ``slab_dtype(params)``, ``insert(hyper,
+# caches, prompt_caches, slot, length)`` and ``kv_kinds(hyper, capacity,
+# max_len, dtype)`` (``(rows, read block, layers counted)`` of each kind
+# of slab).  TransformerLM's are the functions above, untouched and
+# called as they always were, so its plans lower to the same programs;
+# a family that needs more (slabs of two kinds, a step that hands back
+# the chosen experts) brings a module of its own and registers its
+# namespace here: this module names no other family.
+
+def _slab_dims(hyper, capacity, max_len):
+    """Every layer alike: ``max_len`` rows of ``n_heads`` heads."""
+    n_heads = int(hyper["n_heads"])
+    return [(capacity, max_len, n_heads, int(hyper["d_model"]) // n_heads)
+            ] * int(hyper["n_layers"])
+
+
+def _insert(hyper, caches, prompt_caches, slot, length):
+    """A prefilled prompt's (padded) rows into slot ``slot``."""
+    return [(kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))
+            for (ck, cv), (pk, pv) in zip(caches, prompt_caches)]
+
+
+def _kv_kinds(hyper, capacity, max_len, dtype):
+    """One kind of slab, and ONE layer of it counted (all are alike)."""
+    return [(max_len, decode_read_block(
+        capacity, max_len, int(hyper["d_model"]), int(hyper["n_heads"]),
+        dtype), 1)]
+
+
+TRANSFORMER_LM = SimpleNamespace(
+    name="transformer_lm", embed=_embed_token, prefill=_prefill,
+    decode_step=_decode_step,
+    head=lambda params, hyper, hidden: _head_logits(params, hidden),
+    slab_dims=_slab_dims, slab_dtype=lambda params: jnp.float32,
+    insert=_insert, kv_kinds=_kv_kinds, routed=False, refuses=())
+
+
+#: family name -> its namespace; a family's module adds itself
+#: (``register_family``) when the model that names it is imported
+FAMILIES = {TRANSFORMER_LM.name: TRANSFORMER_LM}
+
+
+def register_family(family):
+    """A decoder family hands over its generation functions (a
+    namespace as ``TRANSFORMER_LM``'s) under ``family.name``."""
+    FAMILIES[family.name] = family
+    return family
+
+
+def family_of(hyper):
+    """The generation functions of the family a model's ``hyper`` names
+    (``hyper["family"]``; a ``TransformerLM`` names none)."""
+    name = hyper.get("family") or TRANSFORMER_LM.name
+    if name not in FAMILIES:
+        raise ValueError(
+            f"no generation functions for model family {name!r}: the "
+            "module of the model that names it registers them "
+            f"(known: {sorted(FAMILIES)})")
+    return FAMILIES[name]
 
 
 def _sample(logits, rng, temperature, top_k: Optional[int] = None,

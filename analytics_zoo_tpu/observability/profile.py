@@ -67,8 +67,11 @@ KERNEL_FLASH_FWD = "zoo_flash_fwd"
 KERNEL_FLASH_BWD_DQ = "zoo_flash_bwd_dq"
 KERNEL_FLASH_BWD_DKV = "zoo_flash_bwd_dkv"
 KERNEL_DECODE_ATTN = "zoo_decode_attn"
+#: the decode step's attention over a bfloat16 slab whose cached heads
+#: each serve a group of query heads (the ``cohere2_moe`` family)
+KERNEL_DECODE_ATTN_GQA = "zoo_decode_attn_gqa"
 KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV,
-           KERNEL_DECODE_ATTN)
+           KERNEL_DECODE_ATTN, KERNEL_DECODE_ATTN_GQA)
 #: regions inside the jitted programs: ``jax.named_scope``s, which are
 #: HLO metadata (an executable answered from the persistent compilation
 #: cache keeps the metadata of whoever compiled it first), and
@@ -81,9 +84,17 @@ SCOPE_DECODE_ATTENTION = "zoo_decode_attention"
 SCOPE_DECODE_MLP = "zoo_decode_mlp"
 SCOPE_PREFILL = "zoo_prefill"
 SCOPE_SAMPLE = "zoo_sample"
+#: the top-k expert sublayer (ops/moe.py), in step and admit plans: the
+#: whole of it, and inside it the router, the held experts' grouped
+#: products and the shared experts
+SCOPE_MOE = "zoo_moe"
+SCOPE_MOE_ROUTER = "zoo_moe_router"
+SCOPE_MOE_EXPERTS = "zoo_moe_experts"
+SCOPE_MOE_SHARED = "zoo_moe_shared"
 SCOPES = (SCOPE_LOSS, SCOPE_OPTIMIZER_UPDATE, SCOPE_GRAD_ACCUM,
           SCOPE_DECODE_ATTENTION, SCOPE_DECODE_MLP, SCOPE_PREFILL,
-          SCOPE_SAMPLE)
+          SCOPE_SAMPLE, SCOPE_MOE, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
+          SCOPE_MOE_SHARED)
 #: XLA module names of the jitted programs: the trainer's step, the
 #: decode engine's admit / prefix-admit / single step / fused window /
 #: speculative window / prefix-fill plans

@@ -172,6 +172,18 @@ def _row_tiles(qi, block_q: int, block_k: int, n_kblocks: int, off: int,
         else jnp.minimum(n, n_kblocks)
 
 
+def _row_start(qi, block_q: int, block_k: int, off: int, window):
+    """First key tile of q block ``qi`` under a window (query ``i`` sees
+    keys ``j`` with ``i - j < window``): the tiles before it lie wholly
+    outside the window of the block's FIRST row, hence of every row, and
+    are skipped.  0 without a window."""
+    if window is None:
+        return 0
+    first = (qi * block_q + off - window + 1) // block_k
+    return max(first, 0) if isinstance(first, int) \
+        else jnp.maximum(first, 0)
+
+
 def _col_tiles(kj, block_q: int, block_k: int, off: int, causal: bool):
     """Query tiles of k block ``kj`` (dkv walks a column of tiles):
     the first tile that holds a visible position; those before it lie
@@ -214,12 +226,14 @@ def _dot(a, b, dims):
 
 
 def _score_tile(k_blk, q_blk, post: float, causal: bool, q_pos0, k0,
-                lens_val):
+                lens_val, window=None):
     """One transposed score tile ``k·qᵀ`` (keys along axis 0), scaled by
     what ``_fold_scale`` left over and masked: causal where asked, key
     padding where ``lens_val`` (the valid key count, f32) is given.
     The tile's place enters as two scalars: ``q_pos0``, the diagonal
-    position of its first query, and ``k0``, its first key."""
+    position of its first query, and ``k0``, its first key.  ``window``
+    (causal calls) also masks the keys a query has left behind,
+    ``q_pos - k_pos >= window``."""
     st = _dot(k_blk, q_blk, _NT)
     if post != 1.0:
         st = st * post
@@ -230,6 +244,8 @@ def _score_tile(k_blk, q_blk, post: float, causal: bool, q_pos0, k0,
     if causal:
         q_iota = lax.broadcasted_iota(jnp.int32, st.shape, 1)
         valid = q_iota - k_iota >= k0 - q_pos0
+        if window is not None:
+            valid &= q_iota - k_iota < window + (k0 - q_pos0)
     if lens_val is not None:
         kmask = k_iota.astype(jnp.float32) < lens_val - k0
         valid = kmask if valid is None else valid & kmask
@@ -238,7 +254,7 @@ def _score_tile(k_blk, q_blk, post: float, causal: bool, q_pos0, k0,
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
                       sk: int, causal: bool, sq: int, scale: float,
-                      block_q: int, masked: bool):
+                      block_q: int, masked: bool, window=None):
     """One (batch·head, q-block) cell: walk the key tiles of this row
     of the (q, k) plane, K and V whole in VMEM, with online softmax.
     Matmuls run at the INPUT dtype (bf16 on the MXU's native rate) with
@@ -252,7 +268,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
 
     ``masked=True`` adds a per-(batch·head) valid-key-count operand
     (``lens_ref``, (1, 1) f32): keys at positions >= the count are
-    masked, and whole key blocks beyond it are skipped."""
+    masked, and whole key blocks beyond it are skipped.  ``window``
+    (forward only, causal): the walk starts at the first tile that holds
+    a key inside the window (``_row_start``)."""
     if masked:
         lens_ref, o_ref, lse_ref = rest
         lens_val = lens_ref[0, 0]
@@ -268,7 +286,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
         k_blk = k_ref[pl.ds(k0, block_k), :]
         v_blk = v_ref[pl.ds(k0, block_k), :]
         st = _score_tile(k_blk, q, post, causal, qi * block_q + sk - sq,
-                         k0, lens_val)          # (block_k, block_q)
+                         k0, lens_val, window)  # (block_k, block_q)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -277,7 +295,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int,
         return m_new, l_new, o_new
 
     m, l, o = lax.fori_loop(
-        0, _row_end(qi, lens_val, block_q, block_k, sq, sk, causal), tile,
+        _row_start(qi, block_q, block_k, sk - sq, window),
+        _row_end(qi, lens_val, block_q, block_k, sq, sk, causal), tile,
         (jnp.full((1, block_q), NEG_INF, jnp.float32),
          jnp.zeros((1, block_q), jnp.float32),
          jnp.zeros((d, block_q), jnp.float32)))
@@ -409,17 +428,31 @@ def _stat_spec(block):
     return pl.BlockSpec((None, 1, block), lambda i, j: (i, 0, j))
 
 
+def _group_spec(s, d, group: int):
+    """``_whole_spec`` for keys and values that ``group`` consecutive
+    query heads share: head ``i`` of the grid reads array ``i // group``
+    (no copy of K and V a query head; the block stays resident while the
+    index does not change)."""
+    return pl.BlockSpec((None, s, d), lambda i, j: (i // group, 0, 0))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "sq", "sk", "causal", "masked", "block_q", "block_k", "scale",
-    "interpret"))
+    "interpret", "window", "group"))
 def _flash_fwd_call(qf, kf, vf, lens, sq, sk, causal, masked, block_q,
-                    block_k, scale, interpret):
+                    block_k, scale, interpret, window=None, group=1):
+    """``window`` and ``group`` (grouped-query attention: ``qf`` holds
+    ``group`` times the heads of ``kf`` / ``vf``) are the forward's
+    alone; without them the kernel is traced as it always was."""
     bh, _, d = qf.shape
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k, sk=sk,
                                causal=causal, sq=sq, scale=scale,
-                               block_q=block_q, masked=masked)
-    in_specs = [_row_spec(block_q, d), _whole_spec(sk, d),
-                _whole_spec(sk, d)]
+                               block_q=block_q, masked=masked,
+                               **({} if window is None
+                                  else {"window": window}))
+    kv_spec = (_whole_spec(sk, d) if group == 1
+               else _group_spec(sk, d, group))
+    in_specs = [_row_spec(block_q, d), kv_spec, kv_spec]
     args = [qf, kf, vf]
     if masked:
         in_specs.append(_whole_spec(1, 1))
@@ -1332,3 +1365,305 @@ def decode_attention(q, k_new, v_new, ck, cv, pos, n_heads: int,
         in_specs=(rows, rows, rows, slabs, slabs, P(axes)),
         out_specs=(rows, slabs, slabs), check_vma=False)(
             q, k_new, v_new, ck, cv, pos)
+
+
+# ------------------------- grouped queries, a window, a bfloat16 slab
+#
+# The ``cohere2_moe`` family's attention: ``n_heads`` query heads over
+# ``n_kv_heads`` cached heads (query head h reads cached head
+# ``h // group``), causal, on some layers inside a window (query i sees
+# keys j with ``i - j < window``).  Forward only: the prefill runs the
+# flash forward kernel with the key/value block index mapped through
+# the group and the window turned into tile bounds; the decode step has
+# a kernel of its own over a bfloat16 slab whose rows may be a RING.
+
+def rope_interleaved(x, pos, theta: float):
+    """Rotary positions over interleaved pairs (``rope_gptj``): the pair
+    ``(x[2i], x[2i + 1])`` of the last axis turns by ``pos * theta **
+    (-2i / d)``.  ``pos`` broadcasts against ``x.shape[:-1]``.
+    Float32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.asarray(pos, jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def gqa_qkv(p, h, pos, rope_theta=None):
+    """``h (b, s, d_model)`` through ``Wq (d_model, heads, d)``, ``Wk``,
+    ``Wv (d_model, kv_heads, d)`` into ``(b, heads, s, d)`` layout, the
+    products in the weights' dtype with float32 accumulation; ``q`` and
+    ``k`` turned by :func:`rope_interleaved` at ``pos`` (``(s,)`` or
+    ``(b, s)``) where ``rope_theta`` is given (a layer without it has no
+    positions at all).  Returns the three in the weights' dtype: keys
+    are cached as they come out, positions applied."""
+    hb = h.astype(p["Wq"].dtype)
+
+    def proj(w):
+        return jnp.einsum("bse,ehd->bhsd", hb, w,
+                          preferred_element_type=jnp.float32)
+
+    q, k, v = proj(p["Wq"]), proj(p["Wk"]), proj(p["Wv"])
+    if rope_theta is not None:
+        at = jnp.asarray(pos)
+        at = at[None, None, :] if at.ndim == 1 else at[:, None, :]
+        q = rope_interleaved(q, at, rope_theta)
+        k = rope_interleaved(k, at, rope_theta)
+    dt = p["Wq"].dtype
+    return q.astype(dt), k.astype(dt), v.astype(dt)
+
+
+def _attention_gqa_reference(q, k, v, window=None):
+    """The masked softmax in ``jax.numpy`` over ``(b, heads, s, d)``
+    queries and ``(b, kv_heads, s, d)`` keys and values: what runs off
+    the chip and what the kernel is tested against."""
+    b, h, s, d = q.shape
+    g = h // k.shape[1]
+    qg = q.reshape(b, k.shape[1], g, s, d)
+    scores = jnp.einsum("bngsd,bntd->bngst", qg, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen &= i - j < window
+    probs = jax.nn.softmax(jnp.where(seen, scores, NEG_INF), axis=-1)
+    o = jnp.einsum("bngst,bntd->bngsd", probs.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, h, s, d).astype(q.dtype)
+
+
+def attention_gqa_bhsd(q, k, v, window=None, interpret=None):
+    """Causal self-attention of ``(b, heads, s, d)`` queries over
+    ``(b, kv_heads, s, d)`` keys and values, ``heads`` a multiple of
+    ``kv_heads``, inside ``window`` where one is given.  On a TPU, for a
+    shape ``_flash_plan`` admits, the flash forward kernel
+    (``zoo_flash_fwd``) with no copy of K and V a query head and only
+    the tiles that hold a visible position; otherwise the masked softmax
+    in ``jax.numpy``.  ``interpret``: force the kernel (True: in the
+    pallas interpreter), as the tests do."""
+    b, h, s, d = q.shape
+    n_kv = k.shape[1]
+    if h % n_kv:
+        raise ValueError(f"{h} query heads do not divide over {n_kv} "
+                         "key/value heads")
+    plan = _flash_plan(True, s, s, d, q.dtype)[0]
+    run = (_on_tpu() and plan is not None and not plan[2]) \
+        if interpret is None else True
+    if not run:
+        return _attention_gqa_reference(q, k, v, window)
+    block_q, block_k = plan[:2]
+    out, _ = _flash_fwd_call(
+        q.reshape(b * h, s, d), k.reshape(b * n_kv, s, d),
+        v.reshape(b * n_kv, s, d), jnp.zeros((b * h, 1, 1), jnp.float32),
+        sq=s, sk=s, causal=True, masked=False, block_q=block_q,
+        block_k=block_k, scale=1.0 / math.sqrt(d),
+        interpret=bool(interpret), window=window, group=h // n_kv)
+    return out.reshape(b, h, s, d)
+
+
+#: rows a grid step of the grouped decode kernel takes from a slab: the
+#: largest of these that divides the slab's length.  A row is 2 KiB at 8
+#: cached heads of 128, so 512 rows are a 1 MiB transfer a slab.
+_GQA_BLOCKS = (512, 256, 128)
+#: rows of the bfloat16 tile in which the kernel rewrites the new row
+_GQA_ROW_TILE = 16
+
+
+def _decode_gqa_plan(rows: int, n_heads: int, n_kv_heads: int, d_head: int,
+                     dtype):
+    """The row block of the grouped decode kernel for one slab shape, or
+    ``(None, reason)``.  It wants a bfloat16 slab (the 16-row write
+    tile is the bfloat16 one), heads of 128 (a cached head is one lane
+    tile), whole groups, and a length that a block divides."""
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return None, f"the slab is {jnp.dtype(dtype).name}, not bfloat16"
+    if d_head != 128 or n_heads % n_kv_heads:
+        return None, (f"{n_heads} query heads over {n_kv_heads} cached "
+                      f"heads of {d_head}: not whole groups of 128-wide "
+                      "heads")
+    for block in _GQA_BLOCKS:
+        if rows % block == 0:
+            return block, None
+    return None, f"no block of {_GQA_BLOCKS} divides {rows} rows"
+
+
+def decode_gqa_read_block(rows: int, n_heads: int, n_kv_heads: int,
+                          d_head: int, dtype) -> int:
+    """Rows of a slot's slab that one step of
+    :func:`decode_attention_gqa` reads at a time: the kernel's block
+    where the kernel runs, else all ``rows``."""
+    if not _on_tpu():
+        return rows
+    return _decode_gqa_plan(rows, n_heads, n_kv_heads, d_head,
+                            dtype)[0] or rows
+
+
+def _decode_gqa_kernel(row_ref, live_ref, slot_ref, blk_ref, q_ref, kn_ref,
+                       vn_ref, ck_ref, cv_ref, o_ref, cko_ref, cvo_ref,
+                       m_ref, l_ref, acc_ref, *, block: int, n_kv: int,
+                       group: int, d: int, scale: float):
+    """One live row block of one slot; the grid is flat over the live
+    blocks, slot after slot, as ``_decode_attn_kernel``'s.  Per cached
+    head, the group's ``(group, d)`` queries against the block's
+    ``(block, d)`` keys on the MXU, an online softmax whose state
+    (``m``, ``l`` lane-broadcast, ``acc``: a row a query head) starts
+    from the NEW row, which never leaves VMEM.  ``row_ref``: where the
+    new row goes (``pos`` mod the slab's length: a windowed layer's
+    slab is a ring); ``live_ref``: how many rows are live, the new one
+    included.  Row ``row`` of the slab itself is stale (the ring's
+    oldest position, or nothing yet) and masked; the block that holds
+    it rewrites its 16-row tile in place, and the slot's last block
+    hands out the normalised result."""
+    g = pl.program_id(0)
+    s, j = slot_ref[g], blk_ref[g]
+    row, n_live = row_ref[s], live_ref[s]
+    heads = [(slice(h * group, (h + 1) * group), slice(h * d, (h + 1) * d))
+             for h in range(n_kv)]
+
+    @pl.when(j == 0)
+    def _start():
+        for qs, ls in heads:
+            s0 = jnp.sum(q_ref[qs, :].astype(jnp.float32)
+                         * kn_ref[:, ls].astype(jnp.float32),
+                         axis=1, keepdims=True) * scale
+            m_ref[qs, :] = jnp.broadcast_to(s0, (group, d))
+            acc_ref[qs, :] = jnp.broadcast_to(
+                vn_ref[:, ls].astype(jnp.float32), (group, d))
+        l_ref[...] = jnp.ones_like(l_ref)
+
+    idx = j * block + lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    live = (idx < n_live) & (idx != row)
+    for qs, ls in heads:
+        st = jnp.where(live, _dot(q_ref[qs, :], ck_ref[:, ls], _NT) * scale,
+                       NEG_INF)                         # (group, block)
+        m_prev = m_ref[qs, :]
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=1, keepdims=True))
+        p = jnp.exp(st - m_new[:, :1])
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[qs, :] = l_ref[qs, :] * corr + jnp.sum(p, axis=1,
+                                                     keepdims=True)
+        m_ref[qs, :] = m_new
+        acc_ref[qs, :] = acc_ref[qs, :] * corr + _dot(
+            p.astype(cv_ref.dtype), cv_ref[:, ls], _NN)
+
+    @pl.when(j == row // block)
+    def _write():
+        tile = pl.multiple_of(
+            (row % block) // _GQA_ROW_TILE * _GQA_ROW_TILE, _GQA_ROW_TILE)
+        new = (lax.broadcasted_iota(jnp.int32, (_GQA_ROW_TILE, 1), 0)
+               == row % _GQA_ROW_TILE)
+        cko_ref[...] = jnp.where(new, kn_ref[...],
+                                 ck_ref[pl.ds(tile, _GQA_ROW_TILE), :])
+        cvo_ref[...] = jnp.where(new, vn_ref[...],
+                                 cv_ref[pl.ds(tile, _GQA_ROW_TILE), :])
+
+    @pl.when(j == (n_live - 1) // block)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "n_kv_heads",
+                                             "block", "interpret"))
+def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
+                     n_kv_heads: int, block: int, interpret: bool):
+    """The kernel over ``(b, heads * d)`` queries, ``(b, kv_heads * d)``
+    new rows, ``(b, rows, kv_heads * d)`` bfloat16 slabs and ``(b,)``
+    int32 positions.  A jit of its own inside the step, as
+    ``_decode_attn_call`` is: traced and lowered once a program."""
+    b, rows, width = ck.shape
+    d = width // n_kv_heads
+    row = (pos % rows).astype(jnp.int32)
+    n_live = jnp.minimum(pos + 1, rows).astype(jnp.int32)
+    slot_of, blk_of, n_steps = _live_blocks(n_live - 1, block,
+                                            rows // block)
+
+    def mine(*shape):       # one slot's block of a per-slot operand
+        return pl.BlockSpec((None,) + shape,
+                            lambda g, r, n, so, bo: (so[g], 0, 0))
+
+    slab = pl.BlockSpec((None, block, width),
+                        lambda g, r, n, so, bo: (so[g], bo[g], 0))
+    tile = pl.BlockSpec(
+        (None, _GQA_ROW_TILE, width),
+        lambda g, r, n, so, bo: (so[g], r[so[g]] // _GQA_ROW_TILE, 0))
+    o, ck, cv = pl.pallas_call(
+        functools.partial(_decode_gqa_kernel, block=block, n_kv=n_kv_heads,
+                          group=n_heads // n_kv_heads, d=d,
+                          scale=1.0 / math.sqrt(d)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_steps,),        # as many steps as blocks are live
+            in_specs=[mine(n_heads, d), mine(1, width), mine(1, width),
+                      slab, slab],
+            out_specs=[mine(n_heads, d), tile, tile],
+            scratch_shapes=[pltpu.VMEM((n_heads, d), jnp.float32)] * 3),
+        out_shape=[jax.ShapeDtypeStruct((b, n_heads, d), ck.dtype),
+                   jax.ShapeDtypeStruct(ck.shape, ck.dtype),
+                   jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
+        # operands count from the scalar prefetch: the slabs are 7 and 8
+        input_output_aliases={7: 1, 8: 2},
+        interpret=interpret,
+        name=_profile.KERNEL_DECODE_ATTN_GQA,
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}),
+    )(row, n_live, slot_of, blk_of,
+      q.astype(ck.dtype).reshape(b, n_heads, d),
+      k_new.astype(ck.dtype)[:, None, :], v_new.astype(cv.dtype)[:, None, :],
+      ck, cv)
+    return o.reshape(b, n_heads * d), ck, cv
+
+
+def _decode_attention_gqa_reference(q, k_new, v_new, ck, cv, pos,
+                                    n_heads: int, n_kv_heads: int):
+    """The masked full-length softmax in ``jax.numpy``: the new row
+    written at ``pos`` mod the slab's length, then every query head over
+    the live rows of its cached head."""
+    b, rows, width = ck.shape
+    d = width // n_kv_heads
+    posv = jnp.broadcast_to(pos, (b,))
+    ck = kv_write_row(ck, k_new, posv % rows)
+    cv = kv_write_row(cv, v_new, posv % rows)
+    qg = q.astype(ck.dtype).reshape(b, n_kv_heads, n_heads // n_kv_heads, d)
+    scores = jnp.einsum("bngd,btnd->bngt", qg, kv_heads(ck, n_kv_heads),
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    live = jnp.arange(rows)[None, :] < jnp.minimum(posv + 1, rows)[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(live[:, None, None, :], scores, NEG_INF), axis=-1)
+    o = jnp.einsum("bngt,btnd->bngd", probs.astype(cv.dtype),
+                   kv_heads(cv, n_kv_heads),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, n_heads * d).astype(ck.dtype), ck, cv
+
+
+def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
+                         n_kv_heads: int):
+    """One decode step's grouped-query attention of every sequence over
+    its own slab.  ``q``: ``(b, heads * d)``; ``k_new``, ``v_new``:
+    ``(b, kv_heads * d)``; ``ck``, ``cv``: ``(b, rows, kv_heads * d)``
+    slabs; ``pos``: ``(b,)`` positions of the new token.  The slab's
+    length says what it holds: position p lives in row ``p mod rows``,
+    so a slab as long as the sequence may grow holds every position, and
+    a slab of ``window`` rows is a ring that holds exactly the window
+    once it is full (keys are cached with their rotary positions
+    applied, so a row needs no position of its own).  Writes the new row
+    and attends to the ``min(pos + 1, rows)`` live rows.  Returns
+    ``(o (b, heads * d), ck, cv)``.
+
+    On a TPU, for a slab ``_decode_gqa_plan`` admits, the pallas kernel
+    ``zoo_decode_attn_gqa`` over the live row blocks only; otherwise the
+    masked full-length softmax."""
+    b, rows, width = ck.shape
+    pos = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
+    block = None
+    if _on_tpu():
+        block = _decode_gqa_plan(rows, n_heads, n_kv_heads,
+                                 width // n_kv_heads, ck.dtype)[0]
+    if block is None:
+        return _decode_attention_gqa_reference(q, k_new, v_new, ck, cv, pos,
+                                               n_heads, n_kv_heads)
+    return _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads=n_heads,
+                            n_kv_heads=n_kv_heads, block=block,
+                            interpret=False)

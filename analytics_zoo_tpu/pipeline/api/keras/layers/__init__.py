@@ -16,7 +16,8 @@ from .pooling import (
     GlobalAveragePooling2D, GlobalAveragePooling3D)
 from .normalization import (BatchNormalization, WithinChannelLRN2D, LRN2D,
                             LayerNorm)
-from .embedding import Embedding, SparseEmbedding, WordEmbedding
+from .embedding import (Embedding, SparseEmbedding, TiedEmbedding,
+                        WordEmbedding)
 from .merge import Merge, merge
 from .advanced_activations import (ELU, LeakyReLU, PReLU, SReLU,
                                    ThresholdedReLU)
@@ -27,7 +28,8 @@ from .torch_style import (
     SoftShrink, HardTanh, RReLU, Exp, Log, Sqrt, Square, Negative, Identity,
     Power, Mul, CAdd, CMul, Scale, GaussianSampler, KerasLayerWrapper,
     Narrow, Select, Squeeze)
-from .moe import SwitchMoE
-from .attention import MultiHeadSelfAttention, PositionalEmbedding
+from .moe import SwitchMoE, TopKMoE
+from .attention import (GroupedQueryAttention, MultiHeadSelfAttention,
+                        PositionalEmbedding)
 from ..engine import Sequential, Model
 from .....core.graph import Input, InputLayer

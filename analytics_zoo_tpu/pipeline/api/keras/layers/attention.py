@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from .....core import initializers
 from .....core.module import Layer, register_layer
-from .....ops.attention import attention_bhsd
+from .....ops.attention import (attention_bhsd, attention_gqa_bhsd,
+                                gqa_qkv)
 
 
 @register_layer
@@ -171,4 +172,56 @@ class PositionalEmbedding(Layer):
     def get_config(self):
         cfg = super().get_config()
         cfg.update(max_len=self.max_len, init=self.init_name)
+        return cfg
+
+
+@register_layer
+class GroupedQueryAttention(Layer):
+    """Causal self-attention with ``n_heads`` query heads over
+    ``n_kv_heads`` key/value heads (query head h reads key/value head
+    ``h // (n_heads // n_kv_heads)``), no biases.  ``rope_theta``:
+    rotary positions over interleaved pairs, all ``head_dim`` dims;
+    ``None``: no positions at all.  ``window``: query i sees keys j with
+    ``j <= i`` and ``i - j < window``; ``None``: every ``j <= i``.
+    Products run in the weights' dtype with float32 accumulation, the
+    softmax in float32; the output is float32.  Forward only on the
+    chip's kernel path (``ops.attention.attention_gqa_bhsd``)."""
+
+    def __init__(self, n_heads, n_kv_heads, head_dim, rope_theta=None,
+                 window=None, init="glorot_uniform", input_shape=None,
+                 name=None):
+        super().__init__(input_shape=input_shape, name=name)
+        self.n_heads, self.n_kv_heads = int(n_heads), int(n_kv_heads)
+        self.head_dim = int(head_dim)
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_heads ({n_heads}) is not a multiple of "
+                             f"n_kv_heads ({n_kv_heads})")
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
+        self.window = None if window is None else int(window)
+        self.init_name = init
+
+    def init_params(self, rng, input_shape):
+        d_model, hd = input_shape[-1], self.head_dim
+        init = initializers.get(self.init_name)
+        ks = jax.random.split(rng, 4)
+        return {"Wq": init(ks[0], (d_model, self.n_heads, hd)),
+                "Wk": init(ks[1], (d_model, self.n_kv_heads, hd)),
+                "Wv": init(ks[2], (d_model, self.n_kv_heads, hd)),
+                "Wo": init(ks[3], (self.n_heads, hd, d_model))}
+
+    def call(self, params, state, inputs, training=False, rng=None):
+        q, k, v = gqa_qkv(params, inputs, jnp.arange(inputs.shape[1]),
+                          self.rope_theta)
+        o = attention_gqa_bhsd(q, k, v, window=self.window)
+        return jnp.einsum("bhsd,hde->bse", o, params["Wo"],
+                          preferred_element_type=jnp.float32)
+
+    def compute_output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def get_config(self):
+        cfg = super().get_config()
+        cfg.update(n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                   head_dim=self.head_dim, rope_theta=self.rope_theta,
+                   window=self.window, init=self.init_name)
         return cfg

@@ -147,3 +147,47 @@ def _build_table(embedding_file, word_index) -> np.ndarray:
     for idx, vec in vectors.items():
         table[idx] = vec
     return table
+
+
+@register_layer
+class TiedEmbedding(Layer):
+    """One table for both ends of a language model (``tie_word_
+    embeddings``): called on token ids ``(batch, seq)`` it looks them up
+    (float32 out), called on hidden states ``(batch, seq, d)`` it is the
+    output head, ``x E^T * logit_scale`` in the table's dtype with
+    float32 accumulation.  Use the SAME instance at both nodes of the
+    graph: a layer instance contributes one params entry."""
+
+    def __init__(self, input_dim, output_dim, logit_scale=1.0,
+                 init="uniform", input_length=None, input_shape=None,
+                 name=None):
+        if input_length is not None and input_shape is None:
+            input_shape = (input_length,)
+        super().__init__(input_shape=input_shape, name=name)
+        self.input_dim, self.output_dim = int(input_dim), int(output_dim)
+        self.logit_scale = float(logit_scale)
+        self.init_name = init
+
+    def init_params(self, rng, input_shape):
+        return {"embeddings": initializers.get(self.init_name)(
+            rng, (self.input_dim, self.output_dim))}
+
+    def call(self, params, state, inputs, training=False, rng=None):
+        table = params["embeddings"]
+        if inputs.ndim == 2:
+            return jnp.take(table, inputs.astype(jnp.int32),
+                            axis=0).astype(jnp.float32)
+        return jnp.einsum("bse,ve->bsv", inputs.astype(table.dtype), table,
+                          preferred_element_type=jnp.float32) \
+            * self.logit_scale
+
+    def compute_output_shape(self, input_shape):
+        if len(input_shape) == 2:
+            return tuple(input_shape) + (self.output_dim,)
+        return tuple(input_shape[:-1]) + (self.input_dim,)
+
+    def get_config(self):
+        cfg = super().get_config()
+        cfg.update(input_dim=self.input_dim, output_dim=self.output_dim,
+                   logit_scale=self.logit_scale, init=self.init_name)
+        return cfg

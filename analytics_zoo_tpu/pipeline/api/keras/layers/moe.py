@@ -21,10 +21,13 @@ user wiring.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
+from .....core import initializers
 from .....core.module import Layer, register_layer
 from .....observability.log import get_logger
+from .....ops.moe import moe_sublayer
 from .....parallel.expert import (MoEParams, expert_capacity,
                                   init_moe_params, moe_sharded,
                                   switch_moe)
@@ -133,4 +136,70 @@ class SwitchMoE(Layer):
         cfg.update(n_experts=self.n_experts, hidden_dim=self.hidden_dim,
                    capacity_factor=self.capacity_factor,
                    aux_weight=self.aux_weight, residual=self.residual)
+        return cfg
+
+
+@register_layer
+class TopKMoE(Layer):
+    """Top-k, dropless mixture of gated (SwiGLU) experts with shared
+    experts beside them, told which experts it holds (``ops/moe.py``):
+
+        s = sigmoid(h Wr);  I = top-k of s;  g_e = s_e / sum_{I} s
+        y = sum_{e in I, e held} g_e E_e(h) + mean_j S_j(h)
+
+    The router keeps all ``n_experts`` outputs; ``experts_held = (first,
+    count)`` names the contiguous range whose weights live here (default:
+    all of them), stacked ``(count, d, hidden)``: one chip's share of an
+    expert-parallel layer, with nothing standing in for the others.
+    No capacity, no dropped token, no auxiliary loss, no state.  Input
+    ``(batch, seq, d)`` or ``(tokens, d)``, output the same shape in
+    float32, WITHOUT a residual."""
+
+    def __init__(self, n_experts, top_k, hidden_dim, n_shared=0,
+                 experts_held=None, init="glorot_uniform",
+                 input_shape=None, name=None):
+        super().__init__(input_shape=input_shape, name=name)
+        self.n_experts, self.top_k = int(n_experts), int(top_k)
+        self.hidden_dim, self.n_shared = int(hidden_dim), int(n_shared)
+        first, count = experts_held or (0, self.n_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {experts_held} is no range of "
+                             f"the {n_experts} experts")
+        self.experts_held = (int(first), int(count))
+        self.init_name = init
+
+    def init_params(self, rng, input_shape):
+        d, f = input_shape[-1], self.hidden_dim
+        init = initializers.get(self.init_name)
+        ks = iter(jax.random.split(rng, 7))
+
+        def stack(n, shape):
+            return jax.vmap(lambda r: init(r, shape))(
+                jax.random.split(next(ks), n))
+
+        count = self.experts_held[1]
+        p = {"router": init(next(ks), (d, self.n_experts)),
+             "w_gate": stack(count, (d, f)), "w_up": stack(count, (d, f)),
+             "w_down": stack(count, (f, d))}
+        if self.n_shared:
+            p.update(s_gate=stack(self.n_shared, (d, f)),
+                     s_up=stack(self.n_shared, (d, f)),
+                     s_down=stack(self.n_shared, (f, d)))
+        return p
+
+    def call(self, params, state, inputs, training=False, rng=None):
+        flat = inputs.reshape(-1, inputs.shape[-1])
+        m, _ = moe_sublayer(params, flat, self.top_k, self.experts_held)
+        return m.reshape(inputs.shape)
+
+    def compute_output_shape(self, input_shape):
+        return tuple(input_shape)
+
+    def get_config(self):
+        cfg = super().get_config()
+        cfg.update(n_experts=self.n_experts, top_k=self.top_k,
+                   hidden_dim=self.hidden_dim, n_shared=self.n_shared,
+                   experts_held=list(self.experts_held),
+                   init=self.init_name)
         return cfg

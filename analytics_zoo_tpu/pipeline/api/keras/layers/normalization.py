@@ -179,17 +179,30 @@ class LRN2D(Layer):
 @register_layer
 class LayerNorm(Layer):
     """Layer normalization over the feature axis (TPU-era extension;
-    required by the attention/transformer stack in ops/attention.py)."""
+    required by the attention/transformer stack in ops/attention.py).
+    ``bias=False`` keeps the gain alone (``(x - mean) / sqrt(var + eps) *
+    gamma``), as the ``cohere2_moe`` family has it; statistics are taken
+    in float32 whatever the input's dtype."""
 
-    def __init__(self, epsilon=1e-5, input_shape=None, name=None):
+    def __init__(self, epsilon=1e-5, bias=True, input_shape=None,
+                 name=None):
         super().__init__(input_shape=input_shape, name=name)
         self.epsilon = float(epsilon)
+        self.bias = bool(bias)
 
     def init_params(self, rng, input_shape):
         n = input_shape[-1]
+        if not self.bias:
+            return {"gamma": jnp.ones((n,))}
         return {"gamma": jnp.ones((n,)), "beta": jnp.zeros((n,))}
 
     def call(self, params, state, inputs, training=False, rng=None):
+        if not self.bias:
+            x = inputs.astype(jnp.float32)
+            mean = jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+            return (x - mean) / jnp.sqrt(var + self.epsilon) \
+                * params["gamma"].astype(jnp.float32)
         mean = jnp.mean(inputs, axis=-1, keepdims=True)
         var = jnp.var(inputs, axis=-1, keepdims=True)
         y = (inputs - mean) / jnp.sqrt(var + self.epsilon)
@@ -198,4 +211,6 @@ class LayerNorm(Layer):
     def get_config(self):
         cfg = super().get_config()
         cfg["epsilon"] = self.epsilon
+        if not self.bias:
+            cfg["bias"] = False     # omitted when True (byte-stability)
         return cfg

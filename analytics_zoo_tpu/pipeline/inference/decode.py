@@ -103,10 +103,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...models.generation import (_decode_step, _decode_window,
                                   _embed_token, _head_logits, _prefill,
-                                  _prefill_ext, _sample)
+                                  _prefill_ext, _sample, family_of)
 from ...observability import profile as _profile
-from ...ops.attention import (decode_read_block, kv_insert, kv_slab_spec,
-                              kv_slab_zeros)
+from ...ops.attention import kv_insert, kv_slab_spec, kv_slab_zeros
 from ...observability.log import get_logger as _get_logger
 from .serving import _execstore, bucket_ladder
 
@@ -370,10 +369,16 @@ class DecodeEngine:
     """KV-cache-slotted continuous-batching decode engine (module doc).
 
     Args:
-        params: the TransformerLM param tree (``trainer.state.params``)
-            — placed on ``device`` once at construction.
+        params: the model's param tree (``trainer.state.params``) —
+            placed on ``device`` once at construction.
         hyper: the model's hyper dict (``n_layers``/``n_heads``/
-            ``d_model``/``max_len``/``moe_every``...).
+            ``d_model``/``max_len``/``moe_every``...).  Its ``family``
+            key (``models.generation.family_of``) says whose prefill
+            and decode step the plans trace and how the cache is laid
+            out: none for a ``TransformerLM``; ``"cohere2_moe"``
+            (``CommandAPlusLM``) brings slabs of two lengths in the
+            weights' dtype and a step that hands back the chosen
+            experts, and refuses ``prefix_pool``, drafts and ``mesh``.
         capacity: decode slots — the fixed batch width of the
             persistent step executable.
         max_len: per-slot cache length (default the model's
@@ -444,6 +449,18 @@ class DecodeEngine:
                 "draft and target must share a vocabulary "
                 f"({draft_hyper['vocab_size']} vs "
                 f"{hyper['vocab_size']})")
+        # the model's family: whose embed / prefill / decode step / head
+        # the plans trace, and how its cache is laid out
+        self._fam = family_of(hyper)
+        for what, given in (("prefix_pool", bool(prefix_pool)),
+                            ("draft", draft_params is not None),
+                            ("mesh", mesh is not None)):
+            if given and what in self._fam.refuses:
+                raise ValueError(
+                    f"the decode engine does not support {what} for the "
+                    f"{self._fam.name} family yet: its prefix blocks, "
+                    "draft window and slot sharding are written for "
+                    "TransformerLM's block and cache")
         self.capacity = int(capacity)
         self.step_fuse = max(1, int(step_fuse))
         self._hyper = dict(hyper)
@@ -538,8 +555,9 @@ class DecodeEngine:
         # positions a future occupant always overwrites before
         # attending (write-then-attend, see _build_step_fn).
         with jax.default_device(self._device):
-            caches = [kv_slab_zeros(*self._slab_dims(hyper))
-                      for _ in range(self._n_layers)]
+            self._slab_dtype = self._fam.slab_dtype(self._params)
+            caches = [kv_slab_zeros(*dims, dtype=self._slab_dtype)
+                      for dims in self._layer_slab_dims()]
             dcaches = []
             if self._draft_hyper is not None:
                 dh = self._draft_hyper
@@ -569,9 +587,17 @@ class DecodeEngine:
         # positions of a slot's slab that one step reads at a time (the
         # decode kernel's block, or the whole slab where it does not
         # run): what ``kv_positions_read`` rounds a length up to
-        self._kv_block = decode_read_block(
-            self.capacity, self.max_len, int(hyper["d_model"]),
-            int(hyper["n_heads"]))
+        # by kind of slab: (rows, read block, layers counted).  Where
+        # every layer's slab is alike and full-length (TransformerLM) it
+        # is one block and nothing a ring could skip: ``_kv_block``, and
+        # a dispatch then counts as it did before there were kinds
+        self._kv_kinds = self._fam.kv_kinds(
+            hyper, self.capacity, self.max_len, self._slab_dtype)
+        self._kv_block = None
+        if len(self._kv_kinds) == 1:
+            [(rows, block, layers)] = self._kv_kinds
+            if rows >= self.max_len and layers == 1:
+                self._kv_block = block
         self._tok = jax.device_put(tok, self._slot_sharding(1))
         self._pos = jax.device_put(pos, self._slot_sharding(1))
         self._samp = jax.device_put(samp, self._slot_sharding(1))
@@ -640,6 +666,16 @@ class DecodeEngine:
                           # the dispatched steps had live, and that they
                           # read (_kv_positions)
                           "kv_positions_live": 0, "kv_positions_read": 0,
+                          # positions a full-length slab would have held
+                          # for those steps and a windowed layer's ring
+                          # did not (_kv_positions)
+                          "kv_positions_window_skipped": 0,
+                          # a routed family's live slots (_count_routed):
+                          # (token, expert) pairs routed, those that fell
+                          # on experts held here, and held experts with at
+                          # least one token, summed over layers and steps
+                          "moe_assignments": 0, "moe_assignments_held": 0,
+                          "moe_experts_hit": 0,
                           # submit -> admission, summed (beside
                           # ``admitted``), and the dispatcher thread's
                           # time by what it was doing (_LoopPhase)
@@ -676,6 +712,11 @@ class DecodeEngine:
         n_heads = int(hyper["n_heads"])
         return (self.capacity, self.max_len, n_heads,
                 int(hyper["d_model"]) // n_heads)
+
+    def _layer_slab_dims(self):
+        """The target model's slab dims, layer by layer, as its family
+        lays them out (``_slab_dims`` is the draft's, a TransformerLM)."""
+        return self._fam.slab_dims(self._hyper, self.capacity, self.max_len)
 
     # ---- placement shardings --------------------------------------------
     def _rep_sharding(self):
@@ -741,14 +782,16 @@ class DecodeEngine:
         wholesale.  Shapes depend on (capacity, max_len) only — never
         occupancy."""
         params, hyper, max_len = weights[0], self._hyper, self.max_len
+        fam = self._fam
         posc = jnp.minimum(pos, max_len - 1)
-        emb = _embed_token(params, tok, posc)
-        logits, caches = _decode_step(params, hyper, caches, emb, posc,
-                                      mesh=self._mesh)
+        emb = fam.embed(params, tok, posc)
+        # a routed family's step also hands back the experts it chose
+        logits, caches, *routed = fam.decode_step(
+            params, hyper, caches, emb, posc, mesh=self._mesh)
         nxt = self._select(logits, samp, pick_sorted)
         seed, stepc, temp, topk, topp = samp
         return (caches, nxt, jnp.minimum(pos + 1, max_len),
-                (seed, stepc + 1, temp, topk, topp))
+                (seed, stepc + 1, temp, topk, topp), *routed)
 
     def _step_body(self, caches, tok, pos, samp, pick_sorted, weights):
         return self._step_core(caches, tok, pos, samp, pick_sorted,
@@ -785,11 +828,11 @@ class DecodeEngine:
         the AOT lowering inputs for the step/admit plans (committed to
         the engine's device — or slot-sharded over its mesh — exactly
         like the live state)."""
-        pair = kv_slab_spec(*self._slab_dims(self._hyper),
-                            sharding=self._slot_sharding(3))
+        caches = [kv_slab_spec(*dims, sharding=self._slot_sharding(3),
+                               dtype=self._slab_dtype)
+                  for dims in self._layer_slab_dims()]
         ispec = jax.ShapeDtypeStruct((self.capacity,), jnp.int32,
                                      sharding=self._slot_sharding(1))
-        caches = [pair for _ in range(self._n_layers)]
         return caches, ispec, ispec, self._samp_specs()
 
     def _step_specs(self):
@@ -901,13 +944,15 @@ class DecodeEngine:
         def stepk(caches, tok, pos, samp, pick_sorted, weights):
             def body(carry, _):
                 c, t, p, sm = carry
-                c, t, p, sm = self._step_body(c, t, p, sm, pick_sorted,
-                                              weights)
-                return (c, t, p, sm), t
+                c, t, p, sm, *routed = self._step_body(
+                    c, t, p, sm, pick_sorted, weights)
+                return (c, t, p, sm), (t, *routed) if routed else t
 
             (caches, tok, pos, samp), toks = lax.scan(
                 body, (caches, tok, pos, samp), None, length=k)
-            return caches, tok, pos, samp, toks  # toks: (k, capacity)
+            # toks: (k, capacity); a routed family's: that and the
+            # chosen experts (k, capacity, layers, top_k)
+            return caches, tok, pos, samp, toks
 
         return self._plan(
             f"step{k}", jax.jit(_profile.named(_profile.PROGRAM_STEPK,
@@ -959,8 +1004,8 @@ class DecodeEngine:
             dprops = dprops[:k - 1]  # (k-1, capacity)
             # the exact fallback token — bit-identical to the
             # non-speculative step plan by shared trace
-            caches, t0, _, _ = self._step_core(caches, tok, pos, samp,
-                                               pick_sorted, weights)
+            caches, t0, *_ = self._step_core(caches, tok, pos, samp,
+                                             pick_sorted, weights)
             # windowed verify of the proposals at pos+1 .. pos+k-1
             embs = [_embed_token(params, dprops[j],
                                  jnp.minimum(pos + 1 + j, max_len - 1))
@@ -1039,20 +1084,18 @@ class DecodeEngine:
         all one executable, so admitting is a single dispatch.  A
         drafted engine's plan also prefills the DRAFT's caches for the
         prompt (the draft must enter the window in lockstep)."""
-        hyper, dhyper = self._hyper, self._draft_hyper
+        hyper, dhyper, fam = self._hyper, self._draft_hyper, self._fam
 
         def admit(caches, dcaches, tok, pos, samp, prompt, length,
                   slot, seed0, temp0, topk0, topp0, weights):
             params, dparams = weights
-            x, pc = _prefill(params, hyper, prompt, s_b)
+            x, pc = fam.prefill(params, hyper, prompt, s_b)
             last = lax.dynamic_index_in_dim(x[0], length - 1,
                                             keepdims=False)
-            logits0 = _head_logits(params, last[None, :])[0]
+            logits0 = fam.head(params, hyper, last[None, :])[0]
             tok0 = self._sample_first(logits0, seed0, temp0, topk0,
                                       topp0)
-            new_caches = [
-                (kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))
-                for (ck, cv), (pk, pv) in zip(caches, pc)]
+            new_caches = fam.insert(hyper, caches, pc, slot, length)
             new_dcaches = dcaches
             if dhyper is not None:
                 _, dpc = _prefill(dparams, dhyper, prompt, s_b)
@@ -1272,16 +1315,10 @@ class DecodeEngine:
                     self._samp, greedy)
                 jax.device_get(acc)
             else:
-                (self._caches, self._tok, self._pos,
-                 self._samp) = self._step_fn(
-                    self._caches, self._tok, self._pos, self._samp,
-                    greedy)
+                self._run_step(self._step_fn, greedy)
                 jax.device_get(self._tok)
                 for fn in self._stepk_fns.values():
-                    (self._caches, self._tok, self._pos, self._samp,
-                     toks) = fn(self._caches, self._tok, self._pos,
-                                self._samp, greedy)
-                    jax.device_get(toks)
+                    jax.device_get(self._run_step(fn, greedy)[0])
         finally:
             with self._start_cond:
                 self._warming = False
@@ -1712,24 +1749,37 @@ class DecodeEngine:
                 return k
         return 1
 
-    def _kv_positions(self, k: int) -> Tuple[int, int]:
-        """What the next ``k`` steps of the live slots find in one
-        layer's slab: the positions that are live (a slot's length, its
-        new row included, step by step) and the positions the step reads
-        for them (each length rounded up to ``_kv_block``: the decode
-        kernel's key block, or the whole slab where the kernel does not
-        run).  Their ratio says how much of what a step moves it uses;
-        free slots are in neither."""
-        live = read = 0
+    def _kv_positions(self, k: int) -> Tuple[int, int, int]:
+        """What the next ``k`` steps of the live slots find in the
+        slabs, by kind of slab (``_kv_kinds``: one layer's slab where
+        all layers are alike, as TransformerLM's; each kind times its
+        layers where a family has slabs of two lengths): the positions
+        that are live (a slot's length, its new row included, step by
+        step; in a windowed layer's ring at most its rows), the
+        positions the step reads for them (the live ones rounded up to
+        the kind's read block: the decode kernel's, or the whole slab
+        where the kernel does not run), and the positions a full-length
+        slab would have held there and the ring did not.  live / read
+        says how much of what a step moves it uses; free slots are in
+        none."""
+        live = read = skipped = 0
         block = self._kv_block
         for req in self._slots:
             if req is not None:
                 for n in range(req.length + req.scheduled,
                                req.length + req.scheduled + k):
                     n = min(n, self.max_len)
-                    live += n
-                    read += -(-n // block) * block
-        return live, read
+                    if block is not None:   # one kind, full-length
+                        live += n
+                        read += -(-n // block) * block
+                        continue
+                    for rows, block_r, layers in self._kv_kinds:
+                        held = min(n, rows)
+                        live += layers * held
+                        read += layers * min(
+                            -(-held // block_r) * block_r, rows)
+                        skipped += layers * (n - held)
+        return live, read, skipped
 
     def _any_sampled(self) -> bool:
         """Whether a slot the dispatcher holds live samples
@@ -1742,6 +1792,17 @@ class DecodeEngine:
         longer."""
         return any(req is not None and req.temperature > 0.0
                    for req in self._slots)
+
+    def _run_step(self, plan, flag):
+        """Run a step plan over the persistent state and rebind it:
+        ``(caches, tok, pos, samp, *rest) = plan(...)``.  Returns
+        ``rest``, what the plan hands back beside the state: the single
+        step nothing, or a routed family's chosen experts; a fused
+        window its token matrix (with the chosen experts beside it)."""
+        (self._caches, self._tok, self._pos, self._samp,
+         *rest) = plan(self._caches, self._tok, self._pos, self._samp,
+                       flag)
+        return rest
 
     def _dispatch_step(self):
         """Dispatch the next decode window WITHOUT fetching (jax
@@ -1758,28 +1819,28 @@ class DecodeEngine:
         if self._draft_hyper is not None:
             return self._dispatch_spec()
         k = self._choose_fuse()
-        kv_live, kv_read = self._kv_positions(k)
+        kv_live, kv_read, kv_skipped = self._kv_positions(k)
         pick_sorted = self._any_sampled()
+        # a family without rings has nothing skipped to say on its span
+        ring = ({} if self._kv_block is not None
+                else {"kv_positions_window_skipped": kv_skipped})
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
                          kv_positions_read=kv_read,
-                         pick_sorted=int(pick_sorted)):
+                         pick_sorted=int(pick_sorted), **ring):
             flag = self._pick_flags[pick_sorted]
             if k > 1:
-                (self._caches, self._tok, self._pos, self._samp,
-                 toks) = self._stepk_fns[k](self._caches, self._tok,
-                                            self._pos, self._samp, flag)
+                [toks] = self._run_step(self._stepk_fns[k], flag)
                 self._counters["fused_dispatches"] += 1
             else:
-                (self._caches, self._tok, self._pos,
-                 self._samp) = self._step_fn(self._caches, self._tok,
-                                             self._pos, self._samp, flag)
-                toks = self._tok
+                routed = self._run_step(self._step_fn, flag)
+                toks = (self._tok, *routed) if routed else self._tok
             self._counters["steps"] += k
             if pick_sorted:
                 self._counters["steps_sorted"] += k
             self._counters["kv_positions_live"] += kv_live
             self._counters["kv_positions_read"] += kv_read
+            self._counters["kv_positions_window_skipped"] += kv_skipped
             for req in self._slots:
                 if req is not None:
                     req.scheduled += k
@@ -1794,7 +1855,7 @@ class DecodeEngine:
         k = self.spec_tokens
         # of the target's slabs, the window's one exact step goes
         # through the decode-attention op; the verify reads them whole
-        kv_live, kv_read = self._kv_positions(1)
+        kv_live, kv_read, _ = self._kv_positions(1)
         pick_sorted = self._any_sampled()
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
@@ -1815,7 +1876,27 @@ class DecodeEngine:
                     req.scheduled += k
             return toks, acc, list(self._slots), k
 
-    def _push_window(self, snapshot, toks, counts):
+    def _count_routed(self, snapshot, chosen):
+        """The ``moe_*`` counts of one fetched window, from the experts
+        ``chosen (k, capacity, layers, top_k)`` by every slot at every
+        step: over the steps a LIVE slot still had tokens to make (a
+        free slot's, and a finished request's surplus steps, are
+        garbage), the routed pairs, those whose expert is held here, and
+        the held experts that got at least one token, step by step and
+        layer by layer.  Returns the three increments."""
+        first, count = self._hyper["experts_held"]
+        k = chosen.shape[0]
+        steps = np.array([0 if req is None or req.stream.done
+                          else min(k, req.max_new - req.produced)
+                          for req in snapshot])
+        live = np.arange(k)[:, None] < steps[None, :]   # (k, capacity)
+        local = chosen - first
+        held = (local >= 0) & (local < count) & live[:, :, None, None]
+        hit = (local[..., None] == np.arange(count)) & held[..., None]
+        return (int(live.sum()) * chosen.shape[2] * chosen.shape[3],
+                int(held.sum()), int(hit.any(axis=(1, 3)).sum()))
+
+    def _push_window(self, snapshot, toks, counts, chosen=None):
         """Fan one fetched window out to the slots live at dispatch
         time, evicting finished requests: ``toks`` is (k, capacity),
         ``counts[slot]`` how many of the k rows are valid for that
@@ -1828,6 +1909,15 @@ class DecodeEngine:
         c = self._counters
         tokens0, evicted0 = c["tokens"], c["evicted"]
         with self._phase("fanout") as ann:
+            routed = {}
+            if chosen is not None:
+                routed = dict(zip(
+                    ("moe_assignments", "moe_assignments_held",
+                     "moe_experts_hit"),
+                    self._count_routed(snapshot, chosen)))
+                for name, n in routed.items():
+                    c[name] += n
+                routed["steps"] = len(toks)
             for slot, req in enumerate(snapshot):
                 if req is None or req.stream.done:
                     continue
@@ -1851,7 +1941,7 @@ class DecodeEngine:
                         self._free.append(slot)
                         break
             ann.set_metadata(tokens=c["tokens"] - tokens0,
-                             evicted=c["evicted"] - evicted0)
+                             evicted=c["evicted"] - evicted0, **routed)
 
     def _process_step(self, pending):
         """Fetch a dispatched window ((capacity,) single step,
@@ -1862,9 +1952,13 @@ class DecodeEngine:
         with self._phase("fetch"):      # the host waits for the device
             toks = jax.device_get(tok_dev)
         _profile.note_transfer("d2h")
+        chosen = None
+        if self._fam.routed:    # the chosen experts rode the same fetch
+            toks, chosen = toks
+            chosen = chosen.reshape((k, self.capacity) + chosen.shape[-2:])
         if k == 1:
             toks = toks.reshape(1, -1)
-        self._push_window(snapshot, toks, [k] * self.capacity)
+        self._push_window(snapshot, toks, [k] * self.capacity, chosen)
 
     def _process_spec(self, pending):
         """Fetch a speculative window's (spec_tokens, capacity)

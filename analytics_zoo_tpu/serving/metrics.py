@@ -102,6 +102,7 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
         "zoo_decode_queue_wait_seconds_total": [],
         "zoo_decode_loop_seconds_total": [],
         "zoo_decode_kv_positions_total": [],
+        "zoo_decode_moe_total": [],
     }
     decode_gauges: Dict[str, List] = {
         "zoo_decode_slot_occupancy": [],
@@ -228,7 +229,11 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
                 if key.startswith("loop_") and key.endswith("_s"))
             decode_counters["zoo_decode_kv_positions_total"].extend(
                 ({**ml, "kind": kind}, dec.get(f"kv_positions_{kind}", 0))
-                for kind in ("live", "read"))
+                for kind in ("live", "read", "window_skipped"))
+            decode_counters["zoo_decode_moe_total"].extend(
+                ({**ml, "kind": kind}, dec.get(f"moe_{kind}", 0))
+                for kind in ("assignments", "assignments_held",
+                             "experts_hit"))
             decode_gauges["zoo_decode_slot_occupancy"].append(
                 (ml, dec.get("slots_active", 0)))
             decode_gauges["zoo_decode_slot_capacity"].append(
@@ -354,7 +359,16 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
             "decode steps of the live slots: live (each slot's length) "
             "and read (rounded up to what a step fetches: the decode "
             "kernel's key block, or the whole slab where it does not "
-            "run); live/read is how much of what a step moves it uses",
+            "run); live/read is how much of what a step moves it uses; "
+            "window_skipped: positions a full-length slab would have "
+            "held and a windowed layer's ring did not (a family with "
+            "slabs of two lengths counts every layer, not one)",
+        "zoo_decode_moe_total":
+            "a routed (mixture-of-experts) model's decode steps, live "
+            "slots only: assignments ((token, expert) pairs routed), "
+            "assignments_held (those whose expert this engine holds), "
+            "experts_hit (held experts with at least one token, summed "
+            "over layers and steps); 0 for a dense model",
         "zoo_decode_slot_occupancy":
             "decode slots currently holding a live sequence",
         "zoo_decode_slot_capacity":

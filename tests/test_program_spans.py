@@ -145,6 +145,8 @@ def test_decode_spans_land_in_the_profile_with_their_stats(lm, tmp_path):
         assert floor <= stats["kv_positions_live"] <= floor * SEQ
         assert stats["kv_positions_read"] == floor * SEQ
         assert stats["pick_sorted"] == 0        # nobody samples
+        # slabs all alike and full-length: no ring, nothing skipped to say
+        assert "kv_positions_window_skipped" not in stats
     fanouts = tr.named("decode/fanout")
     # first tokens leave at the admission; the rest through the fan-out
     assert sum(e[4]["tokens"] for e in fanouts) == 9 + 4 + 12 + 7 + 3 - 5
@@ -340,7 +342,8 @@ def test_kv_positions_read_rounds_up_to_the_kernels_block(lm, monkeypatch):
     eng = DecodeEngine(wide.trainer.state.params, wide.hyper, capacity=3,
                        max_len=256, prompt_buckets=(BUCKET,))
     try:
-        assert eng._kv_block == 128
+        assert eng._kv_kinds == [(256, 128, 1)]     # rows, block, layers
+        assert eng._kv_block == 128     # one kind: counted as before kinds
 
         class Req:
             def __init__(self, length, scheduled):
@@ -349,11 +352,11 @@ def test_kv_positions_read_rounds_up_to_the_kernels_block(lm, monkeypatch):
         eng._slots = [Req(100, 27), None, Req(10, 1)]
         # lengths 127, 128, 129 and 11, 12, 13
         assert eng._kv_positions(3) == (127 + 128 + 129 + 11 + 12 + 13,
-                                        128 + 128 + 256 + 3 * 128)
+                                        128 + 128 + 256 + 3 * 128, 0)
         eng._slots = [None, Req(200, 55), None]     # clamped to the slab
-        assert eng._kv_positions(2) == (255 + 256, 256 + 256)
+        assert eng._kv_positions(2) == (255 + 256, 256 + 256, 0)
         eng._slots = [None] * 3
-        assert eng._kv_positions(4) == (0, 0)
+        assert eng._kv_positions(4) == (0, 0, 0)
     finally:
         eng.close()
 
@@ -380,8 +383,13 @@ def test_loop_counters_reach_prometheus(lm):
     kv = fams["zoo_decode_kv_positions_total"]
     assert kv.mtype == "counter"
     by_kind = {labels["kind"]: v for labels, v in kv.samples}
-    assert set(by_kind) == {"live", "read"}
+    assert set(by_kind) == {"live", "read", "window_skipped"}
     assert 0 < by_kind["live"] <= by_kind["read"]
+    assert by_kind["window_skipped"] == 0       # no windowed layer here
+    moe = fams["zoo_decode_moe_total"]
+    assert moe.mtype == "counter"
+    assert {labels["kind"]: v for labels, v in moe.samples} == {
+        "assignments": 0, "assignments_held": 0, "experts_hit": 0}
     steps = fams["zoo_decode_steps_total"].samples[0][1]
     picked = fams["zoo_decode_steps_sorted_total"]
     assert picked.mtype == "counter" and picked.samples[0][1] == 0 < steps

@@ -277,15 +277,18 @@ def two_layers():
     return lm
 
 
-def _engine_without_state(hyper, slots, device, weights):
+def _engine_without_state(hyper, slots, device, weights, max_len=MAX_LEN):
     """A ``DecodeEngine`` at ``slots`` x 1024 made without its state (a
     described device holds no array), and the dict its ``_plan`` fills:
     each plan it is asked for is lowered over the ``weights`` shapes
     under its name, and no further."""
+    from analytics_zoo_tpu.models.generation import family_of
     from analytics_zoo_tpu.pipeline.inference.decode import DecodeEngine
     eng = object.__new__(DecodeEngine)
-    eng.capacity, eng.max_len = slots, MAX_LEN
+    eng.capacity, eng.max_len = slots, max_len
     eng._hyper, eng._n_layers = dict(hyper), int(hyper["n_layers"])
+    eng._fam = family_of(hyper)
+    eng._slab_dtype = eng._fam.slab_dtype(weights[0])
     eng._draft_hyper = eng._mesh = None
     eng._device = device
     eng._admit_fns = {}
@@ -457,4 +460,74 @@ def test_chat_cell_plans_lower_at_published_widths(topo, one_chip,
     assert {name: _module_name(plan) for name, plan in lowered.items()} \
         == {"admit128": profile.PROGRAM_ADMIT,
             "step4": profile.PROGRAM_STEPK}
-    assert profile.KERNEL_DECODE_ATTN in lowered["step4"].as_text()
+    # the two pinned plans hold exactly the kernels they held before a
+    # second family came (ISSUE 36): one decode kernel a layer in the
+    # window, one flash forward a layer in the admission, nothing of the
+    # other family's
+    step, admit = (lowered[n].as_text() for n in ("step4", "admit128"))
+    assert step.count(profile.KERNEL_DECODE_ATTN) > 0
+    assert profile.KERNEL_FLASH_FWD in admit
+    assert profile.KERNEL_DECODE_ATTN not in admit
+    for text in (step, admit):
+        assert profile.KERNEL_DECODE_ATTN_GQA not in text
+        assert profile.SCOPE_MOE not in text
+        assert "ragged" not in text
+
+
+def _command_a_plus():
+    """The benchmark's ``command-a-plus-ep16`` at its published widths
+    (4 layers, 8 of 128 experts held, an eighth of the vocabulary), as
+    ``benchmark/adapters/cohere2moe.py`` builds it, with the reference's
+    bfloat16 parameter shapes: nothing is allocated."""
+    import json
+    from benchmark.adapters import cohere2moe as adapter
+    from benchmark.reference import cohere2moe as ref
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "command-a-plus-ep16.json")) as f:
+        cfg = json.load(f)
+    lm = adapter.build(cfg, {})
+    params = {layer: {leaf: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+                      for leaf, (shape, _) in leaves.items()}
+              for layer, leaves in ref.param_spec(cfg).items()}
+    # the keras graph's own parameter tree has the same leaves
+    graph, _ = jax.eval_shape(lambda key: lm.to_graph().init(key),
+                              jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_map(lambda a: a.shape, graph) \
+        == jax.tree_util.tree_map(lambda a: a.shape, params)
+    # 3,122.7 M parameters: 6.25 GB at 2 bytes
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree_util.tree_leaves(params)) == 3_122_679_808
+    return lm, params
+
+
+def test_docqa_cell_plans_lower_at_published_widths(topo, one_chip,
+                                                    on_the_chip):
+    """``cmdaplus-docqa-closed-5k``'s engine, whole: 4 layers, 48 slots x
+    6144 (three rings of 4096 rows and one slab of 6144), the admit plan
+    of its 4608-token bucket and the fused window of 4 steps, lowered
+    for the described chip from shapes alone: the window holds the
+    grouped decode kernel and the expert sublayer's scopes, the
+    admission the flash forward and the grouped products."""
+    from analytics_zoo_tpu.observability import profile
+    lm, params = _command_a_plus()
+    eng, lowered = _engine_without_state(
+        lm.hyper, 48, topo.devices[0], _abstract((params, None), one_chip),
+        max_len=6144)
+    assert [d[1] for d in eng._layer_slab_dims()] == [4096] * 3 + [6144]
+    eng._admit_fn_for(4608)
+    eng._build_stepk_plan(4)
+    assert {name: _module_name(plan) for name, plan in lowered.items()} \
+        == {"admit4608": profile.PROGRAM_ADMIT,
+            "step4": profile.PROGRAM_STEPK}
+    step, admit = (lowered[n].as_text(debug_info=True)
+                   for n in ("step4", "admit4608"))
+    assert profile.KERNEL_DECODE_ATTN_GQA in step
+    assert profile.KERNEL_FLASH_FWD in admit
+    assert "ragged_dot" in admit and "ragged_dot" not in step
+    for text in (step, admit):
+        for scope in (profile.SCOPE_MOE_ROUTER, profile.SCOPE_MOE_EXPERTS,
+                      profile.SCOPE_MOE_SHARED):
+            assert scope in text, scope
+    # the slabs are bfloat16 and the three rings are the window's length
+    assert "48x4096x1024xbf16" in step and "48x6144x1024xbf16" in step
