@@ -2,6 +2,7 @@ from .common import ZooModel, register_zoo_model
 from .textclassification import TextClassifier
 from .textgeneration import TransformerLM
 from .commandaplus import CommandAPlusLM
+from .granitehybrid import GraniteHybridLM
 from .recommendation import (Recommender, NeuralCF, WideAndDeep,
                              UserItemFeature, UserItemPrediction,
                              ColumnFeatureInfo)

@@ -31,7 +31,7 @@ from jax import lax
 from ..observability import profile as _profile
 from ..ops.attention import (attention_bhsd, decode_attention,
                              decode_read_block, kv_heads, kv_insert, kv_pad,
-                             kv_rows, kv_write_row)
+                             kv_rows, kv_slab_shape, kv_write_row)
 from ..parallel.expert import MoEParams, expert_capacity, switch_moe
 from ..pipeline.api.keras.activations import get as get_activation
 
@@ -93,12 +93,14 @@ def _embed_token(params, tok, pos):
 
 
 @jax.named_scope(_profile.SCOPE_PREFILL)
-def _prefill(params, hyper, prompt, cache_len):
+def _prefill(params, hyper, prompt, cache_len, length=None):
     """Batched prompt pass: causal attention over the whole prompt in one
     forward (the training-shaped compute), writing each layer's K/V into
     position [0, s_p) of a (b, cache_len, heads * d) slab (the layout is
     ``ops.attention.kv_*``'s) and returning the last position's hidden
-    state."""
+    state.  ``length`` (the prompt's own, inside the padded width) is not
+    needed: causal attention keeps the padding out of every row before
+    it."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
     s_p = prompt.shape[1]
     x = jnp.take(params["tok_embed"]["embeddings"],
@@ -267,16 +269,31 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
 # model's family for its functions here instead of importing
 # TransformerLM's: ``embed(params, tok, pos)``, ``prefill(params, hyper,
 # prompt, cache_len)``, ``decode_step(params, hyper, caches, x_tok, pos,
-# mesh)`` and ``head(params, hyper, hidden)``, plus how the family lays
-# out its cache: ``slab_dims(hyper, capacity, max_len)`` (one ``(capacity,
-# rows, heads, d_head)`` a layer), ``slab_dtype(params)``, ``insert(hyper,
-# caches, prompt_caches, slot, length)`` and ``kv_kinds(hyper, capacity,
-# max_len, dtype)`` (``(rows, read block, layers counted)`` of each kind
-# of slab).  TransformerLM's are the functions above, untouched and
-# called as they always were, so its plans lower to the same programs;
-# a family that needs more (slabs of two kinds, a step that hands back
-# the chosen experts) brings a module of its own and registers its
-# namespace here: this module names no other family.
+# mesh)`` and ``head(params, hyper, hidden)``, plus the state a slot
+# holds: ``state_shapes(hyper, capacity, max_len, dtype)`` (each layer's
+# tuple of ``(shape, dtype)``, capacity first: key/value slabs, or a
+# recurrent layer's fixed-size state), ``slab_dtype(params)``,
+# ``insert(hyper, caches, prompt_caches, slot, length)`` and
+# ``kv_kinds(hyper, capacity, max_len, dtype)`` (``(rows, read block,
+# layers counted)`` of each kind of slab).  The engine makes the state's
+# zeros, specs, placement and donation from ``state_shapes`` alone.  The
+# prefill is called with the prompt's ``length`` as a keyword; a family
+# whose state is slabs alone does not need it.  TransformerLM's are the
+# functions above, untouched and called as they always were, so its plans
+# lower to the same programs; a family that needs more (slabs of two
+# kinds, a step that hands back the chosen experts, recurrent state)
+# brings a module of its own and registers its namespace here: this
+# module names no other family.
+
+def slab_state_shapes(slab_dims):
+    """``state_shapes`` of a family whose every layer holds a key and a
+    value slab, from its ``slab_dims(hyper, capacity, max_len)`` (one
+    ``(capacity, rows, heads, d_head)`` a layer)."""
+    def state_shapes(hyper, capacity, max_len, dtype):
+        return [((kv_slab_shape(*dims), dtype),) * 2
+                for dims in slab_dims(hyper, capacity, max_len)]
+    return state_shapes
+
 
 def _slab_dims(hyper, capacity, max_len):
     """Every layer alike: ``max_len`` rows of ``n_heads`` heads."""
@@ -302,8 +319,9 @@ TRANSFORMER_LM = SimpleNamespace(
     name="transformer_lm", embed=_embed_token, prefill=_prefill,
     decode_step=_decode_step,
     head=lambda params, hyper, hidden: _head_logits(params, hidden),
-    slab_dims=_slab_dims, slab_dtype=lambda params: jnp.float32,
-    insert=_insert, kv_kinds=_kv_kinds, routed=False, refuses=())
+    state_shapes=slab_state_shapes(_slab_dims),
+    slab_dtype=lambda params: jnp.float32, insert=_insert,
+    kv_kinds=_kv_kinds, routed=False, refuses=())
 
 
 #: family name -> its namespace; a family's module adds itself

@@ -42,7 +42,7 @@ from ..ops.attention import (attention_gqa_bhsd, decode_attention_gqa,
                              decode_gqa_read_block, gqa_qkv, kv_insert,
                              kv_rows)
 from ..ops.moe import moe_sublayer
-from .generation import register_family
+from .generation import register_family, slab_state_shapes
 
 NAME = "cohere2_moe"
 
@@ -89,12 +89,12 @@ def head(params, hyper, hidden):
 
 
 @jax.named_scope(_profile.SCOPE_PREFILL)
-def prefill(params, hyper, prompt, cache_len):
+def prefill(params, hyper, prompt, cache_len, length=None):
     """Batched prompt pass ``(b, s)`` ids -> ``(x (b, s, d), caches)``:
     every layer's keys and values of the prompt as slab rows ``(b, s,
     kv_heads * d_head)`` in the weights' dtype, NOT padded: ``insert``
-    lays them into the slabs by layer kind."""
-    del cache_len
+    lays them into the slabs by layer kind (and takes ``length`` there)."""
+    del cache_len, length
     b, s = prompt.shape
     eps = hyper["layer_norm_eps"]
     x = jnp.take(params["tok_embed"]["embeddings"],
@@ -198,8 +198,8 @@ def kv_kinds(hyper, capacity, max_len, dtype):
 
 FAMILY = register_family(SimpleNamespace(
     name=NAME, embed=embed, prefill=prefill, decode_step=decode_step,
-    head=head, slab_dims=slab_dims, slab_dtype=slab_dtype, insert=insert,
-    kv_kinds=kv_kinds,
+    head=head, state_shapes=slab_state_shapes(slab_dims),
+    slab_dtype=slab_dtype, insert=insert, kv_kinds=kv_kinds,
     #: the step hands back the chosen experts beside the tokens
     routed=True,
     #: what the engine cannot do for this family yet

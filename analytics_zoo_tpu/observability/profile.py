@@ -70,8 +70,11 @@ KERNEL_DECODE_ATTN = "zoo_decode_attn"
 #: the decode step's attention over a bfloat16 slab whose cached heads
 #: each serve a group of query heads (the ``cohere2_moe`` family)
 KERNEL_DECODE_ATTN_GQA = "zoo_decode_attn_gqa"
+#: the decode step's state-space update (ops/ssm.py): every slot's
+#: float32 state read and written in place
+KERNEL_SSM_DECODE = "zoo_ssm_decode"
 KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV,
-           KERNEL_DECODE_ATTN, KERNEL_DECODE_ATTN_GQA)
+           KERNEL_DECODE_ATTN, KERNEL_DECODE_ATTN_GQA, KERNEL_SSM_DECODE)
 #: regions inside the jitted programs: ``jax.named_scope``s, which are
 #: HLO metadata (an executable answered from the persistent compilation
 #: cache keeps the metadata of whoever compiled it first), and
@@ -91,10 +94,16 @@ SCOPE_MOE = "zoo_moe"
 SCOPE_MOE_ROUTER = "zoo_moe_router"
 SCOPE_MOE_EXPERTS = "zoo_moe_experts"
 SCOPE_MOE_SHARED = "zoo_moe_shared"
+#: a Mamba-2 mixer (ops/ssm.py), in step and admit plans: the whole of it
+#: (in_proj to out_proj), and inside it the convolution and the scan (a
+#: prompt's chunked scan, or a step's state update)
+SCOPE_SSM = "zoo_ssm"
+SCOPE_SSM_CONV = "zoo_ssm_conv"
+SCOPE_SSM_SCAN = "zoo_ssm_scan"
 SCOPES = (SCOPE_LOSS, SCOPE_OPTIMIZER_UPDATE, SCOPE_GRAD_ACCUM,
           SCOPE_DECODE_ATTENTION, SCOPE_DECODE_MLP, SCOPE_PREFILL,
           SCOPE_SAMPLE, SCOPE_MOE, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
-          SCOPE_MOE_SHARED)
+          SCOPE_MOE_SHARED, SCOPE_SSM, SCOPE_SSM_CONV, SCOPE_SSM_SCAN)
 #: XLA module names of the jitted programs: the trainer's step, the
 #: decode engine's admit / prefix-admit / single step / fused window /
 #: speculative window / prefix-fill plans

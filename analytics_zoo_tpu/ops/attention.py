@@ -1667,3 +1667,12 @@ def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
     return _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads=n_heads,
                             n_kv_heads=n_kv_heads, block=block,
                             interpret=False)
+
+
+def scale_queries(q, scale: float):
+    """Queries ``(..., d)`` for a softmax scaled by ``scale`` where the
+    attention ops scale by ``1 / sqrt(d)``: ``q * scale * sqrt(d)`` in
+    float32, back in ``q``'s dtype (exact where that factor is a power of
+    two, as ``attention_multiplier`` 1/64 over heads of 64 gives 1/8)."""
+    factor = float(scale) * math.sqrt(q.shape[-1])
+    return (q.astype(jnp.float32) * factor).astype(q.dtype)

@@ -15,7 +15,7 @@ from .pooling import (
     GlobalMaxPooling2D, GlobalMaxPooling3D, GlobalAveragePooling1D,
     GlobalAveragePooling2D, GlobalAveragePooling3D)
 from .normalization import (BatchNormalization, WithinChannelLRN2D, LRN2D,
-                            LayerNorm)
+                            LayerNorm, RMSNorm)
 from .embedding import (Embedding, SparseEmbedding, TiedEmbedding,
                         WordEmbedding)
 from .merge import Merge, merge
@@ -29,6 +29,7 @@ from .torch_style import (
     Power, Mul, CAdd, CMul, Scale, GaussianSampler, KerasLayerWrapper,
     Narrow, Select, Squeeze)
 from .moe import SwitchMoE, TopKMoE
+from .ssm import GatedMLP, Mamba2Mixer
 from .attention import (GroupedQueryAttention, MultiHeadSelfAttention,
                         PositionalEmbedding)
 from ..engine import Sequential, Model

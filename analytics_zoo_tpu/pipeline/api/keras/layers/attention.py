@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from .....core import initializers
 from .....core.module import Layer, register_layer
 from .....ops.attention import (attention_bhsd, attention_gqa_bhsd,
-                                gqa_qkv)
+                                gqa_qkv, scale_queries)
 
 
 @register_layer
@@ -185,11 +185,13 @@ class GroupedQueryAttention(Layer):
     ``j <= i`` and ``i - j < window``; ``None``: every ``j <= i``.
     Products run in the weights' dtype with float32 accumulation, the
     softmax in float32; the output is float32.  Forward only on the
-    chip's kernel path (``ops.attention.attention_gqa_bhsd``)."""
+    chip's kernel path (``ops.attention.attention_gqa_bhsd``).
+    ``scale``: the softmax's scale where it is not ``1 / sqrt(head_dim)``
+    (folded into the queries: ``ops.attention.scale_queries``)."""
 
     def __init__(self, n_heads, n_kv_heads, head_dim, rope_theta=None,
-                 window=None, init="glorot_uniform", input_shape=None,
-                 name=None):
+                 window=None, init="glorot_uniform", scale=None,
+                 input_shape=None, name=None):
         super().__init__(input_shape=input_shape, name=name)
         self.n_heads, self.n_kv_heads = int(n_heads), int(n_kv_heads)
         self.head_dim = int(head_dim)
@@ -198,6 +200,7 @@ class GroupedQueryAttention(Layer):
                              f"n_kv_heads ({n_kv_heads})")
         self.rope_theta = None if rope_theta is None else float(rope_theta)
         self.window = None if window is None else int(window)
+        self.scale = None if scale is None else float(scale)
         self.init_name = init
 
     def init_params(self, rng, input_shape):
@@ -212,6 +215,8 @@ class GroupedQueryAttention(Layer):
     def call(self, params, state, inputs, training=False, rng=None):
         q, k, v = gqa_qkv(params, inputs, jnp.arange(inputs.shape[1]),
                           self.rope_theta)
+        if self.scale is not None:
+            q = scale_queries(q, self.scale)
         o = attention_gqa_bhsd(q, k, v, window=self.window)
         return jnp.einsum("bhsd,hde->bse", o, params["Wo"],
                           preferred_element_type=jnp.float32)
@@ -224,4 +229,6 @@ class GroupedQueryAttention(Layer):
         cfg.update(n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
                    head_dim=self.head_dim, rope_theta=self.rope_theta,
                    window=self.window, init=self.init_name)
+        if self.scale is not None:
+            cfg["scale"] = self.scale   # omitted when None (byte-stability)
         return cfg

@@ -11,6 +11,7 @@ because ``jnp.mean`` over a sharded axis lowers to a psum over ICI.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .....core import shapes as shape_utils
@@ -214,3 +215,33 @@ class LayerNorm(Layer):
         if not self.bias:
             cfg["bias"] = False     # omitted when True (byte-stability)
         return cfg
+
+
+@register_layer
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the feature axis, gain only:
+    ``x * rsqrt(mean(x^2) + eps) * gamma``, statistics and output in
+    float32 whatever the input's dtype."""
+
+    def __init__(self, epsilon=1e-6, input_shape=None, name=None):
+        super().__init__(input_shape=input_shape, name=name)
+        self.epsilon = float(epsilon)
+
+    def init_params(self, rng, input_shape):
+        return {"gamma": jnp.ones((input_shape[-1],))}
+
+    def call(self, params, state, inputs, training=False, rng=None):
+        return rms_norm(params["gamma"], inputs, self.epsilon)
+
+    def get_config(self):
+        cfg = super().get_config()
+        cfg["epsilon"] = self.epsilon
+        return cfg
+
+
+def rms_norm(gamma, x, eps):
+    """:class:`RMSNorm`'s arithmetic, for the code that reads its
+    parameters by name (the decode paths)."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * gamma.astype(jnp.float32)

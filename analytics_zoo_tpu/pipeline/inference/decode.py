@@ -383,7 +383,11 @@ class DecodeEngine:
             out: none for a ``TransformerLM``; ``"cohere2_moe"``
             (``CommandAPlusLM``) brings slabs of two lengths in the
             weights' dtype and a step that hands back the chosen
-            experts, and refuses ``prefix_pool``, drafts and ``mesh``.
+            experts; ``"granitemoehybrid"`` (``GraniteHybridLM``)
+            brings a fixed-size recurrent state per slot beside the
+            slabs (a Mamba layer's convolution window and float32
+            state), laid down at each prompt's own length; both refuse
+            ``prefix_pool``, drafts and ``mesh``.
         capacity: decode slots — the fixed batch width of the
             persistent step executable.
         max_len: per-slot cache length (default the model's
@@ -561,8 +565,9 @@ class DecodeEngine:
         # attending (write-then-attend, see _build_step_fn).
         with jax.default_device(self._device):
             self._slab_dtype = self._fam.slab_dtype(self._params)
-            caches = [kv_slab_zeros(*dims, dtype=self._slab_dtype)
-                      for dims in self._layer_slab_dims()]
+            caches = [tuple(jnp.zeros(shape, dtype)
+                            for shape, dtype in leaves)
+                      for leaves in self._layer_state_shapes()]
             dcaches = []
             if self._draft_hyper is not None:
                 dh = self._draft_hyper
@@ -587,7 +592,8 @@ class DecodeEngine:
         # so an uncommitted first call would cost every admit plan a
         # SECOND compile the first time it sees steady-state inputs,
         # breaking the one-compile-per-(bucket, capacity) invariant
-        self._caches = jax.device_put(caches, self._slot_sharding(3))
+        self._caches = jax.device_put(caches, jax.tree_util.tree_map(
+            lambda a: self._slot_sharding(a.ndim), caches))
         self._dcaches = jax.device_put(dcaches, self._slot_sharding(3))
         # positions of a slot's slab that one step reads at a time (the
         # decode kernel's block, or the whole slab where it does not
@@ -689,11 +695,18 @@ class DecodeEngine:
                           # least one token, summed over layers and steps
                           "moe_assignments": 0, "moe_assignments_held": 0,
                           "moe_experts_hit": 0,
+                          # admissions that laid a recurrent state into a
+                          # slot (a family with state-space layers)
+                          "ssm_states_written": 0,
                           # submit -> admission, summed (beside
                           # ``admitted``), and the dispatcher thread's
                           # time by what it was doing (_LoopPhase)
                           "queue_wait_s": 0.0,
                           **{f"loop_{p}_s": 0.0 for p in LOOP_PHASES}}
+        # bytes of float32 recurrent state held (0: slabs alone)
+        state_bytes = getattr(self._fam, "ssm_state_bytes", None)
+        self._ssm_state_bytes = (state_bytes(hyper, self.capacity)
+                                 if state_bytes else 0)
         self._bucket_stats: Dict[str, Dict[int, Any]] = {
             "hits": {}, "misses": {}, "compile_time_s": {}}
         self._occupancy = 0
@@ -725,10 +738,12 @@ class DecodeEngine:
         return (self.capacity, self.max_len, n_heads,
                 int(hyper["d_model"]) // n_heads)
 
-    def _layer_slab_dims(self):
-        """The target model's slab dims, layer by layer, as its family
-        lays them out (``_slab_dims`` is the draft's, a TransformerLM)."""
-        return self._fam.slab_dims(self._hyper, self.capacity, self.max_len)
+    def _layer_state_shapes(self):
+        """The target model's per-slot state, layer by layer, as its
+        family lays it out: a tuple of ``(shape, dtype)`` a layer, the
+        capacity first (``_slab_dims`` is the draft's, a TransformerLM)."""
+        return self._fam.state_shapes(self._hyper, self.capacity,
+                                      self.max_len, self._slab_dtype)
 
     # ---- placement shardings --------------------------------------------
     def _rep_sharding(self):
@@ -840,9 +855,10 @@ class DecodeEngine:
         the AOT lowering inputs for the step/admit plans (committed to
         the engine's device — or slot-sharded over its mesh — exactly
         like the live state)."""
-        caches = [kv_slab_spec(*dims, sharding=self._slot_sharding(3),
-                               dtype=self._slab_dtype)
-                  for dims in self._layer_slab_dims()]
+        caches = [tuple(jax.ShapeDtypeStruct(
+            shape, dtype, sharding=self._slot_sharding(len(shape)))
+            for shape, dtype in leaves)
+            for leaves in self._layer_state_shapes()]
         ispec = jax.ShapeDtypeStruct((self.capacity,), jnp.int32,
                                      sharding=self._slot_sharding(1))
         return caches, ispec, ispec, self._samp_specs()
@@ -1101,7 +1117,7 @@ class DecodeEngine:
         def admit(caches, dcaches, tok, pos, samp, prompt, length,
                   slot, seed0, temp0, topk0, topp0, weights):
             params, dparams = weights
-            x, pc = fam.prefill(params, hyper, prompt, s_b)
+            x, pc = fam.prefill(params, hyper, prompt, s_b, length=length)
             last = lax.dynamic_index_in_dim(x[0], length - 1,
                                             keepdims=False)
             logits0 = fam.head(params, hyper, last[None, :])[0]
@@ -1508,6 +1524,7 @@ class DecodeEngine:
         ``InferenceModel.serving_stats`` and the Prometheus bridge)."""
         out = dict(self._counters)
         out.update(capacity=self.capacity,
+                   ssm_state_bytes=self._ssm_state_bytes,
                    slots_active=self._occupancy,
                    queued=self._q.qsize(),
                    prompt_buckets=self.prompt_buckets,
@@ -1693,6 +1710,8 @@ class DecodeEngine:
                 req.first = self._admit_monolithic(req, slot)
             self._counters["prefills"] += 1
             self._counters["admitted"] += 1
+            if self._ssm_state_bytes:
+                self._counters["ssm_states_written"] += 1
             req.scheduled = 1
             if span is not None:
                 span.set_label("decode_bucket", req.bucket)

@@ -514,7 +514,8 @@ def test_docqa_cell_plans_lower_at_published_widths(topo, one_chip,
     eng, lowered = _engine_without_state(
         lm.hyper, 48, topo.devices[0], _abstract((params, None), one_chip),
         max_len=6144)
-    assert [d[1] for d in eng._layer_slab_dims()] == [4096] * 3 + [6144]
+    assert [keys[0][1] for keys, _ in eng._layer_state_shapes()] \
+        == [4096] * 3 + [6144]
     eng._admit_fn_for(4608)
     eng._build_stepk_plan(4)
     assert {name: _module_name(plan) for name, plan in lowered.items()} \
@@ -531,3 +532,112 @@ def test_docqa_cell_plans_lower_at_published_widths(topo, one_chip,
             assert scope in text, scope
     # the slabs are bfloat16 and the three rings are the window's length
     assert "48x4096x1024xbf16" in step and "48x6144x1024xbf16" in step
+
+
+# ------------------------------ granite4h-chat-closed-64: state-space layers
+GRANITE_SLOTS, GRANITE_LEN = 64, 1280
+#: one layer's float32 state over the cell's slots: 64 heads x 64 x 128
+GRANITE_STATE_BYTES = GRANITE_SLOTS * 64 * 64 * 128 * 4
+
+
+def _granite(layer_types=None):
+    """The benchmark's ``granite-4.0-h-micro`` at its published widths, as
+    ``benchmark/adapters/granitehybrid.py`` builds it (``layer_types``:
+    fewer layers of the same widths), with the reference's bfloat16
+    parameter shapes: nothing is allocated."""
+    import json
+    from benchmark.adapters import granitehybrid as adapter
+    from benchmark.reference import granitehybrid as ref
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    if layer_types is not None:
+        cfg.update(layer_types=layer_types,
+                   num_hidden_layers=len(layer_types))
+    lm = adapter.build(cfg, {})
+    params = {layer: {leaf: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+                      for leaf, (shape, _) in leaves.items()}
+              for layer, leaves in ref.param_spec(cfg).items()}
+    return lm, params, ref.n_params(cfg)
+
+
+@pytest.fixture
+def ssm_on_the_chip(monkeypatch):
+    """The described chip is a TPU for the state-space ops too."""
+    from analytics_zoo_tpu.ops import ssm
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+
+
+def test_granite_cell_plans_lower_at_published_widths(topo, one_chip,
+                                                      ssm_on_the_chip):
+    """``granite4h-chat-closed-64``'s engine, whole: 40 layers, 64 slots x
+    1280, the admit plan of its 1024-token bucket and the fused window of
+    4 steps, lowered from shapes alone: the window holds the state-update
+    kernel once a Mamba layer and the mixers' scopes, the admission the
+    flash forward once an attention layer and the chunked scan."""
+    from analytics_zoo_tpu.observability import profile
+    lm, params, n = _granite()
+    assert n == 3_191_396_096
+    eng, lowered = _engine_without_state(
+        lm.hyper, GRANITE_SLOTS, topo.devices[0],
+        _abstract((params, None), one_chip), max_len=GRANITE_LEN)
+    eng._admit_fn_for(1024)
+    eng._build_stepk_plan(4)
+    assert {name: _module_name(plan) for name, plan in lowered.items()} \
+        == {"admit1024": profile.PROGRAM_ADMIT,
+            "step4": profile.PROGRAM_STEPK}
+    step, admit = (lowered[n].as_text(debug_info=True)
+                   for n in ("step4", "admit1024"))
+    # the kernel's jit is lowered once and called once a Mamba layer
+    import re
+    assert profile.KERNEL_SSM_DECODE in step
+    assert len(re.findall(r"call @_ssm_decode_call\(", step)) == 36
+    assert profile.KERNEL_SSM_DECODE not in admit
+    assert profile.KERNEL_FLASH_FWD in admit
+    for text in (step, admit):
+        for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_CONV,
+                      profile.SCOPE_SSM_SCAN):
+            assert scope in text, scope
+    # the state float32, the windows and slabs bfloat16
+    assert "64x64x64x128xf32" in step and "64x3x4352xbf16" in step
+    assert "64x1280x512xbf16" in step
+
+
+def test_ssm_decode_compiles_in_place(one_chip, ssm_on_the_chip):
+    """The state update at the cell's 64 slots: one Mosaic call that
+    carries its name, the state aliased through it, and no temporary the
+    size of a slot's state."""
+    from analytics_zoo_tpu.observability import profile
+    from analytics_zoo_tpu.ops import ssm
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(ssm.ssm_decode, donate_argnums=(0,)).lower(
+        spec(GRANITE_SLOTS, 64, 64, 128), spec(GRANITE_SLOTS, 64, 64),
+        spec(GRANITE_SLOTS, 64), spec(64), spec(GRANITE_SLOTS, 128),
+        spec(GRANITE_SLOTS, 128), spec(64)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert profile.KERNEL_SSM_DECODE in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == GRANITE_STATE_BYTES
+    assert mem.temp_size_in_bytes < GRANITE_STATE_BYTES // GRANITE_SLOTS
+
+
+def test_granite_step_plan_compiles_without_a_state_sized_temporary(
+        topo, one_chip, ssm_on_the_chip):
+    """One Mamba layer and one attention layer at the published widths,
+    the fused window of 4 steps at 64 slots x 1280: every slot's state
+    updated in place (aliased), no temporary a layer's state in size."""
+    lm, params, _ = _granite(["mamba", "attention"])
+    eng, lowered = _engine_without_state(
+        lm.hyper, GRANITE_SLOTS, topo.devices[0],
+        _abstract((params, None), one_chip), max_len=GRANITE_LEN)
+    eng._build_stepk_plan(4)
+    compiled = lowered["step4"].compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < GRANITE_STATE_BYTES
+    assert mem.alias_size_in_bytes >= GRANITE_STATE_BYTES
