@@ -1465,25 +1465,31 @@ def attention_gqa_bhsd(q, k, v, window=None, interpret=None):
 
 
 #: rows a grid step of the grouped decode kernel takes from a slab: the
-#: largest of these that divides the slab's length.  A row is 2 KiB at 8
-#: cached heads of 128, so 512 rows are a 1 MiB transfer a slab.
+#: largest of these that divides the slab's length.  A row is kv_heads x
+#: d_head x 2 bytes: 2 KiB at 8 cached heads of 128, so 512 rows are a
+#: 1 MiB transfer a slab; 1 KiB at 8 of 64, where 1280 rows take 256.
 _GQA_BLOCKS = (512, 256, 128)
 #: rows of the bfloat16 tile in which the kernel rewrites the new row
 _GQA_ROW_TILE = 16
+#: lanes of the slab's tile, the kernel's unit: one cached head of 128,
+#: or ``128 / d_head`` narrower ones side by side
+_GQA_LANES = 128
 
 
 def _decode_gqa_plan(rows: int, n_heads: int, n_kv_heads: int, d_head: int,
                      dtype):
     """The row block of the grouped decode kernel for one slab shape, or
     ``(None, reason)``.  It wants a bfloat16 slab (the 16-row write
-    tile is the bfloat16 one), heads of 128 (a cached head is one lane
-    tile), whole groups, and a length that a block divides."""
+    tile is the bfloat16 one), heads of 128, 64 or 32 (a lane tile holds
+    one, two or four cached heads), whole groups, a row of whole lane
+    tiles, and a length that a block divides."""
     if jnp.dtype(dtype) != jnp.bfloat16:
         return None, f"the slab is {jnp.dtype(dtype).name}, not bfloat16"
-    if d_head != 128 or n_heads % n_kv_heads:
+    if d_head not in (128, 64, 32) or n_heads % n_kv_heads \
+            or n_kv_heads * d_head % _GQA_LANES:
         return None, (f"{n_heads} query heads over {n_kv_heads} cached "
-                      f"heads of {d_head}: not whole groups of 128-wide "
-                      "heads")
+                      f"heads of {d_head}: not whole groups of heads of "
+                      "128, 64 or 32 in whole lane tiles")
     for block in _GQA_BLOCKS:
         if rows % block == 0:
             return block, None
@@ -1503,25 +1509,30 @@ def decode_gqa_read_block(rows: int, n_heads: int, n_kv_heads: int,
 
 def _decode_gqa_kernel(row_ref, live_ref, slot_ref, blk_ref, q_ref, kn_ref,
                        vn_ref, ck_ref, cv_ref, o_ref, cko_ref, cvo_ref,
-                       m_ref, l_ref, acc_ref, *, block: int, n_kv: int,
-                       group: int, d: int, scale: float):
+                       m_ref, l_ref, acc_ref, *, block: int, n_tiles: int,
+                       group: int, scale: float):
     """One live row block of one slot; the grid is flat over the live
-    blocks, slot after slot, as ``_decode_attn_kernel``'s.  Per cached
-    head, the group's ``(group, d)`` queries against the block's
-    ``(block, d)`` keys on the MXU, an online softmax whose state
-    (``m``, ``l`` lane-broadcast, ``acc``: a row a query head) starts
-    from the NEW row, which never leaves VMEM.  ``row_ref``: where the
-    new row goes (``pos`` mod the slab's length: a windowed layer's
-    slab is a ring); ``live_ref``: how many rows are live, the new one
-    included.  Row ``row`` of the slab itself is stale (the ring's
-    oldest position, or nothing yet) and masked; the block that holds
-    it rewrites its 16-row tile in place, and the slot's last block
-    hands out the normalised result."""
+    blocks, slot after slot, as ``_decode_attn_kernel``'s.  Per 128-lane
+    tile of the slab (one cached head of 128, or 128 / d narrower ones
+    side by side), the tile's ``(group, 128)`` query rows against the
+    block's ``(block, 128)`` keys on the MXU, an online softmax whose
+    state (``m``, ``l`` lane-broadcast, ``acc``: a row a query head)
+    starts from the NEW row, which never leaves VMEM.  A query row holds
+    its head's values in its own cached head's lanes and zeros in the
+    tile's others (``_decode_gqa_call`` packs them), so its score is its
+    own head's, and of its ``acc`` row only its own head's lanes are
+    its result.  ``row_ref``: where the new row goes (``pos`` mod the
+    slab's length: a windowed layer's slab is a ring); ``live_ref``: how
+    many rows are live, the new one included.  Row ``row`` of the slab
+    itself is stale (the ring's oldest position, or nothing yet) and
+    masked; the block that holds it rewrites its 16-row tile in place,
+    and the slot's last block hands out the normalised result."""
     g = pl.program_id(0)
     s, j = slot_ref[g], blk_ref[g]
     row, n_live = row_ref[s], live_ref[s]
+    d = _GQA_LANES
     heads = [(slice(h * group, (h + 1) * group), slice(h * d, (h + 1) * d))
-             for h in range(n_kv)]
+             for h in range(n_tiles)]
 
     @pl.when(j == 0)
     def _start():
@@ -1565,6 +1576,17 @@ def _decode_gqa_kernel(row_ref, live_ref, slot_ref, blk_ref, q_ref, kn_ref,
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
+def _own_lanes(n_heads: int, group: int, d: int):
+    """``(n_heads, 128)``: True in the lanes of a query head's own cached
+    head within its lane tile.  Head ``h`` reads cached head ``h //
+    group``, which sits ``(h // group) % (128 // d)`` heads into its
+    tile; query heads are ordered by cached head, so a tile's query rows
+    are consecutive."""
+    per = _GQA_LANES // d
+    return (np.arange(n_heads)[:, None] // group % per
+            == np.arange(_GQA_LANES)[None, :] // d)
+
+
 @functools.partial(jax.jit, static_argnames=("n_heads", "n_kv_heads",
                                              "block", "interpret"))
 def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
@@ -1572,9 +1594,14 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
     """The kernel over ``(b, heads * d)`` queries, ``(b, kv_heads * d)``
     new rows, ``(b, rows, kv_heads * d)`` bfloat16 slabs and ``(b,)``
     int32 positions.  A jit of its own inside the step, as
-    ``_decode_attn_call`` is: traced and lowered once a program."""
+    ``_decode_attn_call`` is: traced and lowered once a program.  A head
+    narrower than a lane tile goes in with its values in its own cached
+    head's lanes of the tile and zeros in the others (``_own_lanes``),
+    and its result comes out of those lanes; heads of 128 go in and come
+    out as they are."""
     b, rows, width = ck.shape
     d = width // n_kv_heads
+    lanes, per = _GQA_LANES, _GQA_LANES // d   # cached heads a lane tile
     row = (pos % rows).astype(jnp.int32)
     n_live = jnp.minimum(pos + 1, rows).astype(jnp.int32)
     slot_of, blk_of, n_steps = _live_blocks(n_live - 1, block,
@@ -1589,18 +1616,23 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
     tile = pl.BlockSpec(
         (None, _GQA_ROW_TILE, width),
         lambda g, r, n, so, bo: (so[g], r[so[g]] // _GQA_ROW_TILE, 0))
+    q = q.astype(ck.dtype).reshape(b, n_heads, d)
+    if per > 1:     # each head into its own cached head's lanes
+        own = _own_lanes(n_heads, n_heads // n_kv_heads, d)
+        q = jnp.where(own, jnp.tile(q, (1, 1, per)), 0)
     o, ck, cv = pl.pallas_call(
-        functools.partial(_decode_gqa_kernel, block=block, n_kv=n_kv_heads,
-                          group=n_heads // n_kv_heads, d=d,
+        functools.partial(_decode_gqa_kernel, block=block,
+                          n_tiles=width // lanes,
+                          group=per * n_heads // n_kv_heads,
                           scale=1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_steps,),        # as many steps as blocks are live
-            in_specs=[mine(n_heads, d), mine(1, width), mine(1, width),
+            in_specs=[mine(n_heads, lanes), mine(1, width), mine(1, width),
                       slab, slab],
-            out_specs=[mine(n_heads, d), tile, tile],
-            scratch_shapes=[pltpu.VMEM((n_heads, d), jnp.float32)] * 3),
-        out_shape=[jax.ShapeDtypeStruct((b, n_heads, d), ck.dtype),
+            out_specs=[mine(n_heads, lanes), tile, tile],
+            scratch_shapes=[pltpu.VMEM((n_heads, lanes), jnp.float32)] * 3),
+        out_shape=[jax.ShapeDtypeStruct((b, n_heads, lanes), ck.dtype),
                    jax.ShapeDtypeStruct(ck.shape, ck.dtype),
                    jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
         # operands count from the scalar prefetch: the slabs are 7 and 8
@@ -1609,10 +1641,11 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
         name=_profile.KERNEL_DECODE_ATTN_GQA,
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))}),
-    )(row, n_live, slot_of, blk_of,
-      q.astype(ck.dtype).reshape(b, n_heads, d),
+    )(row, n_live, slot_of, blk_of, q,
       k_new.astype(ck.dtype)[:, None, :], v_new.astype(cv.dtype)[:, None, :],
       ck, cv)
+    if per > 1:     # a head's result is its own lanes; the rest are zeros
+        o = jnp.where(own, o, 0).reshape(b, n_heads, per, d).sum(axis=2)
     return o.reshape(b, n_heads * d), ck, cv
 
 
@@ -1652,9 +1685,10 @@ def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
     and attends to the ``min(pos + 1, rows)`` live rows.  Returns
     ``(o (b, heads * d), ck, cv)``.
 
-    On a TPU, for a slab ``_decode_gqa_plan`` admits, the pallas kernel
-    ``zoo_decode_attn_gqa`` over the live row blocks only; otherwise the
-    masked full-length softmax."""
+    On a TPU, for a slab ``_decode_gqa_plan`` admits (heads of 128, 64
+    or 32), the pallas kernel ``zoo_decode_attn_gqa`` over the live row
+    blocks only, a 128-lane tile of cached heads at a time; otherwise the
+    masked full-length softmax, which reads every row of every slab."""
     b, rows, width = ck.shape
     pos = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
     block = None

@@ -292,15 +292,29 @@ def test_window_tiles_skip_what_the_window_left_behind():
     assert A._row_tiles(10, 512, 512, 11, 0, True) == 11
 
 
-@pytest.mark.parametrize("positions", [[5, 255, 100], [256, 300, 700],
-                                       [0, 511, 512]])
-def test_decode_gqa_kernel_in_interpret_mode(positions):
-    """``zoo_decode_attn_gqa`` over a bfloat16 slab of 256 rows, as a
-    full slab (pos < rows) and as a ring (pos >= rows), against the
-    masked softmax; the slabs come out with the new row written and
-    nothing else touched."""
+#: (query heads, cached heads, d_head, rows, block): heads of 128, one
+#: cached head a lane tile; granite's heads of 64, two a tile, over its
+#: 1280 rows in blocks of 256; heads of 32, four a tile
+_GQA_128, _GQA_64, _GQA_32 = ((16, 2, 128, 256, 128), (32, 8, 64, 1280, 256),
+                              (16, 8, 32, 256, 128))
+
+
+@pytest.mark.parametrize("layout, positions", [
+    pytest.param(_GQA_128, [5, 255, 100], id="positions0"),
+    pytest.param(_GQA_128, [256, 300, 700], id="positions1"),
+    pytest.param(_GQA_128, [0, 511, 512], id="positions2"),
+    pytest.param(_GQA_64, [5, 255, 1279], id="d64-block-edge-last-row"),
+    pytest.param(_GQA_64, [256, 700, 1024], id="d64-block-starts"),
+    pytest.param(_GQA_32, [3, 300, 511], id="d32-ring")])
+def test_decode_gqa_kernel_in_interpret_mode(layout, positions):
+    """``zoo_decode_attn_gqa`` over a bfloat16 slab, as a full slab (pos
+    < rows) and as a ring (pos >= rows), against the masked softmax; the
+    slabs come out with the new row written and nothing else touched.
+    Heads narrower than a lane tile go in lane-packed: each row's score
+    and result are its own head's."""
     rng = np.random.default_rng(6)
-    b, heads, n_kv, d, rows = 3, 16, 2, 128, 256
+    heads, n_kv, d, rows, block = layout
+    b = 3
     bf = jnp.bfloat16
     ck, cv = (jnp.asarray(rng.normal(size=(b, rows, n_kv * d)), bf)
               for _ in range(2))
@@ -311,7 +325,7 @@ def test_decode_gqa_kernel_in_interpret_mode(positions):
     o1, k1, v1 = A._decode_attention_gqa_reference(q, kn, vn, ck, cv, pos,
                                                    heads, n_kv)
     o2, k2, v2 = A._decode_gqa_call(q, kn, vn, ck, cv, pos, n_heads=heads,
-                                    n_kv_heads=n_kv, block=128,
+                                    n_kv_heads=n_kv, block=block,
                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(k1, np.float32),
                                   np.asarray(k2, np.float32))
@@ -341,8 +355,16 @@ def test_decode_gqa_plan_says_what_it_admits():
     assert A._decode_gqa_plan(4096, 128, 8, 128, jnp.bfloat16)[0] == 512
     assert A._decode_gqa_plan(6144, 128, 8, 128, jnp.bfloat16)[0] == 512
     assert A._decode_gqa_plan(384, 16, 2, 128, jnp.bfloat16)[0] == 128
+    # heads of 64, two cached heads a lane tile: granite's 1280 rows
+    assert A._decode_gqa_plan(1280, 32, 8, 64, jnp.bfloat16)[0] == 256
+    assert A._decode_gqa_plan(4096, 128, 8, 64, jnp.bfloat16)[0] == 512
+    assert A._decode_gqa_plan(256, 16, 8, 32, jnp.bfloat16)[0] == 256
     for bad in ((4096, 128, 8, 128, jnp.float32),
-                (4096, 128, 8, 64, jnp.bfloat16),
+                (1280, 32, 8, 64, jnp.float32),
+                (4096, 128, 8, 96, jnp.bfloat16),      # 96 does not divide 128
+                (4096, 128, 1, 64, jnp.bfloat16),      # half a lane tile
+                (4096, 128, 8, 256, jnp.bfloat16),
+                (4096, 12, 8, 64, jnp.bfloat16),       # not whole groups
                 (100, 128, 8, 128, jnp.bfloat16)):
         block, why = A._decode_gqa_plan(*bad)
         assert block is None and why

@@ -596,6 +596,11 @@ def test_granite_cell_plans_lower_at_published_widths(topo, one_chip,
     assert len(re.findall(r"call @_ssm_decode_call\(", step)) == 36
     assert profile.KERNEL_SSM_DECODE not in admit
     assert profile.KERNEL_FLASH_FWD in admit
+    # the grouped decode kernel takes the 64-wide heads, two cached heads
+    # a lane tile: once an attention layer, and only in the window
+    assert profile.KERNEL_DECODE_ATTN_GQA in step
+    assert len(re.findall(r"call @_decode_gqa_call\(", step)) == 4
+    assert profile.KERNEL_DECODE_ATTN_GQA not in admit
     for text in (step, admit):
         for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_CONV,
                       profile.SCOPE_SSM_SCAN):
@@ -631,7 +636,8 @@ def test_granite_step_plan_compiles_without_a_state_sized_temporary(
         topo, one_chip, ssm_on_the_chip):
     """One Mamba layer and one attention layer at the published widths,
     the fused window of 4 steps at 64 slots x 1280: every slot's state
-    updated in place (aliased), no temporary a layer's state in size."""
+    updated in place (aliased), no temporary a layer's state in size, no
+    operation but the grouped decode kernel making a layer's slab."""
     lm, params, _ = _granite(["mamba", "attention"])
     eng, lowered = _engine_without_state(
         lm.hyper, GRANITE_SLOTS, topo.devices[0],
@@ -641,3 +647,16 @@ def test_granite_step_plan_compiles_without_a_state_sized_temporary(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < GRANITE_STATE_BYTES
     assert mem.alias_size_in_bytes >= GRANITE_STATE_BYTES
+    # nor a layer's slab in size (64 x 1280 x 512 x 2 B): no operation but
+    # the kernel makes one (the masked full-length path copied each slab
+    # to another layout for its per-head contraction, and scattered the
+    # new row into it)
+    import re
+    slab = GRANITE_SLOTS * GRANITE_LEN * 8 * 64
+    passed_on = {"parameter", "get-tuple-element", "bitcast", "custom-call"}
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(2) not in passed_on \
+                and np.prod([int(n) for n in m.group(1).split(",")]) == slab:
+            pytest.fail("an operation makes a slab: " + line[:200])
