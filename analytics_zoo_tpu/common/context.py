@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import re
 from typing import Dict, Optional
 
 import jax
@@ -75,11 +76,23 @@ def enable_compile_cache() -> str:
     directory that moves never hits, so never a temporary, pid- or
     time-named one.  Failure to enable it raises: a cold compile
     mistaken for a warm one is a wrong measurement, not a degraded
-    one."""
+    one.
+
+    An executable's key holds the program's metadata, its
+    ``jax.named_scope``s among it: jax leaves metadata out by default,
+    and a program that differs from a cached one in its scopes alone
+    would be answered with the other's executable and show the other's
+    names in a profile.  File names in that metadata count from the
+    checkout (unless a canonicalization is set already), so the same
+    code in another directory still hits."""
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(_CHECKOUT + os.sep))
     return cache_dir
 
 

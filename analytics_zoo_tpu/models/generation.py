@@ -50,12 +50,14 @@ def _block_params(params, i, moe):
     return bp
 
 
+@jax.named_scope(_profile.SCOPE_NORM)
 def _layer_norm(p, x, eps=1e-5):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     return (x - mean) / jnp.sqrt(var + eps) * p["gamma"] + p["beta"]
 
 
+@jax.named_scope(_profile.SCOPE_MLP)
 def _mlp(bp, f):
     if "moe" in bp:
         d = f.shape[-1]
@@ -71,6 +73,7 @@ def _mlp(bp, f):
         + bp["down"]["b"]
 
 
+@jax.named_scope(_profile.SCOPE_HEAD)
 def _head_logits(params, hidden):
     """Final LN + lm_head over a (b, d) hidden state — the one logits
     head both the sampling and beam builders share."""
@@ -78,6 +81,7 @@ def _head_logits(params, hidden):
     return x @ params["lm_head"]["W"] + params["lm_head"]["b"]
 
 
+@jax.named_scope(_profile.SCOPE_EMBED)
 def _embed_token(params, tok, pos):
     """Token + positional embedding for one decode step (tok: (rows,)
     int ids; pos: scalar shared position, or (rows,) per-row positions
@@ -92,7 +96,12 @@ def _embed_token(params, tok, pos):
     return emb + p.astype(emb.dtype)
 
 
-@jax.named_scope(_profile.SCOPE_PREFILL)
+@jax.named_scope(_profile.SCOPE_NORM)
+def _residual(x, y):
+    """A sublayer's output added to the stream."""
+    return x + y
+
+
 def _prefill(params, hyper, prompt, cache_len, length=None):
     """Batched prompt pass: causal attention over the whole prompt in one
     forward (the training-shaped compute), writing each layer's K/V into
@@ -103,27 +112,32 @@ def _prefill(params, hyper, prompt, cache_len, length=None):
     it."""
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
     s_p = prompt.shape[1]
-    x = jnp.take(params["tok_embed"]["embeddings"],
-                 prompt.astype(jnp.int32), axis=0)
-    x = x + params["pos_embed"]["table"][:s_p].astype(
-        x.dtype)
+    with jax.named_scope(_profile.SCOPE_EMBED):
+        x = jnp.take(params["tok_embed"]["embeddings"],
+                     prompt.astype(jnp.int32), axis=0)
+        x = x + params["pos_embed"]["table"][:s_p].astype(
+            x.dtype)
     caches = []
     for i in range(n_layers):
         moe = bool(moe_every) and (i + 1) % moe_every == 0
         bp = _block_params(params, i, moe)
         a = _layer_norm(bp["ln_a"], x)
-        q = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wq"])
-        k = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wk"])
-        v = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wv"])
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+            q = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wq"])
+            k = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wk"])
+            v = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wv"])
         o = attention_bhsd(q, k, v, causal=True)
-        x = x + jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+            o = jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
+        x = _residual(x, o)
         f = _layer_norm(bp["ln_m"], x)
-        x = x + _mlp(bp, f)
+        x = _residual(x, _mlp(bp, f))
         caches.append((kv_pad(kv_rows(k), cache_len),
                        kv_pad(kv_rows(v), cache_len)))
     return x, caches
 
 
+@jax.named_scope(_profile.SCOPE_ATTN_PROJ)
 def _rows_proj(a, w):
     """``a (..., e)`` through a ``(e, heads, d)`` projection, straight
     into slab rows ``(..., heads * d)``."""
@@ -152,10 +166,11 @@ def _decode_step(params, hyper, caches, x_tok, pos, mesh=None):
                 _rows_proj(a, bp["attn"]["Wv"]), ck, cv, pos, n_heads,
                 mesh=mesh)
             wo = bp["attn"]["Wo"]
-            x = x + o @ wo.reshape(-1, wo.shape[-1])
-        with jax.named_scope(_profile.SCOPE_DECODE_MLP):
-            f = _layer_norm(bp["ln_m"], x)
-            x = x + _mlp(bp, f)
+            with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                o = o @ wo.reshape(-1, wo.shape[-1])
+            x = _residual(x, o)
+        f = _layer_norm(bp["ln_m"], x)
+        x = _residual(x, _mlp(bp, f))
         new_caches.append((ck, cv))
     return _head_logits(params, x), new_caches
 
@@ -190,7 +205,8 @@ def _decode_window(params, hyper, caches, x_toks, pos):
         ck, cv = caches[i]
         with jax.named_scope(_profile.SCOPE_DECODE_ATTENTION):
             a = _layer_norm(bp["ln_a"], x)
-            q = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wq"])
+            with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                q = jnp.einsum("bke,ehd->bhkd", a, bp["attn"]["Wq"])
             kk = _rows_proj(a, bp["attn"]["Wk"])
             vv = _rows_proj(a, bp["attn"]["Wv"])
             for j in range(k):
@@ -205,17 +221,17 @@ def _decode_window(params, hyper, caches, x_toks, pos):
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
             o = jnp.einsum("bhkt,bthd->bhkd", probs.astype(cv.dtype),
                            kv_heads(cv, n_heads))
-            x = x + jnp.einsum("bhkd,hde->bke", o, bp["attn"]["Wo"])
-        with jax.named_scope(_profile.SCOPE_DECODE_MLP):
-            f = _layer_norm(bp["ln_m"], x)
-            x = x + _mlp(bp, f)
+            with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                o = jnp.einsum("bhkd,hde->bke", o, bp["attn"]["Wo"])
+            x = _residual(x, o)
+        f = _layer_norm(bp["ln_m"], x)
+        x = _residual(x, _mlp(bp, f))
         new_caches.append((ck, cv))
     b = x.shape[0]
     logits = _head_logits(params, x.reshape(b * k, -1))
     return logits.reshape(b, k, -1), new_caches
 
 
-@jax.named_scope(_profile.SCOPE_PREFILL)
 def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
     """Prefix-conditioned tail prefill — the prefix-KV-pool admit
     compute.  ``tail`` is (1, s_t) token ids occupying positions
@@ -230,10 +246,11 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
     n_layers, moe_every = hyper["n_layers"], hyper["moe_every"]
     n_heads = hyper["n_heads"]
     s_t = tail.shape[1]
-    x = jnp.take(params["tok_embed"]["embeddings"],
-                 tail.astype(jnp.int32), axis=0)
-    x = x + params["pos_embed"]["table"][p_len:p_len + s_t].astype(
-        x.dtype)
+    with jax.named_scope(_profile.SCOPE_EMBED):
+        x = jnp.take(params["tok_embed"]["embeddings"],
+                     tail.astype(jnp.int32), axis=0)
+        x = x + params["pos_embed"]["table"][p_len:p_len + s_t].astype(
+            x.dtype)
     tail_caches = []
     # tail query j (position p_len + j) sees the whole prefix plus
     # tail positions <= j
@@ -244,21 +261,25 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
         bp = _block_params(params, i, moe)
         pk, pv = (kv_heads(c, n_heads) for c in prefix_kv[i])
         a = _layer_norm(bp["ln_a"], x)
-        q = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wq"])
-        k = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wk"])
-        v = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wv"])
-        d = q.shape[-1]
-        sp = jnp.einsum("bhsd,bthd->bhst", q, pk) / math.sqrt(d)
-        st = jnp.einsum("bhsd,bhtd->bhst", q, k) / math.sqrt(d)
-        st = jnp.where(causal, st, -1e30)
-        scores = jnp.concatenate([sp, st], axis=-1)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        vall = jnp.concatenate([pv, jnp.swapaxes(v, 1, 2)], axis=1)
-        o = jnp.einsum("bhst,bthd->bhsd", probs.astype(vall.dtype),
-                       vall)
-        x = x + jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+            q = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wq"])
+            k = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wk"])
+            v = jnp.einsum("bse,ehd->bhsd", a, bp["attn"]["Wv"])
+        with jax.named_scope(_profile.SCOPE_ATTN_CORE):
+            d = q.shape[-1]
+            sp = jnp.einsum("bhsd,bthd->bhst", q, pk) / math.sqrt(d)
+            st = jnp.einsum("bhsd,bhtd->bhst", q, k) / math.sqrt(d)
+            st = jnp.where(causal, st, -1e30)
+            scores = jnp.concatenate([sp, st], axis=-1)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            vall = jnp.concatenate([pv, jnp.swapaxes(v, 1, 2)], axis=1)
+            o = jnp.einsum("bhst,bthd->bhsd", probs.astype(vall.dtype),
+                           vall)
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+            o = jnp.einsum("bhsd,hde->bse", o, bp["attn"]["Wo"])
+        x = _residual(x, o)
         f = _layer_norm(bp["ln_m"], x)
-        x = x + _mlp(bp, f)
+        x = _residual(x, _mlp(bp, f))
         tail_caches.append((kv_rows(k), kv_rows(v)))
     return x, tail_caches
 
@@ -302,6 +323,7 @@ def _slab_dims(hyper, capacity, max_len):
             ] * int(hyper["n_layers"])
 
 
+@jax.named_scope(_profile.SCOPE_INSERT)
 def _insert(hyper, caches, prompt_caches, slot, length):
     """A prefilled prompt's (padded) rows into slot ``slot``."""
     return [(kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))

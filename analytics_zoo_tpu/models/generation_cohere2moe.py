@@ -52,6 +52,7 @@ def layer_kinds(hyper):
     return list(hyper["layer_types"])[:int(hyper["n_layers"])]
 
 
+@jax.named_scope(_profile.SCOPE_NORM)
 def _norm(p, x, eps):
     x = x.astype(jnp.float32)
     mean = jnp.mean(x, axis=-1, keepdims=True)
@@ -71,6 +72,7 @@ def _moe(hyper, p, h):
                         tuple(hyper["experts_held"]))
 
 
+@jax.named_scope(_profile.SCOPE_EMBED)
 def embed(params, tok, pos):
     """Token embedding of one decode step (no positional table: the
     positions enter in the sliding layers' rotary turn)."""
@@ -78,6 +80,7 @@ def embed(params, tok, pos):
                     tok.astype(jnp.int32), axis=0).astype(jnp.float32)
 
 
+@jax.named_scope(_profile.SCOPE_HEAD)
 def head(params, hyper, hidden):
     """Final norm + the tied head over ``(b, d)`` hidden states:
     ``LN_f(x) Emb^T * logit_scale``, float32 logits."""
@@ -88,7 +91,6 @@ def head(params, hyper, hidden):
         * float(hyper.get("logit_scale", 1.0))
 
 
-@jax.named_scope(_profile.SCOPE_PREFILL)
 def prefill(params, hyper, prompt, cache_len, length=None):
     """Batched prompt pass ``(b, s)`` ids -> ``(x (b, s, d), caches)``:
     every layer's keys and values of the prompt as slab rows ``(b, s,
@@ -97,19 +99,23 @@ def prefill(params, hyper, prompt, cache_len, length=None):
     del cache_len, length
     b, s = prompt.shape
     eps = hyper["layer_norm_eps"]
-    x = jnp.take(params["tok_embed"]["embeddings"],
-                 prompt.astype(jnp.int32), axis=0).astype(jnp.float32)
+    x = embed(params, prompt, None)
     caches = []
     for i, kind in enumerate(layer_kinds(hyper)):
         theta, window = _attn_cfg(hyper, kind)
         ap = params[f"attn_{i}"]
         h = _norm(params[f"ln_{i}"], x, eps)
-        q, k, v = gqa_qkv(ap, h, jnp.arange(s), theta)
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):     # and positions
+            q, k, v = gqa_qkv(ap, h, jnp.arange(s), theta)
         o = attention_gqa_bhsd(q, k, v, window=window)
-        a = jnp.einsum("bhsd,hde->bse", o, ap["Wo"],
-                       preferred_element_type=jnp.float32)
-        m, _ = _moe(hyper, params[f"moe_{i}"], h.reshape(b * s, -1))
-        x = x + a + m.reshape(x.shape)
+        with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+            a = jnp.einsum("bhsd,hde->bse", o, ap["Wo"],
+                           preferred_element_type=jnp.float32)
+        with jax.named_scope(_profile.SCOPE_NORM):
+            h = h.reshape(b * s, -1)
+        m, _ = _moe(hyper, params[f"moe_{i}"], h)
+        with jax.named_scope(_profile.SCOPE_NORM):
+            x = x + a + m.reshape(x.shape)
         caches.append((kv_rows(k), kv_rows(v)))
     return x, caches
 
@@ -137,10 +143,13 @@ def decode_step(params, hyper, caches, x_tok, pos, mesh=None):
                 q.reshape(q.shape[0], -1), k.reshape(k.shape[0], -1),
                 v.reshape(v.shape[0], -1), ck, cv, pos, n_heads, n_kv)
             wo = ap["Wo"]
-            a = jnp.dot(o.astype(wo.dtype), wo.reshape(-1, wo.shape[-1]),
-                        preferred_element_type=jnp.float32)
+            with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                a = jnp.dot(o.astype(wo.dtype),
+                            wo.reshape(-1, wo.shape[-1]),
+                            preferred_element_type=jnp.float32)
         m, top_i = _moe(hyper, params[f"moe_{i}"], h)
-        x = x + a + m
+        with jax.named_scope(_profile.SCOPE_NORM):
+            x = x + a + m
         new_caches.append((ck, cv))
         chosen.append(top_i)
     return head(params, hyper, x), new_caches, jnp.stack(chosen, axis=1)
@@ -170,6 +179,7 @@ def _ring_rows(rows, ring: int, length):
     return jnp.roll(part, start % ring, axis=1)
 
 
+@jax.named_scope(_profile.SCOPE_INSERT)
 def insert(hyper, caches, prompt_caches, slot, length):
     """A prefilled prompt's keys and values into slot ``slot`` of every
     layer's slabs.  A slab at least as long as the prompt bucket takes

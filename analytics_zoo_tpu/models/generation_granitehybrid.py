@@ -47,9 +47,12 @@ def layer_kinds(hyper):
 
 def _mlp(params, hyper, i, x):
     h = rms_norm(params[f"ln_mlp_{i}"]["gamma"], x, hyper["rms_norm_eps"])
-    return x + hyper["residual_multiplier"] * gated_mlp(params[f"mlp_{i}"], h)
+    m = gated_mlp(params[f"mlp_{i}"], h)
+    with jax.named_scope(_profile.SCOPE_NORM):
+        return x + hyper["residual_multiplier"] * m
 
 
+@jax.named_scope(_profile.SCOPE_EMBED)
 def embed(params, tok, pos):
     """The table's rows of one decode step's tokens; ``decode_step``
     multiplies them by ``embedding_multiplier`` (no positions)."""
@@ -57,6 +60,7 @@ def embed(params, tok, pos):
                     tok.astype(jnp.int32), axis=0).astype(jnp.float32)
 
 
+@jax.named_scope(_profile.SCOPE_HEAD)
 def head(params, hyper, hidden):
     """Final norm + the tied head over ``(b, d)`` hidden states:
     ``RMSNorm_f(x) Emb^T / logits_scaling``, float32 logits."""
@@ -67,7 +71,6 @@ def head(params, hyper, hidden):
         * (1.0 / hyper["logits_scaling"])
 
 
-@jax.named_scope(_profile.SCOPE_PREFILL)
 def prefill(params, hyper, prompt, cache_len, length=None):
     """Batched prompt pass ``(b, s)`` ids -> ``(x (b, s, d), states)``: an
     attention layer's keys and values as slab rows ``(b, s, kv_heads *
@@ -77,9 +80,10 @@ def prefill(params, hyper, prompt, cache_len, length=None):
     del cache_len
     s = prompt.shape[1]
     eps, res = hyper["rms_norm_eps"], hyper["residual_multiplier"]
-    x = jnp.take(params["tok_embed"]["embeddings"],
-                 prompt.astype(jnp.int32), axis=0).astype(jnp.float32) \
-        * hyper["embedding_multiplier"]
+    with jax.named_scope(_profile.SCOPE_EMBED):
+        x = jnp.take(params["tok_embed"]["embeddings"],
+                     prompt.astype(jnp.int32), axis=0).astype(jnp.float32) \
+            * hyper["embedding_multiplier"]
     states = []
     for i, kind in enumerate(layer_kinds(hyper)):
         h = rms_norm(params[f"ln_{i}"]["gamma"], x, eps)
@@ -91,10 +95,13 @@ def prefill(params, hyper, prompt, cache_len, length=None):
             q, k, v = gqa_qkv(ap, h, jnp.arange(s))
             o = attention_gqa_bhsd(
                 scale_queries(q, hyper["attention_multiplier"]), k, v)
-            a = jnp.einsum("bhsd,hde->bse", o, ap["Wo"],
-                           preferred_element_type=jnp.float32)
+            with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                a = jnp.einsum("bhsd,hde->bse", o, ap["Wo"],
+                               preferred_element_type=jnp.float32)
             state = (kv_rows(k), kv_rows(v))
-        x = _mlp(params, hyper, i, x + res * a)
+        with jax.named_scope(_profile.SCOPE_NORM):
+            x = x + res * a
+        x = _mlp(params, hyper, i, x)
         states.append(state)
     return x, states
 
@@ -105,7 +112,8 @@ def decode_step(params, hyper, caches, x_tok, pos, mesh=None):
     del mesh
     eps, res = hyper["rms_norm_eps"], hyper["residual_multiplier"]
     n_heads, n_kv = int(hyper["n_heads"]), int(hyper["n_kv_heads"])
-    x = x_tok * hyper["embedding_multiplier"]
+    with jax.named_scope(_profile.SCOPE_EMBED):
+        x = x_tok * hyper["embedding_multiplier"]
     pos = jnp.broadcast_to(pos, x.shape[:1])
     new = []
     for i, kind in enumerate(layer_kinds(hyper)):
@@ -124,11 +132,14 @@ def decode_step(params, hyper, caches, x_tok, pos, mesh=None):
                     q.reshape(q.shape[0], -1), k.reshape(k.shape[0], -1),
                     v.reshape(v.shape[0], -1), ck, cv, pos, n_heads, n_kv)
                 wo = ap["Wo"]
-                a = jnp.dot(o.astype(wo.dtype), wo.reshape(-1, wo.shape[-1]),
-                            preferred_element_type=jnp.float32)
+                with jax.named_scope(_profile.SCOPE_ATTN_PROJ):
+                    a = jnp.dot(o.astype(wo.dtype),
+                                wo.reshape(-1, wo.shape[-1]),
+                                preferred_element_type=jnp.float32)
             new.append((ck, cv))
-        with jax.named_scope(_profile.SCOPE_DECODE_MLP):
-            x = _mlp(params, hyper, i, x + res * a)
+        with jax.named_scope(_profile.SCOPE_NORM):
+            x = x + res * a
+        x = _mlp(params, hyper, i, x)
     return head(params, hyper, x), new
 
 
@@ -153,17 +164,11 @@ def state_shapes(hyper, capacity, max_len, dtype):
             for kind in layer_kinds(hyper)]
 
 
-def ssm_state_bytes(hyper, capacity):
-    """Bytes of float32 recurrent state the engine holds."""
-    heads, hd, n, _, _ = _mamba_dims(hyper)
-    return (layer_kinds(hyper).count("mamba") * capacity * heads * hd * n
-            * 4)
-
-
 def slab_dtype(params):
     return params["tok_embed"]["embeddings"].dtype
 
 
+@jax.named_scope(_profile.SCOPE_INSERT)
 def insert(hyper, caches, prompt_states, slot, length):
     """A prefilled prompt's states into slot ``slot``: an attention
     layer's rows as they are (rows past ``length`` are not live until a
@@ -193,7 +198,7 @@ def kv_kinds(hyper, capacity, max_len, dtype):
 FAMILY = register_family(SimpleNamespace(
     name=NAME, embed=embed, prefill=prefill, decode_step=decode_step,
     head=head, state_shapes=state_shapes, slab_dtype=slab_dtype,
-    insert=insert, kv_kinds=kv_kinds, ssm_state_bytes=ssm_state_bytes,
+    insert=insert, kv_kinds=kv_kinds,
     routed=False,
     #: what the engine cannot do for this family yet: a prefix block or
     #: a draft would need the recurrent state at the block's end, slot

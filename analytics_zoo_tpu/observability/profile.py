@@ -76,17 +76,32 @@ KERNEL_SSM_DECODE = "zoo_ssm_decode"
 KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV,
            KERNEL_DECODE_ATTN, KERNEL_DECODE_ATTN_GQA, KERNEL_SSM_DECODE)
 #: regions inside the jitted programs: ``jax.named_scope``s, which are
-#: HLO metadata (an executable answered from the persistent compilation
-#: cache keeps the metadata of whoever compiled it first), and
+#: HLO metadata (part of the persistent compilation cache's key since
+#: ``common.context.enable_compile_cache`` puts it there), and
 #: ``zoo_sample``, a jit of its own inside the decode plans, whose name
 #: is part of the program
 SCOPE_LOSS = "zoo_loss"
 SCOPE_OPTIMIZER_UPDATE = "zoo_optimizer_update"
 SCOPE_GRAD_ACCUM = "zoo_grad_accum"
+#: a decode step's attention layer: its norm, projections and the
+#: attention over the slab
 SCOPE_DECODE_ATTENTION = "zoo_decode_attention"
-SCOPE_DECODE_MLP = "zoo_decode_mlp"
-SCOPE_PREFILL = "zoo_prefill"
 SCOPE_SAMPLE = "zoo_sample"
+#: the parts of a decoder's forward, in step and admit plans alike: the
+#: token (and position) table lookups; norms and the residual adds beside
+#: them; q/k/v (with their rotary turn) and the output projection; the
+#: causal attention over a prompt (on the chip ``zoo_flash_fwd``); a
+#: dense MLP; a Mamba mixer's in and out projections (its gated norm
+#: with the latter); the head (the final norm inside it is
+#: ``zoo_norm``); what lays a prompt's state and first token into a slot
+SCOPE_EMBED = "zoo_embed"
+SCOPE_NORM = "zoo_norm"
+SCOPE_ATTN_PROJ = "zoo_attn_proj"
+SCOPE_ATTN_CORE = "zoo_attn_core"
+SCOPE_MLP = "zoo_mlp"
+SCOPE_SSM_PROJ = "zoo_ssm_proj"
+SCOPE_HEAD = "zoo_head"
+SCOPE_INSERT = "zoo_insert"
 #: the top-k expert sublayer (ops/moe.py), in step and admit plans: the
 #: whole of it, and inside it the router, the held experts' grouped
 #: products and the shared experts
@@ -100,10 +115,16 @@ SCOPE_MOE_SHARED = "zoo_moe_shared"
 SCOPE_SSM = "zoo_ssm"
 SCOPE_SSM_CONV = "zoo_ssm_conv"
 SCOPE_SSM_SCAN = "zoo_ssm_scan"
+#: the innermost names that partition an admit plan: every operation of
+#: ``jit_admit`` lies under one of them, and the innermost one it lies
+#: under is its part (``zoo_moe`` and ``zoo_ssm`` hold parts; a norm
+#: inside the head is ``zoo_norm``)
+ADMIT_PARTS = (SCOPE_EMBED, SCOPE_NORM, SCOPE_ATTN_PROJ, SCOPE_ATTN_CORE,
+               SCOPE_MLP, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
+               SCOPE_MOE_SHARED, SCOPE_SSM_PROJ, SCOPE_SSM_CONV,
+               SCOPE_SSM_SCAN, SCOPE_HEAD, SCOPE_INSERT, SCOPE_SAMPLE)
 SCOPES = (SCOPE_LOSS, SCOPE_OPTIMIZER_UPDATE, SCOPE_GRAD_ACCUM,
-          SCOPE_DECODE_ATTENTION, SCOPE_DECODE_MLP, SCOPE_PREFILL,
-          SCOPE_SAMPLE, SCOPE_MOE, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
-          SCOPE_MOE_SHARED, SCOPE_SSM, SCOPE_SSM_CONV, SCOPE_SSM_SCAN)
+          SCOPE_DECODE_ATTENTION, SCOPE_MOE, SCOPE_SSM) + ADMIT_PARTS
 #: XLA module names of the jitted programs: the trainer's step, the
 #: decode engine's admit / prefix-admit / single step / fused window /
 #: speculative window / prefix-fill plans

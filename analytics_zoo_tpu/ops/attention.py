@@ -918,6 +918,7 @@ def _flash_over_mesh(mesh, q, k, v, causal: bool, kv_lengths,
                          out_specs=spec, check_vma=False)(*args)
 
 
+@jax.named_scope(_profile.SCOPE_ATTN_CORE)
 def attention_bhsd(q, k, v, causal: bool = False,
                    implementation: str = "auto", kv_lengths=None):
     """(b, h, s, d)-layout dispatch — the transpose-free fast path for
@@ -1013,6 +1014,7 @@ def kv_slab_zeros(capacity: int, max_len: int, n_heads: int, d_head: int,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+@jax.named_scope(_profile.SCOPE_INSERT)
 def kv_rows(x):
     """Projected keys or values ``(b, heads, s, d_head)``, as the
     prefill's attention takes them, as slab rows ``(b, s, heads *
@@ -1030,6 +1032,7 @@ def kv_heads(rows, n_heads: int):
     return rows.reshape(b, t, n_heads, hd // n_heads)
 
 
+@jax.named_scope(_profile.SCOPE_INSERT)
 def kv_pad(rows, cache_len: int):
     """Rows of a prompt, zero-padded on the right to a slab's length."""
     return jnp.pad(rows, [(0, 0), (0, cache_len - rows.shape[1]), (0, 0)])
@@ -1392,6 +1395,7 @@ def rope_interleaved(x, pos, theta: float):
                      axis=-1).reshape(x.shape)
 
 
+@jax.named_scope(_profile.SCOPE_ATTN_PROJ)
 def gqa_qkv(p, h, pos, rope_theta=None):
     """``h (b, s, d_model)`` through ``Wq (d_model, heads, d)``, ``Wk``,
     ``Wv (d_model, kv_heads, d)`` into ``(b, heads, s, d)`` layout, the
@@ -1435,6 +1439,7 @@ def _attention_gqa_reference(q, k, v, window=None):
     return o.reshape(b, h, s, d).astype(q.dtype)
 
 
+@jax.named_scope(_profile.SCOPE_ATTN_CORE)
 def attention_gqa_bhsd(q, k, v, window=None, interpret=None):
     """Causal self-attention of ``(b, heads, s, d)`` queries over
     ``(b, kv_heads, s, d)`` keys and values, ``heads`` a multiple of
@@ -1703,6 +1708,7 @@ def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
                             interpret=False)
 
 
+@jax.named_scope(_profile.SCOPE_ATTN_PROJ)
 def scale_queries(q, scale: float):
     """Queries ``(..., d)`` for a softmax scaled by ``scale`` where the
     attention ops scale by ``1 / sqrt(d)``: ``q * scale * sqrt(d)`` in

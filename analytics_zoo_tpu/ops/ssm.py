@@ -233,6 +233,7 @@ def gated_rmsnorm(y, z, w, eps):
     return g * lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
+@jax.named_scope(_profile.SCOPE_SSM_PROJ)
 def _split_proj(p, h):
     """``h W_in`` split into ``z`` (float32), ``xBC`` (in the weights'
     dtype: what the convolution and its window take) and ``dt`` (float32,
@@ -246,6 +247,7 @@ def _split_proj(p, h):
             .astype(w.dtype), zxd[..., d_inner + conv_dim:])
 
 
+@jax.named_scope(_profile.SCOPE_SSM_PROJ)
 def _out(p, y, z, eps):
     w = p["out_proj"]
     g = gated_rmsnorm(y, z, p["norm"], eps)
@@ -278,7 +280,8 @@ def mamba2_mixer(p, h, eps, chunk, lengths=None):
                 act[..., d_inner:d_inner + n], act[..., d_inner + n:],
                 p["D"], lengths=lengths, chunk=chunk,
                 dtype=p["in_proj"].dtype)
-        return _out(p, y.reshape(b, s, d_inner), z, eps), (window, state)
+            y = y.reshape(b, s, d_inner)
+        return _out(p, y, z, eps), (window, state)
 
 
 def mamba2_mixer_step(p, h, window, state, eps):
@@ -297,4 +300,5 @@ def mamba2_mixer_step(p, h, window, state, eps):
                 state, act[:, :d_inner].reshape(b, heads, hd), _dt(p, dt),
                 -jnp.exp(p["A_log"].astype(jnp.float32)),
                 act[:, d_inner:d_inner + n], act[:, d_inner + n:], p["D"])
-        return _out(p, y.reshape(b, d_inner), z, eps), window, state
+            y = y.reshape(b, d_inner)
+        return _out(p, y, z, eps), window, state

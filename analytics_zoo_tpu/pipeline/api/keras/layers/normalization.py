@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from .....core import shapes as shape_utils
 from .....core.module import Layer, register_layer
+from .....observability import profile as _profile
 
 
 @register_layer
@@ -239,6 +240,7 @@ class RMSNorm(Layer):
         return cfg
 
 
+@jax.named_scope(_profile.SCOPE_NORM)
 def rms_norm(gamma, x, eps):
     """:class:`RMSNorm`'s arithmetic, for the code that reads its
     parameters by name (the decode paths)."""

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from .....core import initializers
 from .....core.module import Layer, register_layer
+from .....observability import profile as _profile
 from .....ops.ssm import mamba2_mixer
 
 
@@ -73,6 +74,7 @@ class Mamba2Mixer(Layer):
         return cfg
 
 
+@jax.named_scope(_profile.SCOPE_MLP)
 def gated_mlp(params, h):
     """``(silu(h W[:, :f]) * (h W[:, f:])) W_out`` with one ``input_linear
     (d, 2f)``: products in the weights' dtype with float32 accumulation,

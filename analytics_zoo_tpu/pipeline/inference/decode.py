@@ -102,8 +102,9 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...models.generation import (_decode_step, _decode_window,
-                                  _embed_token, _head_logits, _prefill,
-                                  _prefill_ext, _sample, family_of)
+                                  _embed_token, _head_logits, _insert,
+                                  _prefill, _prefill_ext, _sample,
+                                  family_of)
 from ...observability import profile as _profile
 from ...ops.attention import kv_insert, kv_slab_spec, kv_slab_zeros
 from ...observability.log import get_logger as _get_logger
@@ -264,13 +265,9 @@ def _pick_tokens(logits, seed, index, temperature, top_k, top_p,
 
 # The pick of the next token is a jitted function of its own INSIDE the
 # plans, named ``zoo_sample``: a device profile then shows its
-# operations (either branch's) under ``jit(zoo_sample)``.  A
-# ``jax.named_scope`` would not do here: scopes are metadata, jax leaves
-# metadata out of the persistent compilation cache's key, and a plan
-# whose instructions did not change is answered from the cache with the
-# metadata (or none) of whoever compiled it first.  The function's
-# symbol is part of the program, so it cannot go stale; XLA inlines the
-# call.
+# operations (either branch's) under ``jit(zoo_sample)``.  The
+# function's symbol is part of the program itself, not only of its
+# metadata; XLA inlines the call.
 _pick_tokens = jax.jit(_profile.named(_profile.SCOPE_SAMPLE, _pick_tokens))
 
 
@@ -695,18 +692,11 @@ class DecodeEngine:
                           # least one token, summed over layers and steps
                           "moe_assignments": 0, "moe_assignments_held": 0,
                           "moe_experts_hit": 0,
-                          # admissions that laid a recurrent state into a
-                          # slot (a family with state-space layers)
-                          "ssm_states_written": 0,
                           # submit -> admission, summed (beside
                           # ``admitted``), and the dispatcher thread's
                           # time by what it was doing (_LoopPhase)
                           "queue_wait_s": 0.0,
                           **{f"loop_{p}_s": 0.0 for p in LOOP_PHASES}}
-        # bytes of float32 recurrent state held (0: slabs alone)
-        state_bytes = getattr(self._fam, "ssm_state_bytes", None)
-        self._ssm_state_bytes = (state_bytes(hyper, self.capacity)
-                                 if state_bytes else 0)
         self._bucket_stats: Dict[str, Dict[int, Any]] = {
             "hits": {}, "misses": {}, "compile_time_s": {}}
         self._occupancy = 0
@@ -1080,6 +1070,7 @@ class DecodeEngine:
             self._stepk_fns[k] = self._build_stepk_plan(k)
         self._step_fn = self._build_step_plan()  # set LAST: the flag
 
+    @jax.named_scope(_profile.SCOPE_INSERT)
     def _slot_write(self, arrays, slot, tok0, length, seed0, temp0,
                     topk0, topp0):
         """Shared admission epilogue: write one slot's (tok, pos,
@@ -1102,8 +1093,9 @@ class DecodeEngine:
         :func:`_sample` + fold_in discipline every later index
         uses, behind the same branch: a greedy request's admission
         sorts nothing)."""
-        return _pick_tokens(logits0, seed0, jnp.zeros((), jnp.int32),
-                            temp0, topk0, topp0, temp0 > 0.0)
+        with jax.named_scope(_profile.SCOPE_SAMPLE):
+            return _pick_tokens(logits0, seed0, jnp.zeros((), jnp.int32),
+                                temp0, topk0, topp0, temp0 > 0.0)
 
     def _build_admit_fn(self, s_b: int):
         """One prompt bucket's monolithic admission plan: batched
@@ -1118,18 +1110,17 @@ class DecodeEngine:
                   slot, seed0, temp0, topk0, topp0, weights):
             params, dparams = weights
             x, pc = fam.prefill(params, hyper, prompt, s_b, length=length)
-            last = lax.dynamic_index_in_dim(x[0], length - 1,
-                                            keepdims=False)
-            logits0 = fam.head(params, hyper, last[None, :])[0]
+            with jax.named_scope(_profile.SCOPE_HEAD):
+                last = lax.dynamic_index_in_dim(x[0], length - 1,
+                                                keepdims=False)
+                logits0 = fam.head(params, hyper, last[None, :])[0]
             tok0 = self._sample_first(logits0, seed0, temp0, topk0,
                                       topp0)
             new_caches = fam.insert(hyper, caches, pc, slot, length)
             new_dcaches = dcaches
             if dhyper is not None:
                 _, dpc = _prefill(dparams, dhyper, prompt, s_b)
-                new_dcaches = [
-                    (kv_insert(ck, pk, slot), kv_insert(cv, pv, slot))
-                    for (ck, cv), (pk, pv) in zip(dcaches, dpc)]
+                new_dcaches = _insert(dhyper, dcaches, dpc, slot, s_b)
             tok, pos, samp = self._slot_write(
                 (tok, pos, samp), slot, tok0, length, seed0, temp0,
                 topk0, topp0)
@@ -1178,7 +1169,8 @@ class DecodeEngine:
 
         def fill(prefix, weights):
             x, pc = _prefill(weights[0], hyper, prefix, p_b)
-            return pc, x[0, p_b - 1]
+            with jax.named_scope(_profile.SCOPE_HEAD):  # the head's input
+                return pc, x[0, p_b - 1]
 
         return jax.jit(_profile.named(_profile.PROGRAM_FILL, fill))
 
@@ -1220,22 +1212,24 @@ class DecodeEngine:
             if tail_pad:
                 xt, tc = _prefill_ext(params, hyper, tail, pkv, p_b)
             new_caches = []
-            for i, (ck, cv) in enumerate(caches):
-                pk, pv = pkv[i]
-                ck, cv = kv_insert(ck, pk, slot), kv_insert(cv, pv, slot)
+            with jax.named_scope(_profile.SCOPE_INSERT):
+                for i, (ck, cv) in enumerate(caches):
+                    pk, pv = pkv[i]
+                    ck, cv = kv_insert(ck, pk, slot), kv_insert(cv, pv, slot)
+                    if tail_pad:
+                        tk, tv = tc[i]
+                        ck = kv_insert(ck, tk, slot, p_b)
+                        cv = kv_insert(cv, tv, slot, p_b)
+                    new_caches.append((ck, cv))
+            with jax.named_scope(_profile.SCOPE_HEAD):
                 if tail_pad:
-                    tk, tv = tc[i]
-                    ck = kv_insert(ck, tk, slot, p_b)
-                    cv = kv_insert(cv, tv, slot, p_b)
-                new_caches.append((ck, cv))
-            if tail_pad:
-                ti = jnp.clip(length - p_b - 1, 0, tail_pad - 1)
-                lh = lax.dynamic_index_in_dim(xt[0], ti,
-                                              keepdims=False)
-                lh = jnp.where(length > p_b, lh, h_pfx)
-            else:
-                lh = h_pfx
-            logits0 = _head_logits(params, lh[None, :])[0]
+                    ti = jnp.clip(length - p_b - 1, 0, tail_pad - 1)
+                    lh = lax.dynamic_index_in_dim(xt[0], ti,
+                                                  keepdims=False)
+                    lh = jnp.where(length > p_b, lh, h_pfx)
+                else:
+                    lh = h_pfx
+                logits0 = _head_logits(params, lh[None, :])[0]
             tok0 = self._sample_first(logits0, seed0, temp0, topk0,
                                       topp0)
             tok, pos, samp = self._slot_write(
@@ -1524,7 +1518,6 @@ class DecodeEngine:
         ``InferenceModel.serving_stats`` and the Prometheus bridge)."""
         out = dict(self._counters)
         out.update(capacity=self.capacity,
-                   ssm_state_bytes=self._ssm_state_bytes,
                    slots_active=self._occupancy,
                    queued=self._q.qsize(),
                    prompt_buckets=self.prompt_buckets,
@@ -1710,8 +1703,6 @@ class DecodeEngine:
                 req.first = self._admit_monolithic(req, slot)
             self._counters["prefills"] += 1
             self._counters["admitted"] += 1
-            if self._ssm_state_bytes:
-                self._counters["ssm_states_written"] += 1
             req.scheduled = 1
             if span is not None:
                 span.set_label("decode_bucket", req.bucket)

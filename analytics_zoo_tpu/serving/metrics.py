@@ -105,12 +105,10 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
         "zoo_decode_loop_seconds_total": [],
         "zoo_decode_kv_positions_total": [],
         "zoo_decode_moe_total": [],
-        "zoo_decode_ssm_states_written_total": [],
     }
     decode_gauges: Dict[str, List] = {
         "zoo_decode_slot_occupancy": [],
         "zoo_decode_slot_capacity": [],
-        "zoo_decode_ssm_state_bytes": [],
     }
     # weight pager (serving density): residency per model plus the
     # fault/eviction outcome counters — exported for every PAGED model
@@ -227,9 +225,7 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
                     ("zoo_decode_first_tokens_deferred_total",
                      "first_tokens_deferred"),
                     ("zoo_decode_queue_wait_seconds_total",
-                     "queue_wait_s"),
-                    ("zoo_decode_ssm_states_written_total",
-                     "ssm_states_written")):
+                     "queue_wait_s")):
                 decode_counters[prom_name].append(
                     (ml, dec.get(key, 0)))
             decode_counters["zoo_decode_loop_seconds_total"].extend(
@@ -247,8 +243,6 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
                 (ml, dec.get("slots_active", 0)))
             decode_gauges["zoo_decode_slot_capacity"].append(
                 (ml, dec.get("capacity", 0)))
-            decode_gauges["zoo_decode_ssm_state_bytes"].append(
-                (ml, dec.get("ssm_state_bytes", 0)))
         # device-parallel serving: per-replica dispatch counters (and
         # their per-bucket breakdown — the bucket metrics' replica
         # label) plus the health gauge
@@ -391,13 +385,6 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
             "assignments_held (those whose expert this engine holds), "
             "experts_hit (held experts with at least one token, summed "
             "over layers and steps); 0 for a dense model",
-        "zoo_decode_ssm_states_written_total":
-            "admissions that laid a recurrent (state-space) state into a "
-            "decode slot, overwriting the slot's whole; 0 for a model "
-            "without state-space layers",
-        "zoo_decode_ssm_state_bytes":
-            "bytes of float32 state-space state the decode engine holds "
-            "for its slots (0 for a model without state-space layers)",
         "zoo_decode_slot_occupancy":
             "decode slots currently holding a live sequence",
         "zoo_decode_slot_capacity":

@@ -5,7 +5,7 @@ the plain reference ``benchmark/reference/granitehybrid.py`` give the
 same LOGITS; the chunked scan is the sequential recurrence, its final
 state the state at each prompt's own length; the state-update kernel in
 interpret mode is its twin; a reused slot inherits nothing; what the
-family refuses; its counters; faults in the admission are seen."""
+family refuses; faults in the admission are seen."""
 
 import importlib
 
@@ -281,9 +281,8 @@ def test_a_reused_slot_gives_a_fresh_engines_logits(served):
 def test_the_state_lies_where_the_family_says(served):
     """Typed per-layer state: a Mamba layer's window (capacity, 3, conv
     dim) in the weights' dtype and its float32 state; the attention
-    layer's two slabs; the counters count the float32 state's bytes and
-    every admission that laid a state down; only the attention layer's
-    slab is counted in ``kv_positions_*``."""
+    layer's two slabs; only the attention layer's slab is counted in
+    ``kv_positions_*``."""
     net, _, eng = served
     kinds = fam.layer_kinds(net.hyper)
     for kind, (a, b) in zip(kinds, eng._caches):
@@ -296,22 +295,7 @@ def test_the_state_lies_where_the_family_says(served):
     before = eng.stats()
     eng.generate([np.arange(1, 6, dtype=np.int32)] * 2, 4)
     s = eng.stats()
-    assert s["ssm_state_bytes"] == 9 * 3 * 4 * 32 * 16 * 4
-    assert s["ssm_states_written"] - before["ssm_states_written"] == 2
     assert s["admitted"] - before["admitted"] == 2
-
-
-def test_counters_reach_prometheus(served):
-    from analytics_zoo_tpu.serving.metrics import registry_families
-    _, _, eng = served
-    stats = eng.stats()
-    fams = {f.name: f for f in registry_families(
-        {"granite": {"serving": {"decode": stats}}})}
-    [(labels, held)] = fams["zoo_decode_ssm_state_bytes"].samples
-    assert labels == {"model": "granite"} and held == 9 * 3 * 4 * 32 * 16 * 4
-    written = fams["zoo_decode_ssm_states_written_total"]
-    assert written.mtype == "counter"
-    assert written.samples[0][1] == stats["ssm_states_written"] > 0
 
 
 def test_the_engine_refuses_what_it_cannot_do_for_this_family(served):
@@ -436,17 +420,18 @@ def test_the_families_without_recurrent_state_keep_their_slabs():
 
 def test_plans_hold_the_mixers_scopes(served):
     """The step and admit plans open ``zoo_ssm`` around each Mamba mixer,
-    ``zoo_ssm_conv`` and ``zoo_ssm_scan`` inside it, and the attention
-    layer's ``zoo_decode_attention``."""
+    ``zoo_ssm_proj``, ``zoo_ssm_conv`` and ``zoo_ssm_scan`` inside it,
+    ``zoo_mlp`` around each MLP, and the step the attention layer's
+    ``zoo_decode_attention``."""
     net, params, eng = served
     weights = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (params, None))
     lowered = jax.jit(lambda *a: eng._step_body(*a)).lower(
         *eng._step_specs(), weights)
     text = lowered.as_text(debug_info=True)
-    for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_CONV,
-                  profile.SCOPE_SSM_SCAN, profile.SCOPE_DECODE_ATTENTION,
-                  profile.SCOPE_DECODE_MLP):
+    for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_PROJ,
+                  profile.SCOPE_SSM_CONV, profile.SCOPE_SSM_SCAN,
+                  profile.SCOPE_DECODE_ATTENTION, profile.SCOPE_MLP):
         assert scope in text, scope
     admit = eng._build_admit_fn(8)
     caches, tok, pos, samp = eng._state_specs()
@@ -456,9 +441,11 @@ def test_plans_hold_the_mixers_scopes(served):
                        jax.ShapeDtypeStruct((1, 8), jnp.int32), i0, i0,
                        i0, f0, i0, f0, weights).as_text(
         debug_info=True)
-    for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_CONV,
-                  profile.SCOPE_SSM_SCAN, profile.SCOPE_PREFILL):
+    for scope in (profile.SCOPE_SSM, profile.SCOPE_SSM_PROJ,
+                  profile.SCOPE_SSM_CONV, profile.SCOPE_SSM_SCAN,
+                  profile.SCOPE_MLP):
         assert scope in text, scope
+    assert "zoo_prefill" not in text
 
 
 def test_reference_control_is_another_forward():

@@ -10,7 +10,9 @@ is what the spans are.  Read back with ``jax.profiler.ProfileData``, the
 same way the benchmark's ``program_spans.py`` reads a chip's trace."""
 
 import glob
+import importlib
 import operator
+import re
 import time
 
 import numpy as np
@@ -501,7 +503,7 @@ def test_decode_programs_hold_their_scopes_and_their_names(lm):
     for name in (profile.PROGRAM_STEP, profile.PROGRAM_STEPK,
                  profile.PROGRAM_SPEC):
         for scope in (profile.SCOPE_SAMPLE, profile.SCOPE_DECODE_ATTENTION,
-                      profile.SCOPE_DECODE_MLP):
+                      profile.SCOPE_MLP):
             assert scope in texts[name], (name, scope)
     for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_PADMIT):
         assert profile.SCOPE_SAMPLE in texts[name], name
@@ -509,8 +511,161 @@ def test_decode_programs_hold_their_scopes_and_their_names(lm):
     for name in decode - {profile.PROGRAM_FILL}:
         assert "stablehlo.case" in texts[name], name
     assert "stablehlo.case" not in texts[profile.PROGRAM_FILL]
-    for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_FILL):
-        assert profile.SCOPE_PREFILL in texts[name], name
+    # the admissions are partitioned into parts, the drafted and the
+    # prefix-pooled ones too; the program's name says "prefill"
+    for name in (profile.PROGRAM_ADMIT, profile.PROGRAM_PADMIT,
+                 profile.PROGRAM_FILL):
+        assert "zoo_prefill" not in texts[name], name
+        assert "" not in located_parts(texts[name], name), name
+
+
+def located_parts(text, program=profile.PROGRAM_ADMIT):
+    """The innermost ``profile.ADMIT_PARTS`` name of every located
+    operation of a lowered module's ``main`` (its text with debug info),
+    constants and the function's returns aside; ``""`` for an operation
+    under none.  A private function's operations are called from one of
+    main's and take that call's path: on the chip XLA inlines the call
+    under its name."""
+    locs = dict(re.findall(r"^(#loc\d+) = (.*)$", text, re.M))
+
+    def path(ref):
+        loc = locs.get(ref, "")
+        named = re.match(r'loc\("([^"]*)"', loc)
+        if named:
+            return named.group(1)
+        call = re.match(r"loc\(callsite\((#loc\d+) at", loc)
+        return path(call.group(1)) if call else ""
+
+    main = text.split("func.func public @main", 1)[1].split(
+        "\n  func.func ", 1)[0]
+    parts = []
+    for line in main.splitlines()[1:]:
+        op = line.strip()
+        if op.startswith(("return ", "stablehlo.return ", "} loc(")) \
+                or "stablehlo.constant " in op:
+            continue
+        ref = re.search(r"loc\((#loc\d+)\)$", op)
+        if ref is None:
+            continue
+        scopes = re.split(r"[/()]", path(ref.group(1)))
+        assert scopes[0] == "jit" and scopes[1] == program[4:], op
+        names = [s for s in scopes if s in profile.ADMIT_PARTS]
+        parts.append(names[-1] if names else "")
+    assert parts
+    return parts
+
+
+def admit_and_step_texts(eng, bucket):
+    """The lowered (debug-info) text of ``eng``'s admit plan for
+    ``bucket`` and of its fused window plan."""
+    texts = {}
+
+    def capture(name, jitted, arg_specs):
+        weights = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            eng._weights)
+        texts[name] = jitted.lower(*arg_specs, weights).as_text(
+            debug_info=True)
+        return lambda *a: None
+
+    eng._plan = capture
+    eng._ensure_step_plans()
+    eng._admit_fn_for(bucket)
+    return texts[f"admit{bucket}"], texts[f"step{max(eng._fuse_sizes)}"]
+
+
+def family_engine(family):
+    """A tiny served model of each family and an engine of 2 slots over
+    its float32 weights."""
+    if family == "transformer_lm":
+        net = TransformerLM(vocab_size=VOCAB, seq_len=SEQ, n_layers=2,
+                            d_model=32, n_heads=2)
+        net.ensure_inference_ready()
+    else:
+        t = importlib.import_module(family)
+        net = t.build()
+        net.compile("sgd", "class_nll")
+        net.trainer.adopt_weights(t.ref.make_params(t.CFG, 7, jnp.float32))
+    return DecodeEngine(net.trainer.state.params, net.hyper, capacity=2,
+                        max_len=SEQ, prompt_buckets=(BUCKET,), step_fuse=2)
+
+
+#: per family, the parts of its admission and the scopes its fused
+#: window holds (the names accepted readers go by, and ``zoo_mlp``)
+FAMILIES = {
+    "transformer_lm": (
+        {"zoo_embed", "zoo_norm", "zoo_attn_proj", "zoo_attn_core",
+         "zoo_mlp", "zoo_head", "zoo_insert", "zoo_sample"},
+        (profile.SCOPE_DECODE_ATTENTION, profile.SCOPE_SAMPLE,
+         profile.SCOPE_MLP)),
+    "test_commandaplus": (
+        {"zoo_embed", "zoo_norm", "zoo_attn_proj", "zoo_attn_core",
+         "zoo_moe_router", "zoo_moe_experts", "zoo_moe_shared", "zoo_head",
+         "zoo_insert", "zoo_sample"},
+        (profile.SCOPE_DECODE_ATTENTION, profile.SCOPE_SAMPLE,
+         profile.SCOPE_MOE, profile.SCOPE_MOE_ROUTER,
+         profile.SCOPE_MOE_EXPERTS, profile.SCOPE_MOE_SHARED)),
+    "test_granitehybrid": (
+        {"zoo_embed", "zoo_norm", "zoo_attn_proj", "zoo_attn_core",
+         "zoo_mlp", "zoo_ssm_proj", "zoo_ssm_conv", "zoo_ssm_scan",
+         "zoo_head", "zoo_insert", "zoo_sample"},
+        (profile.SCOPE_DECODE_ATTENTION, profile.SCOPE_SAMPLE,
+         profile.SCOPE_SSM, profile.SCOPE_SSM_CONV, profile.SCOPE_SSM_SCAN,
+         profile.SCOPE_MLP)),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_every_operation_of_an_admission_has_one_part(family):
+    """Each located operation of the lowered admit plan lies under a
+    name of ``profile.ADMIT_PARTS``, the innermost one its part (off
+    the chip the jnp attention stands in for the kernel under
+    ``zoo_attn_core``): the device time a profile shows of the plan
+    splits into those parts with nothing left over but what the
+    compiler adds."""
+    eng = family_engine(family)
+    try:
+        admit, _ = admit_and_step_texts(eng, BUCKET)
+    finally:
+        eng.close()
+    parts = located_parts(admit)
+    assert "" not in parts
+    assert set(parts) == FAMILIES[family][0]
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_step_plans_keep_the_names_the_readers_go_by(family):
+    eng = family_engine(family)
+    try:
+        _, step = admit_and_step_texts(eng, BUCKET)
+    finally:
+        eng.close()
+    for scope in FAMILIES[family][1]:
+        assert scope in step, scope
+    assert "zoo_decode_mlp" not in step
+
+
+def test_the_compile_cache_keys_on_the_scopes(monkeypatch, tmp_path):
+    """``enable_compile_cache`` puts a program's metadata, its scopes
+    among it, into the persistent cache's key: a plan that differs from
+    a cached one in its names alone is compiled anew, not answered with
+    the other's executable."""
+    from analytics_zoo_tpu.common.context import enable_compile_cache
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    regex = "jax_hlo_source_file_canonicalization_regex"
+    was = {f: getattr(jax.config, f) for f in (flag, regex)}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        jax.config.update(flag, False)
+        jax.config.update(regex, None)
+        assert enable_compile_cache() == str(tmp_path)
+        assert getattr(jax.config, flag) is True
+        # file names in the metadata count from the checkout
+        assert getattr(jax.config, regex)
+    finally:
+        for f, v in was.items():
+            jax.config.update(f, v)
 
 
 def test_flash_kernels_carry_their_names():

@@ -1002,7 +1002,10 @@ def test_failed_first_token_fetch_strands_no_caller(lm, monkeypatch):
 def test_deferred_count_and_loop_phases_sum_to_the_wall():
     """Every admission but those of one token has its first token
     fetched behind a window, and the loop's phases (``admit_fetch`` now
-    beside ``admit``, none inside another) still sum to its wall."""
+    beside ``admit``, none inside another) still sum to its wall.  The
+    sum is taken over the dispatcher's whole life, once ``close`` has
+    joined it: a phase still in flight at a snapshot is counted only
+    when it ends, so a window between two snapshots may miss one."""
     from analytics_zoo_tpu.pipeline.inference.decode import LOOP_PHASES
 
     # wide enough that a step outweighs the loop's own bookkeeping
@@ -1014,33 +1017,25 @@ def test_deferred_count_and_loop_phases_sum_to_the_wall():
     eng.warmup()
     rng = np.random.default_rng(101)
     max_news = [1 if i % 8 == 7 else 10 for i in range(120)]
-
-    def snapshot():
-        ta = time.perf_counter()
-        stats = eng.stats()
-        return ta, time.perf_counter(), stats
-
+    lengths = rng.integers(2, BUCKET, 120)
+    assert not any(eng.stats()[f"loop_{p}_s"] for p in LOOP_PHASES)
     try:
-        # a backlog keeps the dispatcher busy, so at each snapshot the
-        # phase in flight (counted only when it ends) is a short one
+        # the dispatcher starts at the first submit
+        t0 = time.perf_counter()
         streams = [eng.submit(rng.integers(0, VOCAB, int(n)), m)
-                   for n, m in zip(rng.integers(2, BUCKET, 120), max_news)]
-        streams[4].result(timeout=120)
-        t0a, t0b, s0 = snapshot()
-        streams[110].result(timeout=120)
-        t1a, t1b, s1 = snapshot()
+                   for n, m in zip(lengths, max_news)]
         [s.result(timeout=120) for s in streams]
-        done = eng.stats()
     finally:
-        eng.close()
+        eng.close(timeout=60)
+    wall = time.perf_counter() - t0
+    assert not eng._thread.is_alive()
+    done = eng.stats()
     assert done["admitted"] == 120 and done["evicted"] == 120
     assert done["first_tokens_deferred"] == 120 - max_news.count(1)
     assert done["tokens"] == sum(max_news)
-    loop_s = sum(s1[f"loop_{p}_s"] - s0[f"loop_{p}_s"]
-                 for p in LOOP_PHASES)
-    assert 0.95 * (t1a - t0b) <= loop_s <= 1.05 * (t1b - t0a), \
-        (loop_s, t1a - t0b, t1b - t0a)
-    assert s1["loop_admit_fetch_s"] > s0["loop_admit_fetch_s"]
+    loop_s = sum(done[f"loop_{p}_s"] for p in LOOP_PHASES)
+    assert 0.95 * wall <= loop_s <= wall, (loop_s, wall)
+    assert done["loop_admit_fetch_s"] > 0
 
 
 # ----------------------------- the round after an eviction and the freed caller
