@@ -3,6 +3,7 @@ from .textclassification import TextClassifier
 from .textgeneration import TransformerLM
 from .commandaplus import CommandAPlusLM
 from .granitehybrid import GraniteHybridLM
+from .ouro import OuroLM
 from .recommendation import (Recommender, NeuralCF, WideAndDeep,
                              UserItemFeature, UserItemPrediction,
                              ColumnFeatureInfo)

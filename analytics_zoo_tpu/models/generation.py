@@ -296,7 +296,10 @@ def _prefill_ext(params, hyper, tail, prefix_kv, p_len: int):
 # recurrent layer's fixed-size state), ``slab_dtype(params)``,
 # ``insert(hyper, caches, prompt_caches, slot, length)`` and
 # ``kv_kinds(hyper, capacity, max_len, dtype)`` (``(rows, read block,
-# layers counted)`` of each kind of slab).  The engine makes the state's
+# layers counted)`` of each kind of slab).  A family that runs its
+# layers more than once a token says how often, ``passes(hyper)`` (the
+# engine's spans and ``pass_steps`` count by it; one where it is not
+# given).  The engine makes the state's
 # zeros, specs, placement and donation from ``state_shapes`` alone.  The
 # prefill is called with the prompt's ``length`` as a keyword; a family
 # whose state is slabs alone does not need it.  TransformerLM's are the

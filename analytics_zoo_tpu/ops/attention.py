@@ -1040,9 +1040,12 @@ def kv_pad(rows, cache_len: int):
 
 def kv_insert(slab, rows, slot, start=0):
     """Write ``rows`` (1, s, heads * d_head) of one sequence into slot
-    ``slot`` of the slab, at positions ``[start, start + s)``."""
-    return lax.dynamic_update_slice(slab, rows.astype(slab.dtype),
-                                    (slot, start, 0))
+    ``slot`` of the slab, at positions ``[start, start + s)``.  A slab
+    with a pass axis, ``(capacity, passes, rows, heads * d_head)``, takes
+    every pass's rows at once: ``(1, passes, s, heads * d_head)``."""
+    return lax.dynamic_update_slice(
+        slab, rows.astype(slab.dtype),
+        (slot,) + (0,) * (slab.ndim - 3) + (start, 0))
 
 
 def kv_write_row(slab, row, pos):
@@ -1395,15 +1398,35 @@ def rope_interleaved(x, pos, theta: float):
                      axis=-1).reshape(x.shape)
 
 
+def rope_half(x, pos, theta: float):
+    """Rotary positions by rotate-half (the Llama / Qwen lineage's): the
+    pair ``(x[i], x[i + d/2])`` of the last axis turns by ``pos * theta
+    ** (-2i / d)``.  ``pos`` broadcasts against ``x.shape[:-1]``.
+    Float32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.asarray(pos, jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1)
+
+
+#: how a layer pairs the dimensions its rotary positions turn
+ROPES = {"interleaved": rope_interleaved, "half": rope_half}
+
+
 @jax.named_scope(_profile.SCOPE_ATTN_PROJ)
-def gqa_qkv(p, h, pos, rope_theta=None):
+def gqa_qkv(p, h, pos, rope_theta=None, rope="interleaved"):
     """``h (b, s, d_model)`` through ``Wq (d_model, heads, d)``, ``Wk``,
     ``Wv (d_model, kv_heads, d)`` into ``(b, heads, s, d)`` layout, the
     products in the weights' dtype with float32 accumulation; ``q`` and
-    ``k`` turned by :func:`rope_interleaved` at ``pos`` (``(s,)`` or
-    ``(b, s)``) where ``rope_theta`` is given (a layer without it has no
-    positions at all).  Returns the three in the weights' dtype: keys
-    are cached as they come out, positions applied."""
+    ``k`` turned at ``pos`` (``(s,)`` or ``(b, s)``) by :func:`rope_
+    interleaved` or, for ``rope="half"``, :func:`rope_half`, where
+    ``rope_theta`` is given (a layer without it has no positions at
+    all).  Returns the three in the weights' dtype: keys are cached as
+    they come out, positions applied."""
     hb = h.astype(p["Wq"].dtype)
 
     def proj(w):
@@ -1414,8 +1437,9 @@ def gqa_qkv(p, h, pos, rope_theta=None):
     if rope_theta is not None:
         at = jnp.asarray(pos)
         at = at[None, None, :] if at.ndim == 1 else at[:, None, :]
-        q = rope_interleaved(q, at, rope_theta)
-        k = rope_interleaved(k, at, rope_theta)
+        turn = ROPES[rope]
+        q = turn(q, at, rope_theta)
+        k = turn(k, at, rope_theta)
     dt = p["Wq"].dtype
     return q.astype(dt), k.astype(dt), v.astype(dt)
 
@@ -1595,7 +1619,8 @@ def _own_lanes(n_heads: int, group: int, d: int):
 @functools.partial(jax.jit, static_argnames=("n_heads", "n_kv_heads",
                                              "block", "interpret"))
 def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
-                     n_kv_heads: int, block: int, interpret: bool):
+                     n_kv_heads: int, block: int, interpret: bool,
+                     pass_index=None):
     """The kernel over ``(b, heads * d)`` queries, ``(b, kv_heads * d)``
     new rows, ``(b, rows, kv_heads * d)`` bfloat16 slabs and ``(b,)``
     int32 positions.  A jit of its own inside the step, as
@@ -1603,35 +1628,65 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
     narrower than a lane tile goes in with its values in its own cached
     head's lanes of the tile and zeros in the others (``_own_lanes``),
     and its result comes out of those lanes; heads of 128 go in and come
-    out as they are."""
-    b, rows, width = ck.shape
+    out as they are.
+
+    Slabs with a pass axis, ``(b, passes, rows, kv_heads * d)``, take
+    ``pass_index`` (an int32 scalar, a value at run time): one more
+    prefetched scalar that the slab's and the write tile's index maps
+    read, so the kernel reads that pass's live row blocks and writes its
+    new row in place, and the other passes' rows are not touched."""
+    b, rows, width = ck.shape[0], ck.shape[-2], ck.shape[-1]
     d = width // n_kv_heads
     lanes, per = _GQA_LANES, _GQA_LANES // d   # cached heads a lane tile
     row = (pos % rows).astype(jnp.int32)
     n_live = jnp.minimum(pos + 1, rows).astype(jnp.int32)
     slot_of, blk_of, n_steps = _live_blocks(n_live - 1, block,
                                             rows // block)
+    scalars = [row, n_live, slot_of, blk_of]
+    kernel = functools.partial(_decode_gqa_kernel, block=block,
+                               n_tiles=width // lanes,
+                               group=per * n_heads // n_kv_heads,
+                               scale=1.0 / math.sqrt(d))
+    # an index map takes the grid step and the prefetched scalars
+    if pass_index is None:
+        lead = ()
+
+        def at(index):
+            return lambda g, r, n, so, bo: index(g, r, so, bo)
+    else:
+        lead = (None,)
+        scalars.append(jnp.reshape(pass_index, (1,)).astype(jnp.int32))
+
+        def at(index):      # the pass between the slot and the rows
+            def with_pass(g, r, n, so, bo, t):
+                slot, *rest = index(g, r, so, bo)
+                return (slot, t[0], *rest)
+            return with_pass
+
+        def kernel(row_ref, live_ref, slot_ref, blk_ref, pass_ref, *refs,
+                   _inner=kernel):      # the pass is the index maps' alone
+            _inner(row_ref, live_ref, slot_ref, blk_ref, *refs)
 
     def mine(*shape):       # one slot's block of a per-slot operand
         return pl.BlockSpec((None,) + shape,
-                            lambda g, r, n, so, bo: (so[g], 0, 0))
+                            lambda g, r, n, so, *_: (so[g], 0, 0))
 
-    slab = pl.BlockSpec((None, block, width),
-                        lambda g, r, n, so, bo: (so[g], bo[g], 0))
+    slab = pl.BlockSpec((None,) + lead + (block, width),
+                        at(lambda g, r, so, bo: (so[g], bo[g], 0)))
     tile = pl.BlockSpec(
-        (None, _GQA_ROW_TILE, width),
-        lambda g, r, n, so, bo: (so[g], r[so[g]] // _GQA_ROW_TILE, 0))
+        (None,) + lead + (_GQA_ROW_TILE, width),
+        at(lambda g, r, so, bo: (so[g], r[so[g]] // _GQA_ROW_TILE, 0)))
     q = q.astype(ck.dtype).reshape(b, n_heads, d)
     if per > 1:     # each head into its own cached head's lanes
         own = _own_lanes(n_heads, n_heads // n_kv_heads, d)
         q = jnp.where(own, jnp.tile(q, (1, 1, per)), 0)
+    # operands count from the scalar prefetch: the slabs follow q and the
+    # two new rows
+    first_slab = len(scalars) + 3
     o, ck, cv = pl.pallas_call(
-        functools.partial(_decode_gqa_kernel, block=block,
-                          n_tiles=width // lanes,
-                          group=per * n_heads // n_kv_heads,
-                          scale=1.0 / math.sqrt(d)),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(n_steps,),        # as many steps as blocks are live
             in_specs=[mine(n_heads, lanes), mine(1, width), mine(1, width),
                       slab, slab],
@@ -1640,13 +1695,12 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
         out_shape=[jax.ShapeDtypeStruct((b, n_heads, lanes), ck.dtype),
                    jax.ShapeDtypeStruct(ck.shape, ck.dtype),
                    jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
-        # operands count from the scalar prefetch: the slabs are 7 and 8
-        input_output_aliases={7: 1, 8: 2},
+        input_output_aliases={first_slab: 1, first_slab + 1: 2},
         interpret=interpret,
         name=_profile.KERNEL_DECODE_ATTN_GQA,
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))}),
-    )(row, n_live, slot_of, blk_of, q,
+    )(*scalars, q,
       k_new.astype(ck.dtype)[:, None, :], v_new.astype(cv.dtype)[:, None, :],
       ck, cv)
     if per > 1:     # a head's result is its own lanes; the rest are zeros
@@ -1655,10 +1709,19 @@ def _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads: int,
 
 
 def _decode_attention_gqa_reference(q, k_new, v_new, ck, cv, pos,
-                                    n_heads: int, n_kv_heads: int):
+                                    n_heads: int, n_kv_heads: int,
+                                    pass_index=None):
     """The masked full-length softmax in ``jax.numpy``: the new row
     written at ``pos`` mod the slab's length, then every query head over
-    the live rows of its cached head."""
+    the live rows of its cached head.  Slabs with a pass axis: pass
+    ``pass_index``'s slabs, written back in their place."""
+    if pass_index is not None:
+        part = [lax.dynamic_index_in_dim(c, pass_index, axis=1,
+                                         keepdims=False) for c in (ck, cv)]
+        o, k1, v1 = _decode_attention_gqa_reference(
+            q, k_new, v_new, *part, pos, n_heads, n_kv_heads)
+        return (o, lax.dynamic_update_index_in_dim(ck, k1, pass_index, 1),
+                lax.dynamic_update_index_in_dim(cv, v1, pass_index, 1))
     b, rows, width = ck.shape
     d = width // n_kv_heads
     posv = jnp.broadcast_to(pos, (b,))
@@ -1677,11 +1740,14 @@ def _decode_attention_gqa_reference(q, k_new, v_new, ck, cv, pos,
 
 
 def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
-                         n_kv_heads: int):
+                         n_kv_heads: int, pass_index=None):
     """One decode step's grouped-query attention of every sequence over
     its own slab.  ``q``: ``(b, heads * d)``; ``k_new``, ``v_new``:
     ``(b, kv_heads * d)``; ``ck``, ``cv``: ``(b, rows, kv_heads * d)``
-    slabs; ``pos``: ``(b,)`` positions of the new token.  The slab's
+    slabs, or ``(b, passes, rows, kv_heads * d)`` with ``pass_index``
+    (an int32 scalar) saying which pass's rows this step reads and
+    writes (a layer run several times a token keeps a cache a pass);
+    ``pos``: ``(b,)`` positions of the new token.  The slab's
     length says what it holds: position p lives in row ``p mod rows``,
     so a slab as long as the sequence may grow holds every position, and
     a slab of ``window`` rows is a ring that holds exactly the window
@@ -1694,7 +1760,7 @@ def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
     or 32), the pallas kernel ``zoo_decode_attn_gqa`` over the live row
     blocks only, a 128-lane tile of cached heads at a time; otherwise the
     masked full-length softmax, which reads every row of every slab."""
-    b, rows, width = ck.shape
+    b, rows, width = ck.shape[0], ck.shape[-2], ck.shape[-1]
     pos = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
     block = None
     if _on_tpu():
@@ -1702,10 +1768,11 @@ def decode_attention_gqa(q, k_new, v_new, ck, cv, pos, n_heads: int,
                                  width // n_kv_heads, ck.dtype)[0]
     if block is None:
         return _decode_attention_gqa_reference(q, k_new, v_new, ck, cv, pos,
-                                               n_heads, n_kv_heads)
+                                               n_heads, n_kv_heads,
+                                               pass_index)
     return _decode_gqa_call(q, k_new, v_new, ck, cv, pos, n_heads=n_heads,
                             n_kv_heads=n_kv_heads, block=block,
-                            interpret=False)
+                            interpret=False, pass_index=pass_index)
 
 
 @jax.named_scope(_profile.SCOPE_ATTN_PROJ)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from .....core import initializers
 from .....core.module import Layer, register_layer
-from .....ops.attention import (attention_bhsd, attention_gqa_bhsd,
+from .....ops.attention import (ROPES, attention_bhsd, attention_gqa_bhsd,
                                 gqa_qkv, scale_queries)
 
 
@@ -180,7 +180,8 @@ class GroupedQueryAttention(Layer):
     """Causal self-attention with ``n_heads`` query heads over
     ``n_kv_heads`` key/value heads (query head h reads key/value head
     ``h // (n_heads // n_kv_heads)``), no biases.  ``rope_theta``:
-    rotary positions over interleaved pairs, all ``head_dim`` dims;
+    rotary positions over all ``head_dim`` dims, in interleaved pairs
+    (``rope="interleaved"``) or by rotate-half (``rope="half"``);
     ``None``: no positions at all.  ``window``: query i sees keys j with
     ``j <= i`` and ``i - j < window``; ``None``: every ``j <= i``.
     Products run in the weights' dtype with float32 accumulation, the
@@ -191,13 +192,16 @@ class GroupedQueryAttention(Layer):
 
     def __init__(self, n_heads, n_kv_heads, head_dim, rope_theta=None,
                  window=None, init="glorot_uniform", scale=None,
-                 input_shape=None, name=None):
+                 rope="interleaved", input_shape=None, name=None):
         super().__init__(input_shape=input_shape, name=name)
         self.n_heads, self.n_kv_heads = int(n_heads), int(n_kv_heads)
         self.head_dim = int(head_dim)
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads ({n_heads}) is not a multiple of "
                              f"n_kv_heads ({n_kv_heads})")
+        if rope not in ROPES:
+            raise ValueError(f"rope {rope!r} is not one of {sorted(ROPES)}")
+        self.rope = rope
         self.rope_theta = None if rope_theta is None else float(rope_theta)
         self.window = None if window is None else int(window)
         self.scale = None if scale is None else float(scale)
@@ -214,7 +218,7 @@ class GroupedQueryAttention(Layer):
 
     def call(self, params, state, inputs, training=False, rng=None):
         q, k, v = gqa_qkv(params, inputs, jnp.arange(inputs.shape[1]),
-                          self.rope_theta)
+                          self.rope_theta, self.rope)
         if self.scale is not None:
             q = scale_queries(q, self.scale)
         o = attention_gqa_bhsd(q, k, v, window=self.window)
@@ -231,4 +235,6 @@ class GroupedQueryAttention(Layer):
                    window=self.window, init=self.init_name)
         if self.scale is not None:
             cfg["scale"] = self.scale   # omitted when None (byte-stability)
+        if self.rope != "interleaved":
+            cfg["rope"] = self.rope     # likewise
         return cfg

@@ -383,8 +383,10 @@ class DecodeEngine:
             experts; ``"granitemoehybrid"`` (``GraniteHybridLM``)
             brings a fixed-size recurrent state per slot beside the
             slabs (a Mamba layer's convolution window and float32
-            state), laid down at each prompt's own length; both refuse
-            ``prefix_pool``, drafts and ``mesh``.
+            state), laid down at each prompt's own length; ``"ouro"``
+            (``OuroLM``) runs its layers several times a token, each
+            pass with its own slabs (a pass axis inside a slot); all
+            three refuse ``prefix_pool``, drafts and ``mesh``.
         capacity: decode slots — the fixed batch width of the
             persistent step executable.
         max_len: per-slot cache length (default the model's
@@ -458,6 +460,10 @@ class DecodeEngine:
         # the model's family: whose embed / prefill / decode step / head
         # the plans trace, and how its cache is laid out
         self._fam = family_of(hyper)
+        # how many times a token runs the layers: a looped family's
+        # passes, else one
+        passes = getattr(self._fam, "passes", None)
+        self._passes = int(passes(hyper)) if passes else 1
         for what, given in (("prefix_pool", bool(prefix_pool)),
                             ("draft", draft_params is not None),
                             ("mesh", mesh is not None)):
@@ -667,6 +673,8 @@ class DecodeEngine:
         # enough for a metrics scrape, same convention as the
         # coalescer's hedge counters)
         self._counters = {"tokens": 0, "steps": 0, "steps_sorted": 0,
+                          # steps x the passes each ran over the layers
+                          "pass_steps": 0,
                           "prefills": 0,
                           "admitted": 0, "evicted": 0,
                           # of the admitted, those whose first token was
@@ -1690,7 +1698,8 @@ class DecodeEngine:
         # preparation and the plan's dispatch; the wait for the first
         # token is a phase of its own later in the round (admit_fetch)
         with self._phase("admit", bucket=req.bucket, length=req.length,
-                         slot=slot, queue_wait_us=int(waited * 1e6)):
+                         slot=slot, queue_wait_us=int(waited * 1e6),
+                         passes=self._passes):
             span = req.span
             if span is not None:
                 span.phase_start("prefill")
@@ -1880,7 +1889,8 @@ class DecodeEngine:
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
                          kv_positions_read=kv_read,
-                         pick_sorted=int(pick_sorted), **ring):
+                         pick_sorted=int(pick_sorted),
+                         passes=self._passes, **ring):
             flag = self._pick_flags[pick_sorted]
             if k > 1:
                 [toks] = self._run_step(self._stepk_fns[k], flag)
@@ -1889,6 +1899,7 @@ class DecodeEngine:
                 routed = self._run_step(self._step_fn, flag)
                 toks = (self._tok, *routed) if routed else self._tok
             self._counters["steps"] += k
+            self._counters["pass_steps"] += k * self._passes
             if pick_sorted:
                 self._counters["steps_sorted"] += k
             self._counters["kv_positions_live"] += kv_live
@@ -1913,12 +1924,14 @@ class DecodeEngine:
         with self._phase("dispatch", k=k, live=self._occupancy,
                          kv_positions_live=kv_live,
                          kv_positions_read=kv_read,
-                         pick_sorted=int(pick_sorted)):
+                         pick_sorted=int(pick_sorted),
+                         passes=self._passes):
             (self._caches, self._dcaches, self._tok, self._pos,
              self._samp, toks, acc) = self._spec_fn(
                 self._caches, self._dcaches, self._tok, self._pos,
                 self._samp, self._pick_flags[pick_sorted])
             self._counters["steps"] += k
+            self._counters["pass_steps"] += k * self._passes
             if pick_sorted:
                 self._counters["steps_sorted"] += k
             self._counters["kv_positions_live"] += kv_live

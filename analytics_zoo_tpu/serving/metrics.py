@@ -93,6 +93,7 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
     decode_counters: Dict[str, List] = {
         "zoo_decode_tokens_total": [],
         "zoo_decode_steps_total": [],
+        "zoo_decode_pass_steps_total": [],
         "zoo_decode_steps_sorted_total": [],
         "zoo_decode_sampled_tokens_total": [],
         "zoo_decode_prefix_hits_total": [],
@@ -211,6 +212,7 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
             for prom_name, key in (
                     ("zoo_decode_tokens_total", "tokens"),
                     ("zoo_decode_steps_total", "steps"),
+                    ("zoo_decode_pass_steps_total", "pass_steps"),
                     ("zoo_decode_steps_sorted_total", "steps_sorted"),
                     ("zoo_decode_sampled_tokens_total",
                      "sampled_tokens"),
@@ -335,6 +337,10 @@ def registry_families(snapshot: Dict[str, Any]) -> List[Family]:
             "engine (prefill first tokens included)",
         "zoo_decode_steps_total":
             "slot-array decode steps dispatched",
+        "zoo_decode_pass_steps_total":
+            "those steps times the passes each ran over the layers: a "
+            "looped model runs its layers several times a token, every "
+            "other model once",
         "zoo_decode_steps_sorted_total":
             "of those, the steps dispatched while a live slot sampled: "
             "they sort the vocabulary to pick their tokens, the others "

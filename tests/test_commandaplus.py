@@ -294,45 +294,60 @@ def test_window_tiles_skip_what_the_window_left_behind():
 
 #: (query heads, cached heads, d_head, rows, block): heads of 128, one
 #: cached head a lane tile; granite's heads of 64, two a tile, over its
-#: 1280 rows in blocks of 256; heads of 32, four a tile
-_GQA_128, _GQA_64, _GQA_32 = ((16, 2, 128, 256, 128), (32, 8, 64, 1280, 256),
-                              (16, 8, 32, 256, 128))
+#: 1280 rows in blocks of 256; heads of 32, four a tile; heads of 128 in
+#: groups of ONE (as many cached heads as query heads: ouro's 16 over 16)
+_GQA_128, _GQA_64, _GQA_32, _GQA_ONE = (
+    (16, 2, 128, 256, 128), (32, 8, 64, 1280, 256), (16, 8, 32, 256, 128),
+    (4, 4, 128, 384, 128))
 
 
-@pytest.mark.parametrize("layout, positions", [
-    pytest.param(_GQA_128, [5, 255, 100], id="positions0"),
-    pytest.param(_GQA_128, [256, 300, 700], id="positions1"),
-    pytest.param(_GQA_128, [0, 511, 512], id="positions2"),
-    pytest.param(_GQA_64, [5, 255, 1279], id="d64-block-edge-last-row"),
-    pytest.param(_GQA_64, [256, 700, 1024], id="d64-block-starts"),
-    pytest.param(_GQA_32, [3, 300, 511], id="d32-ring")])
-def test_decode_gqa_kernel_in_interpret_mode(layout, positions):
+@pytest.mark.parametrize("layout, positions, passes", [
+    pytest.param(_GQA_128, [5, 255, 100], None, id="positions0"),
+    pytest.param(_GQA_128, [256, 300, 700], None, id="positions1"),
+    pytest.param(_GQA_128, [0, 511, 512], None, id="positions2"),
+    pytest.param(_GQA_64, [5, 255, 1279], None, id="d64-block-edge-last-row"),
+    pytest.param(_GQA_64, [256, 700, 1024], None, id="d64-block-starts"),
+    pytest.param(_GQA_32, [3, 300, 511], None, id="d32-ring"),
+    pytest.param(_GQA_ONE, [5, 130, 383], None, id="group-one"),
+    pytest.param(_GQA_ONE, [0, 127, 383], (4, 2), id="group-one-pass2-of-4"),
+    pytest.param(_GQA_128, [5, 255, 100], (3, 0), id="pass0-of-3"),
+    pytest.param(_GQA_64, [256, 700, 1279], (2, 1), id="d64-pass1-of-2")])
+def test_decode_gqa_kernel_in_interpret_mode(layout, positions, passes):
     """``zoo_decode_attn_gqa`` over a bfloat16 slab, as a full slab (pos
     < rows) and as a ring (pos >= rows), against the masked softmax; the
     slabs come out with the new row written and nothing else touched.
     Heads narrower than a lane tile go in lane-packed: each row's score
-    and result are its own head's."""
+    and result are its own head's.  ``passes``: (passes, pass) slabs
+    with a pass axis, the kernel told which pass's rows to read and
+    write; every other pass's rows come out as they went in."""
     rng = np.random.default_rng(6)
     heads, n_kv, d, rows, block = layout
     b = 3
     bf = jnp.bfloat16
-    ck, cv = (jnp.asarray(rng.normal(size=(b, rows, n_kv * d)), bf)
+    lead = (b,) if passes is None else (b, passes[0])
+    ck, cv = (jnp.asarray(rng.normal(size=lead + (rows, n_kv * d)), bf)
               for _ in range(2))
     q = jnp.asarray(rng.normal(size=(b, heads * d)), bf)
     kn, vn = (jnp.asarray(rng.normal(size=(b, n_kv * d)), bf)
               for _ in range(2))
     pos = jnp.asarray(positions, jnp.int32)
+    at = {} if passes is None else {"pass_index": jnp.int32(passes[1])}
     o1, k1, v1 = A._decode_attention_gqa_reference(q, kn, vn, ck, cv, pos,
-                                                   heads, n_kv)
+                                                   heads, n_kv, **at)
     o2, k2, v2 = A._decode_gqa_call(q, kn, vn, ck, cv, pos, n_heads=heads,
                                     n_kv_heads=n_kv, block=block,
-                                    interpret=True)
+                                    interpret=True, **at)
     np.testing.assert_array_equal(np.asarray(k1, np.float32),
                                   np.asarray(k2, np.float32))
     np.testing.assert_array_equal(np.asarray(v1, np.float32),
                                   np.asarray(v2, np.float32))
     np.testing.assert_allclose(np.asarray(o1, np.float32),
                                np.asarray(o2, np.float32), atol=0.02)
+    if passes is not None:      # the other passes untouched, then that one
+        others = np.arange(passes[0]) != passes[1]
+        np.testing.assert_array_equal(np.asarray(k2, np.float32)[:, others],
+                                      np.asarray(ck, np.float32)[:, others])
+        k1, v1 = (x[:, passes[1]] for x in (k1, v1))
     # against repeated heads and an explicit mask, float32
     g = heads // n_kv
     live = np.arange(rows)[None] < np.minimum(np.asarray(pos) + 1,

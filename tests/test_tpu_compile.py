@@ -660,3 +660,151 @@ def test_granite_step_plan_compiles_without_a_state_sized_temporary(
         if m and m.group(2) not in passed_on \
                 and np.prod([int(n) for n in m.group(1).split(",")]) == slab:
             pytest.fail("an operation makes a slab: " + line[:200])
+
+
+# -------------------------------- ouro26-chat-closed-12: layers run in a loop
+OURO_SLOTS, OURO_LEN, OURO_PASSES = 12, 384, 4
+#: one layer's key (or value) slab over the cell's slots: every pass
+OURO_SLAB = OURO_SLOTS * OURO_PASSES * OURO_LEN * 16 * 128
+
+
+def _ouro(layers):
+    """The benchmark's ``ouro-2.6b`` at its published widths and passes,
+    ``layers`` of its 48 layers, as ``benchmark/adapters/ouro.py`` builds
+    it, with the reference's bfloat16 parameter shapes: nothing is
+    allocated."""
+    import json
+    from benchmark.adapters import ouro as adapter
+    from benchmark.reference import ouro as ref
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "ouro-2.6b.json")) as f:
+        cfg = json.load(f)
+    assert ref.n_params(cfg) == 2_667_974_657
+    cfg.update(num_hidden_layers=layers)
+    lm = adapter.build(cfg, {})
+    params = {layer: {leaf: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+                      for leaf, (shape, _) in leaves.items()}
+              for layer, leaves in ref.param_spec(cfg).items()}
+    return lm, params
+
+
+def test_ouro_step_plan_runs_one_pass_program_in_a_loop(topo, one_chip,
+                                                        on_the_chip):
+    """4 layers at the published widths, 4 passes, the fused window of 4
+    steps at 12 slots x 384: the passes are a loop around ONE copy of the
+    layers, so the grouped decode kernel is called once a layer (4 calls
+    in the lowered program and in the compiled one, not passes x layers
+    = 16); each layer's slabs, a pass axis inside a slot, are aliased
+    through the window; and no operation but the kernel makes an array a
+    layer's slab, or a pass's part of one, in size (a pass's part sliced
+    out and written back would be one)."""
+    import re
+    from analytics_zoo_tpu.observability import profile
+    lm, params = _ouro(4)
+    eng, lowered = _engine_without_state(
+        lm.hyper, OURO_SLOTS, topo.devices[0],
+        _abstract((params, None), one_chip), max_len=OURO_LEN)
+    assert [keys[0] for keys, _ in eng._layer_state_shapes()] \
+        == [(OURO_SLOTS, OURO_PASSES, OURO_LEN, 16 * 128)] * 4
+    eng._build_stepk_plan(4)
+    step = lowered["step4"]
+    assert _module_name(step) == profile.PROGRAM_STEPK
+    assert len(re.findall(r"call @_decode_gqa_call\(",
+                          step.as_text(debug_info=True))) == 4
+    compiled = step.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert profile.KERNEL_DECODE_ATTN_GQA in text
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 4 * OURO_SLAB * 2
+    passed_on = {"parameter", "get-tuple-element", "bitcast", "custom-call"}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(2) not in passed_on \
+                and np.prod([int(n) for n in m.group(1).split(",")]) \
+                in (OURO_SLAB, OURO_SLAB // OURO_PASSES):
+            pytest.fail("an operation makes a slab: " + line[:200])
+
+
+def test_ouro_admit_plan_runs_one_pass_program_in_a_loop(topo, one_chip,
+                                                         on_the_chip):
+    """The admission of the 256-token bucket, same 4 layers: one flash
+    forward a layer in the loop's body (not one a layer and a pass), the
+    prompt's rows of every pass laid into the slot."""
+    import re
+    from analytics_zoo_tpu.observability import profile
+    lm, params = _ouro(4)
+    eng, lowered = _engine_without_state(
+        lm.hyper, OURO_SLOTS, topo.devices[0],
+        _abstract((params, None), one_chip), max_len=OURO_LEN)
+    eng._admit_fn_for(256)
+    admit = lowered["admit256"]
+    assert _module_name(admit) == profile.PROGRAM_ADMIT
+    text = admit.as_text(debug_info=True)
+    assert len(re.findall(r"call @_flash_fwd_call\(", text)) == 4
+    assert profile.KERNEL_DECODE_ATTN_GQA not in text
+    assert "stablehlo.while" in text
+    assert f"1x{OURO_PASSES}x256x2048xbf16" in text
+
+
+def _kernels_without_locations(text):
+    """Lowered text with each Mosaic kernel's serialized body replaced by
+    a digest of the kernel printed WITHOUT its source locations (the
+    file and line of the Python that traced it, which any edit above the
+    kernel moves)."""
+    import base64
+    import hashlib
+    import re
+    from jax.interpreters.mlir import ir
+    from jaxlib.mosaic.python import tpu
+
+    def body(m):
+        with ir.Context() as ctx, ir.Location.unknown():
+            ctx.allow_unregistered_dialects = True
+            tpu.register_dialect(ctx)
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r"\\22body\\22: \\22([^\\]*)\\22", body, text)
+
+
+#: sha256 of ``_kernels_without_locations`` of each plan below, as the
+#: programs were before the grouped decode kernel took a pass axis: a
+#: slab without one lowers exactly as it did
+OTHER_FAMILIES_STEP_PLANS = {
+    "cmdaplus.step1":
+        "8ed1148eaa6d6a7a8d45480aab487b2098d4bf0e84fefd2f7dab8bce545f112e",
+    "cmdaplus.step4":
+        "125072efc6acbdb3c88e8e4616d7c87343188882e6b1fe84803761e954d6bf22",
+    "granite.step1":
+        "bf89af6405081d072d5e084105ef9b5db46028f77ecc0612336fa67fe4a85237",
+    "granite.step4":
+        "5f4cbe420e07aba216222ac49135476e1e625aa84fec3eb391735ae77713fc56",
+}
+
+
+def test_the_other_families_step_plans_lower_as_before(topo, one_chip,
+                                                       ssm_on_the_chip):
+    """``cohere2_moe`` (4 layers at the published widths, 8 slots x 6144)
+    and ``granitemoehybrid`` (a Mamba layer and an attention layer, 8
+    slots x 1280): the single step and the fused window of 4, lowered
+    for the described chip, are the programs they were, kernels and
+    all."""
+    import hashlib
+    got = {}
+    for family, (lm, params), max_len in (
+            ("cmdaplus", _command_a_plus(), 6144),
+            ("granite", _granite(["mamba", "attention"])[:2], GRANITE_LEN)):
+        eng, lowered = _engine_without_state(
+            lm.hyper, 8, topo.devices[0],
+            _abstract((params, None), one_chip), max_len=max_len)
+        eng._build_stepk_plan(4)
+        eng._build_step_plan()
+        for name, plan in lowered.items():
+            got[f"{family}.{name}"] = hashlib.sha256(
+                _kernels_without_locations(plan.as_text()).encode()
+            ).hexdigest()
+    assert got == OTHER_FAMILIES_STEP_PLANS
